@@ -49,13 +49,11 @@ import numpy as np
 
 
 def _enable_compile_cache():
-    """Persistent XLA compile cache (shared with the test suite's,
-    platform-partitioned) so repeated bench runs skip the multi-minute
+    """Persistent XLA compile cache under the one rule of
+    util/jax_cache.py, so repeated bench runs skip the multi-minute
     kernel compile."""
     from stellar_core_tpu.util.jax_cache import enable_compile_cache
-    enable_compile_cache(os.path.join(
-        os.path.dirname(os.path.abspath(__file__)),
-        "tests", ".jax_compile_cache"))
+    enable_compile_cache()
 
 
 def _bench_verify_backend(default: str = "tpu") -> str:
@@ -105,6 +103,18 @@ def _device_verify_probe(bucket: int) -> dict:
             "device_sigs_per_sec": round(device_rate, 1),
             "native_sigs_per_sec": round(native_rate, 1),
             "degraded": device_rate < native_rate}
+
+
+def _require_device(probe: dict) -> None:
+    """A bench leg that asked for the device and did not get one
+    raises; it does not carry on with `verify_backend: native`."""
+    if probe["degraded"]:
+        raise RuntimeError(
+            "device probe: degraded (%.0f sigs/s device vs %.0f native) — "
+            "this leg asked for the device; run it on the chip or ask for "
+            "SC_BENCH_VERIFY_BACKEND=native" % (
+                probe["device_sigs_per_sec"],
+                probe["native_sigs_per_sec"]))
 
 
 def _make_batch(n):
@@ -792,26 +802,17 @@ def bench_catchup(n_ledgers: int = 4096,
         app2.shutdown()
         return n / dt, evidence
 
-    # Device health gate: the pipeline leg bets on the device only when
-    # the device actually beats native at the checkpoint bucket. On a
-    # degraded host (no chip; XLA falls back to the CPU interpreter at
-    # ~40 sigs/s vs ~10k native) the leg pins the native verifier so
-    # the measurement isolates the pipeline restructure — download/
-    # verify overlap + staged parallel apply — instead of timing a
-    # broken backend. The probe verdict rides the artifact.
+    # Device health gate: a leg that asked for the device and did not
+    # get one (no chip; XLA:CPU at ~40 sigs/s vs ~10k native) raises —
+    # it never pins the native verifier and records a number under the
+    # device's name. The probe's rates ride the artifact.
     pipe_backend = _bench_verify_backend("tpu")
     probe = None
     if pipe_backend == "tpu":
         from stellar_core_tpu.ops.verifier import _bucket_size
         probe = _device_verify_probe(
             _bucket_size(payments_per_ledger * CHECKPOINT_FREQUENCY))
-        if probe["degraded"]:
-            print("device probe: degraded (%.0f sigs/s device vs %.0f "
-                  "native) — pipeline leg pins the native verifier" % (
-                      probe["device_sigs_per_sec"],
-                      probe["native_sigs_per_sec"]),
-                  file=sys.stderr, flush=True)
-            pipe_backend = "native"
+        _require_device(probe)
 
     # INTERLEAVED best-of-2 per leg: running the legs in blocks lets
     # slow box drift between blocks masquerade as a backend difference
@@ -1008,18 +1009,14 @@ def bench_catchup_bigstate(n_accounts: int = 1_000_000,
         app2.shutdown()
         return replayed / dt, evidence
 
-    # same device health gate as bench_catchup: a degraded device leg
-    # would measure the broken backend, not replay-over-big-state
+    # same device health gate as bench_catchup
     pipe_backend = _bench_verify_backend("tpu")
     probe = None
     if pipe_backend == "tpu":
         from stellar_core_tpu.ops.verifier import _bucket_size
         probe = _device_verify_probe(
             _bucket_size(payments_per_ledger * CHECKPOINT_FREQUENCY))
-        if probe["degraded"]:
-            print("device probe: degraded — bigstate pipeline leg pins "
-                  "the native verifier", file=sys.stderr, flush=True)
-            pipe_backend = "native"
+        _require_device(probe)
 
     host0 = _host_state()
     watch = _HostLoadWatch()

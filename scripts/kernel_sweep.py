@@ -19,7 +19,7 @@ def main():
     import jax
 
     from stellar_core_tpu.util.jax_cache import enable_compile_cache
-    enable_compile_cache(os.path.join(REPO, "tests", ".jax_compile_cache"))
+    enable_compile_cache()
 
     batches = [int(a) for a in sys.argv[1:]] or [16384]
     unrolls = [int(u) for u in

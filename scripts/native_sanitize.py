@@ -4,7 +4,7 @@
     python scripts/native_sanitize.py            # default test set
     python scripts/native_sanitize.py tests/test_crypto.py -k sha512
 
-Builds native/src/*.cpp into a separate libscnative-san.so
+Builds native/src/*.cpp into a separate libscnative-san-<digest>.so
 (`SC_NATIVE_SANITIZE=1`, see native/loader.py), then re-execs pytest
 with libasan LD_PRELOADed — an ASan DSO dlopen'd into a plain python
 needs the runtime loaded first. UBSan is -fno-sanitize-recover, so any

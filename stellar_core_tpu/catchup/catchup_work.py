@@ -644,6 +644,14 @@ class ApplyCheckpointWork(BasicWork):
         log.info("checkpoint %d: batch-verified %d signatures",
                  self.checkpoint, len(tuples))
 
+    def drain(self, timeout: float) -> None:
+        """Wait (bounded) for a dispatched batch that replay outran:
+        its collect thread is inside the device runtime, and a process
+        that exits under it aborts instead of returning its exit code;
+        and only a settled batch shows in the supervisor's status."""
+        if self._pending_batch is not None:
+            self._pending_batch[1].wait(timeout)
+
     def _apply_one(self, lm, seq: int, hhe) -> bool:
         self._resolve_prevalidated()
         the = self._txs_by_seq.get(seq)
@@ -683,6 +691,12 @@ class CatchupWork(Work):
         self._apply_seq: List[int] = []
         self._target = config.to_ledger
         self._tmp = tempfile.mkdtemp(prefix="catchup-")
+
+    def drain(self, timeout: float = 30.0) -> None:
+        """Settle every device batch still in flight (offline
+        `catchup` calls this before it reports and exits)."""
+        for cp in self.applied_checkpoints:
+            cp.drain(timeout)
 
     def do_work(self) -> State:
         if self._phase == 0:

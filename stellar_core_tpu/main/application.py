@@ -39,6 +39,29 @@ class AppState:
     APP_STOPPING_STATE = 5
 
 
+def device_backend_refusal(default_backend: str,
+                           jax_platforms: Optional[str]) -> Optional[str]:
+    """Why a node with SIGNATURE_VERIFY_BACKEND = "tpu" must not start
+    on this JAX platform, or None when it may.
+
+    With no chip JAX hands back the CPU and the kernel runs on XLA:CPU
+    at tens of signatures per second without a word. A CPU the operator
+    chose in JAX's own terms (JAX_PLATFORMS=cpu, i.e.
+    `jax.config.jax_platforms == "cpu"` — what the tests and their
+    `run` children do) is a choice stated outside, not a fallback; a
+    CPU that JAX fell back to is refused. Pure in its two arguments so
+    tests decide it without starting a backend."""
+    if default_backend == "tpu":
+        return None
+    if default_backend == "cpu" and jax_platforms == "cpu":
+        return None
+    return ('SIGNATURE_VERIFY_BACKEND = "tpu" but JAX\'s default backend '
+            f"is {default_backend!r} (jax_platforms={jax_platforms!r}): "
+            "no TPU was found. Run on a machine with a chip, set "
+            "JAX_PLATFORMS=cpu to choose the CPU on purpose, or use "
+            'SIGNATURE_VERIFY_BACKEND = "native".')
+
+
 class Application:
     @classmethod
     def create(cls, clock: VirtualClock, config: Config,
@@ -351,8 +374,21 @@ class Application:
         """Device-batch verifier per SIGNATURE_VERIFY_MESH: production
         multi-chip nodes shard the batch data-parallel over every
         visible device (ICI mesh); `hybrid` folds multi-host layouts
-        into a (dcn, ici) mesh so DCN only carries the result gather."""
+        into a (dcn, ici) mesh so DCN only carries the result gather.
+
+        Refuses to start on a device JAX fell back to (see
+        `device_backend_refusal`), and turns the persistent compile
+        cache on before the first jit is built: the first call of each
+        bucket compiles inside the dispatch, minutes on the thread
+        that closes ledgers, so a restart must find them compiled."""
         import jax
+
+        refusal = device_backend_refusal(jax.default_backend(),
+                                         jax.config.jax_platforms)
+        if refusal:
+            raise RuntimeError(refusal)
+        from ..util.jax_cache import enable_compile_cache
+        enable_compile_cache()
 
         mode = self.config.SIGNATURE_VERIFY_MESH
         min_batch = self.config.VERIFY_DEVICE_MIN_BATCH

@@ -160,7 +160,22 @@ def cmd_catchup(args) -> int:
         work = CatchupWork(app, archive,
                            CatchupConfiguration(to_ledger=to_ledger))
         state = run_work_to_completion(app, work, timeout_virtual=86400)
+        work.drain()
         lcl = app.ledger_manager.get_last_closed_ledger_num()
+        # one JSON line from which a caller outside the process can
+        # tell where replay ended and, on the tpu backend, whether the
+        # device verified anything: the served `backendstatus` object
+        # plus what reached the device
+        report = {"state": state.name, "lcl": lcl,
+                  "lcl_hash": app.ledger_manager
+                  .get_last_closed_ledger_hash().hex()}
+        if cfg.SIGNATURE_VERIFY_BACKEND == "tpu":
+            batch = app.metrics.new_histogram(
+                "crypto.verify.dispatch.batch").to_json()
+            report["backend"] = app.batch_verifier.status()
+            report["crypto.verify.dispatch.batch"] = {
+                "count": batch["count"], "sum": batch["sum"]}
+        print(json.dumps(report), flush=True)
         print(f"catchup {state.name}, LCL {lcl}")
         return 0 if state == State.WORK_SUCCESS else 1
     finally:

@@ -3,7 +3,14 @@
 Generates SHA-512 round constants at build time from first principles
 (fractional bits of cube/square roots of the first primes, FIPS 180-4 §4.2.3)
 so no constant tables are copied from anywhere, then compiles
-src/*.cpp -> build/libscnative.so and exposes a typed ctypes wrapper.
+src/*.cpp -> build/libscnative-<digest>.so and exposes a typed ctypes
+wrapper.
+
+Nothing under build/ is committed, and a built file is named by a
+digest of its sources, its flags and the machine it was built for
+(`built_path`): the library is compiled with -march=native, so a file
+another machine built — a copied tree, a shared disk — has another
+name and is never opened, and a stale one neither.
 """
 
 from __future__ import annotations
@@ -12,21 +19,20 @@ import ctypes
 import hashlib
 import math
 import os
+import platform
 import subprocess
 import threading
-from typing import Optional
+from typing import List, Optional, Sequence
 
 import numpy as np
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "src")
 _BUILD = os.path.join(_DIR, "build")
-_SO = os.path.join(_BUILD, "libscnative.so")
 # SC_NATIVE_SANITIZE=1 selects a separate ASan+UBSan build (never
 # clobbers the fast .so). Load it via scripts/native_sanitize.py, which
 # LD_PRELOADs libasan — dlopen'ing an ASan DSO into a plain python
 # otherwise trips ASan's link-order check. See docs/ANALYSIS.md.
-_SAN_SO = os.path.join(_BUILD, "libscnative-san.so")
 
 
 def sanitize_enabled() -> bool:
@@ -80,19 +86,58 @@ def _gen_consts_header() -> str:
     return "\n".join(lines) + "\n"
 
 
+def machine_id() -> str:
+    """What a -march=native binary is tied to: the architecture and the
+    first CPU's model and feature flags."""
+    parts = [platform.system(), platform.machine()]
+    try:
+        with open("/proc/cpuinfo") as f:
+            for line in f:
+                if not line.strip():
+                    break                       # first CPU only
+                if line.startswith(("model name", "flags", "Features")):
+                    parts.append(line.strip())
+    except OSError:
+        parts.append(platform.processor())
+    return "\n".join(parts)
+
+
+def built_path(stem: str, sources: Sequence[bytes],
+               flags: Sequence[str]) -> str:
+    """build/<stem>-<digest>.so, the digest over the source texts, the
+    compiler flags and `machine_id()`."""
+    h = hashlib.sha256()
+    for part in (*sources, " ".join(flags).encode(), machine_id().encode()):
+        h.update(len(part).to_bytes(8, "little"))
+        h.update(part)
+    return os.path.join(_BUILD, f"{stem}-{h.hexdigest()[:16]}.so")
+
+
+def compile_shared(so: str, cmd: List[str]) -> str:
+    """Run `cmd + ["-o", <tmp>]` and move the result to `so` in one
+    step: a concurrent builder (pytest-xdist workers share the build
+    directory) or a killed compile never leaves a torn file under the
+    final name."""
+    tmp = f"{so}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(cmd + ["-o", tmp], check=True, capture_output=True)
+        os.replace(tmp, so)
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError(
+            f"native build failed: {' '.join(cmd)}\n"
+            f"{e.stderr.decode(errors='replace')}") from e
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
+    return so
+
+
 def build(force: bool = False) -> str:
     san = sanitize_enabled()
-    so = _SAN_SO if san else _SO
     os.makedirs(_BUILD, exist_ok=True)
     srcs = sorted(
         os.path.join(_SRC, f) for f in os.listdir(_SRC) if f.endswith(".cpp"))
-    if not force and os.path.exists(so):
-        so_mtime = os.path.getmtime(so)
-        if all(os.path.getmtime(s) < so_mtime for s in srcs):
-            return so
-    hdr = os.path.join(_SRC, "sha512_consts.h")
-    with open(hdr, "w") as f:
-        f.write(_gen_consts_header())
+    consts = _gen_consts_header()
     if san:
         # -O1 keeps stack traces honest; UBSan aborts on first report so
         # a signed-overflow/misaligned-load bug fails the test run loudly
@@ -101,15 +146,25 @@ def build(force: bool = False) -> str:
                  "-fno-sanitize-recover=undefined"]
     else:
         flags = ["-O3"]
-    cmd = (["g++"] + flags +
-           ["-march=native", "-std=c++17", "-shared", "-fPIC",
-            "-pthread", "-o", so] + srcs)
-    subprocess.run(cmd, check=True, capture_output=True)
-    return so
+    flags += ["-march=native", "-std=c++17", "-shared", "-fPIC", "-pthread"]
+    texts = [consts.encode()]
+    for path in srcs:
+        with open(path, "rb") as f:
+            texts.append(f.read())
+    so = built_path("libscnative-san" if san else "libscnative", texts, flags)
+    if not force and os.path.exists(so):
+        return so
+    # the generated header lives beside the binaries, not in src/
+    hdr = os.path.join(_BUILD, "sha512_consts.h")
+    tmp = f"{hdr}.{os.getpid()}.tmp"
+    with open(tmp, "w") as f:
+        f.write(consts)
+    os.replace(tmp, hdr)
+    return compile_shared(so, ["g++"] + flags + [f"-I{_BUILD}"] + srcs)
 
 
 class NativeLib:
-    """Typed wrapper over libscnative.so."""
+    """Typed wrapper over the built libscnative."""
 
     def __init__(self, so_path: str):
         self._c = ctypes.CDLL(so_path)

@@ -786,8 +786,10 @@ class BackendSupervisor:
                     "quarantined": sum(1 for q in self._quarantined
                                        if q.device == b.index),
                 })
+            device_info = getattr(self._inner, "device_info", None)
             return {
                 "state": self._agg_state,
+                "device": device_info() if device_info else None,
                 "consecutive_failures": self.consecutive_failures,
                 "failure_threshold": self._threshold,
                 "dispatches": self._dispatch_counter.count,

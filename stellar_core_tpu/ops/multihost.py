@@ -25,11 +25,7 @@ from typing import Optional, Sequence
 import numpy as np
 import jax
 from jax.sharding import Mesh, PartitionSpec as PSpec
-try:
-    from jax import shard_map
-except ImportError:                                  # pragma: no cover
-    # older jax exposes shard_map under jax.experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from . import ed25519_kernel
 from .verifier import MIN_BUCKET, ShardedBatchVerifier, TpuBatchVerifier

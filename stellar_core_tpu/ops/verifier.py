@@ -6,7 +6,7 @@ points: txset validation herder/TxSetUtils.cpp:200 and catchup replay
 catchup/ApplyCheckpointWork.h — see SURVEY.md §3.2/§3.3).
 
 Pipeline per batch of (pubkey, sig, msg):
-  1. host (native C++, Python-oracle fallback):
+  1. host (native C++):
      k = SHA512(R‖A‖M) mod L; S<L check; strict decompress + small-order
      checks on A and R; affine -A coords.  (SHA-512's 64-bit rotates are
      hostile to TPU int ops — SURVEY §7 "hard parts" — so hashing stays
@@ -40,7 +40,6 @@ runs the identical per-lane kernel; only the shard layout moves.
 
 from __future__ import annotations
 
-import hashlib
 import time as _time
 from typing import List, Optional, Sequence, Tuple
 
@@ -48,15 +47,10 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as PSpec
-try:
-    from jax import shard_map
-except ImportError:                                  # pragma: no cover
-    # older jax exposes shard_map under jax.experimental
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from . import ed25519_kernel
 from .shard_math import shard_shares
-from ..crypto import ed25519_ref as _ref
 from ..util import chaos
 
 MIN_BUCKET = 8
@@ -141,47 +135,15 @@ def prevalidate_coalesce(counts: Sequence[int], max_fuse: int,
 
 
 def _native():
-    try:
-        from ..native import loader
-        return loader.get_lib()
-    except Exception:
-        return None
-
-
-def _prep_python(pubs: np.ndarray, sigs: np.ndarray,
-                 msgs: Sequence[bytes]):
-    """Oracle-backed host prep (fallback when the native lib is absent)."""
-    n = len(msgs)
-    k_out = np.zeros((n, 32), dtype=np.uint8)
-    neg_a = np.zeros((n, 64), dtype=np.uint8)
-    ok = np.zeros(n, dtype=bool)
-    for i in range(n):
-        pub, sig, msg = bytes(pubs[i]), bytes(sigs[i]), msgs[i]
-        s = int.from_bytes(sig[32:], "little")
-        if s >= _ref.L:
-            continue
-        a_pt = _ref.pt_decompress(pub, strict=True)
-        if a_pt is None or _ref.pt_is_small_order(a_pt):
-            continue
-        r_pt = _ref.pt_decompress(sig[:32], strict=True)
-        if r_pt is None or _ref.pt_is_small_order(r_pt):
-            continue
-        k = _ref.compute_k(sig[:32], pub, msg)
-        k_out[i] = np.frombuffer(k.to_bytes(32, "little"), dtype=np.uint8)
-        nx = (_ref.P - a_pt[0]) % _ref.P
-        neg_a[i, :32] = np.frombuffer(nx.to_bytes(32, "little"),
-                                      dtype=np.uint8)
-        neg_a[i, 32:] = np.frombuffer(a_pt[1].to_bytes(32, "little"),
-                                      dtype=np.uint8)
-        ok[i] = True
-    return k_out, neg_a, ok
+    """The native library; one that cannot be built is an error (the
+    Python-oracle prep it used to fall back to is ~1000x slower)."""
+    from ..native import loader
+    return loader.get_lib()
 
 
 def host_prepare(pubs: np.ndarray, sigs: np.ndarray, msgs: Sequence[bytes]):
     """Returns (k (n,32) u8, neg_a (n,64) u8, ok (n,) bool)."""
     lib = _native()
-    if lib is None:
-        return _prep_python(pubs, sigs, msgs)
     offsets = np.zeros(len(msgs) + 1, dtype=np.uint64)
     np.cumsum([len(m) for m in msgs], out=offsets[1:])
     blob = b"".join(msgs)
@@ -196,17 +158,9 @@ def host_k(pubs: np.ndarray, sigs: np.ndarray, msgs: Sequence[bytes]):
     (ed25519_kernel.verify_kernel_full). SHA-512 stays host-side: 64-bit
     rotates are hostile to the TPU int units (SURVEY.md §7 hard parts)."""
     lib = _native()
-    if lib is not None:
-        offsets = np.zeros(len(msgs) + 1, dtype=np.uint64)
-        np.cumsum([len(m) for m in msgs], out=offsets[1:])
-        blob = b"".join(msgs)
-        k, _ = lib.batch_prepare(pubs, sigs, blob, offsets)
-        return k
-    n = len(msgs)
-    k = np.zeros((n, 32), dtype=np.uint8)
-    for i in range(n):
-        ki = _ref.compute_k(bytes(sigs[i, :32]), bytes(pubs[i]), msgs[i])
-        k[i] = np.frombuffer(ki.to_bytes(32, "little"), dtype=np.uint8)
+    offsets = np.zeros(len(msgs) + 1, dtype=np.uint64)
+    np.cumsum([len(m) for m in msgs], out=offsets[1:])
+    k, _ = lib.batch_prepare(pubs, sigs, b"".join(msgs), offsets)
     return k
 
 
@@ -251,6 +205,14 @@ class TpuBatchVerifier:
         self._device_min_batch = _device_min_batch_default(device_min_batch)
         self.perf = perf  # per-app zone registry (None = process default)
         self._init_dispatch_metrics(metrics)
+
+    def device_info(self) -> dict:
+        """{platform, kind, count} of the devices THIS verifier
+        dispatches to — the `device` of `backendstatus`, so a process
+        that must stay off JAX can tell which device verified."""
+        devs = getattr(self, "devices", None) or jax.devices()[:1]
+        return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+                "count": len(devs)}
 
     def set_device_min_batch(self, n: int) -> None:
         """Live re-tune of the host-bypass cutoff (ops/controller.py;

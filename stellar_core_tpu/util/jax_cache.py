@@ -1,50 +1,52 @@
-"""Platform-partitioned persistent XLA compile cache.
+"""Where JAX's persistent compilation cache lives: one rule for the
+node, the tests, the bench and the scripts.
 
-One shared cache directory serving both the CPU test mesh and the real
-TPU chip poisons cross-platform runs: XLA:CPU AOT artifacts compiled on
-one host generation are loaded on another (cpu_aot_loader machine-feature
-mismatch warnings, and a real SIGILL footgun when the features actually
-differ), and an 8-device CPU dryrun must never load chip AOT results.
-Partition by backend platform + (for CPU) the host ISA so each target
-only ever sees artifacts it produced.
+- `JAX_COMPILATION_CACHE_DIR` set: JAX reads it itself and this module
+  sets NO directory in code, so whoever starts the process (an
+  operator, a harness, a sealed chip machine that mounts a warm cache)
+  places the cache from outside.
+- unset: `<checkout>/.jax_compile_cache/<platform>` (gitignored). The
+  path is part of the cache key, so it is fixed: never a temporary
+  name, a pid or a time. The platform sub-directory keeps XLA:CPU AOT
+  artifacts (which embed host machine features and do not transfer
+  between host generations) apart from chip executables.
+
+`cache_dir_for_backend` asks `jax.default_backend()`, which starts the
+backend and on a chip machine TAKES THE CHIP: only a process that is
+meant to own the chip may call it. (`chip_smoke.py`, which must stay
+off JAX and stand alone, restates `CACHE_ROOT` to count entries.)
 """
 
 from __future__ import annotations
 
 import os
 import platform as _platform
+from typing import Optional
+
+CACHE_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_compile_cache")
 
 
-def cache_dir_for_backend(base: str, namespace: str = "") -> str:
-    """`base`/<backend>[-<machine>][-<namespace>] — resolved after
-    backend init."""
+def cache_dir_for_backend() -> str:
+    """`CACHE_ROOT`/<backend>[-<machine>] — resolved after backend
+    init (see the module docstring: this takes the chip)."""
     import jax
     backend = jax.default_backend()
-    suffix = backend
     if backend == "cpu":
-        # partition CPU artifacts by host ISA: AOT results embed machine
-        # features and do not transfer between host generations
-        suffix = "cpu-" + _platform.machine()
-    if namespace:
-        suffix += "-" + namespace
-    return os.path.join(base, suffix)
+        backend = "cpu-" + _platform.machine()
+    return os.path.join(CACHE_ROOT, backend)
 
 
-def enable_compile_cache(base: str,
-                         min_compile_secs: float = 2.0,
-                         namespace: str = "") -> str:
-    """Point JAX's persistent compilation cache at a platform-partitioned
-    subdirectory of `base`; returns the resolved directory.
-
-    `namespace` further isolates writers whose XLA tuning may differ
-    from other processes on the same host (e.g. the driver's CPU-mesh
-    dryrun): a namespace only ever loads artifacts it compiled itself,
-    so its log tail stays free of cpu_aot_loader feature-mismatch
-    noise by construction."""
+def enable_compile_cache() -> Optional[str]:
+    """Turn the persistent cache on under the rule above. Returns the
+    directory this module chose, or None when the environment placed
+    the cache and nothing was set here."""
     import jax
-    d = cache_dir_for_backend(base, namespace)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 2.0)
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    d = cache_dir_for_backend()
     os.makedirs(d, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", d)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      min_compile_secs)
     return d

@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import importlib.util
 import os
-import subprocess
 import sys
 import sysconfig
 import threading
@@ -28,27 +27,24 @@ K_ENUM, K_STRUCT, K_UNION = 10, 11, 12
 
 _PKG = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_PKG, "native", "src", "pyext", "xdr_codec.cpp")
-_BUILD = os.path.join(_PKG, "native", "build")
-_SO = os.path.join(_BUILD, "_scxdr.so")
 
 
 def build_ext(force: bool = False) -> str:
-    os.makedirs(_BUILD, exist_ok=True)
-    # >= : a fresh checkout gives source and prebuilt .so near-identical
-    # mtimes; treat that as up to date rather than demanding a toolchain
-    if (not force and os.path.exists(_SO)
-            and os.path.getmtime(_SO) >= os.path.getmtime(_SRC)):
-        return _SO
+    """Compile the extension into native/build/ under a name that
+    carries the digest of its source, flags, Python ABI and machine
+    (native/loader.py `built_path`): a stale or foreign file is never
+    opened, and a failed build is an error, not a quiet Python path."""
+    from ..native import loader
+    os.makedirs(loader._BUILD, exist_ok=True)
+    flags = ["-O3", "-std=c++17", "-shared", "-fPIC", "-fvisibility=hidden"]
+    with open(_SRC, "rb") as f:
+        src = f.read()
+    abi = sysconfig.get_config_var("SOABI") or sys.version
+    so = loader.built_path("_scxdr", [src, abi.encode()], flags)
+    if not force and os.path.exists(so):
+        return so
     inc = sysconfig.get_paths()["include"]
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-           "-fvisibility=hidden", f"-I{inc}", "-o", _SO, _SRC]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True)
-    except Exception:
-        if os.path.exists(_SO):   # stale beats none: the differential
-            return _SO            # tests gate correctness either way
-        raise
-    return _SO
+    return loader.compile_shared(so, ["g++"] + flags + [f"-I{inc}", _SRC])
 
 
 def _load_ext():
@@ -72,31 +68,22 @@ class NativeCodec:
         self.pack = None
         self.unpack = None
         self.clone = None
-        self._failed = False
 
     def refresh(self) -> None:
         from . import runtime
         with self._lock:
             if self.gen == runtime._XDR_GEN[0]:
                 return
-            if self._failed:
-                self.gen = runtime._XDR_GEN[0]
-                return
-            try:
-                if self.ext is None:
-                    self.ext = _load_ext()
-                self.cap = self._compile(runtime)
-                self.pack = self.ext.pack
-                self.unpack = self.ext.unpack
-                self.clone = self.ext.clone
-                self.gen = runtime._XDR_GEN[0]
-                self.ok = True
-            except Exception:
-                # no native toolchain / build failure: permanent Python
-                # fallback for this process
-                self._failed = True
-                self.ok = False
-                self.gen = runtime._XDR_GEN[0]
+            # a codec that cannot be built or compiled is an error:
+            # the Python path is ~1000x slower and would hide it
+            if self.ext is None:
+                self.ext = _load_ext()
+            self.cap = self._compile(runtime)
+            self.pack = self.ext.pack
+            self.unpack = self.ext.unpack
+            self.clone = self.ext.clone
+            self.gen = runtime._XDR_GEN[0]
+            self.ok = True
 
     def _compile(self, runtime):
         nodes: list = []
