@@ -17,6 +17,7 @@ from __future__ import annotations
 import io
 import os
 import tempfile
+import time
 from typing import Dict, List, Optional
 
 import threading
@@ -160,6 +161,7 @@ class GetRemoteFileWork(BasicWork):
         self.remote = remote
         self.local = local
         self._ev = None
+        self._t0 = 0.0       # perf_counter at the spawn of the command
 
     def on_reset(self) -> None:
         self._ev = None
@@ -171,16 +173,26 @@ class GetRemoteFileWork(BasicWork):
             os.makedirs(os.path.dirname(os.path.abspath(self.local)),
                         exist_ok=True)
             cmd = self.archive.get_file_cmd(self.remote, self.local)
+            self._t0 = time.perf_counter()
+            if tracing.ENABLED:
+                rec = self.app.flight_recorder
+                if rec.active:
+                    # one async span per fetched archive file, from the
+                    # spawn of the `get` command to its exit code: the
+                    # clock is cranked many times in between
+                    rec.async_begin("catchup.download", self.remote,
+                                    {"remote": self.remote})
             self._ev = self.app.process_manager.run_process(
                 cmd, lambda code: self.wake_up())
             return State.WORK_WAITING
         if self._ev.exit_code is None:
             return State.WORK_WAITING
+        self.app.metrics.new_timer("catchup.download.wall").update(
+            time.perf_counter() - self._t0)
         if tracing.ENABLED:
             rec = self.app.flight_recorder
             if rec.active:
-                # history work-step marker: one per fetched archive file
-                rec.instant("catchup.download", {
+                rec.async_end("catchup.download", self.remote, {
                     "remote": self.remote, "exit": self._ev.exit_code})
         if self._ev.exit_code == 0 and os.path.exists(self.local):
             return State.WORK_SUCCESS
@@ -449,6 +461,10 @@ class ApplyCheckpointWork(BasicWork):
         self._get: Optional[GetRemoteFileWork] = None
         self._next_seq: Optional[int] = None
         self._pending_batch = None   # (tuples, resolver future)
+        # perf_counter at the dispatch of the batch, and the verifier's
+        # running number for it (None from a verifier that keeps none)
+        self._batch_t0 = 0.0
+        self._batch_id = None
         self._frame_sets: Dict[int, TxSetFrame] = {}
         self._prefetch_failed = False
         # seconds the FIRST result probe may wait (see
@@ -605,19 +621,28 @@ class ApplyCheckpointWork(BasicWork):
                         exc_info=True)
             return
         self._pending_batch = (tuples, fut)
+        self._batch_t0 = time.perf_counter()
+        self._batch_id = getattr(self.batch_verifier, "last_batch_id", None)
+        # the table exists, empty, from the dispatch on: a check that
+        # apply makes before the batch has landed is a counted miss
+        # (and verified by the fallback, as before), so hits + misses
+        # are all the checks of this checkpoint's applies
+        from ..tx.signature_checker import (PrevalidatedVerifier,
+                                            default_verify)
+        self.prevalidated = PrevalidatedVerifier(
+            fallback=self.verify or default_verify)
         log.info("checkpoint %d: dispatched batch of %d signatures",
                  self.checkpoint, len(tuples))
 
-    def _resolve_prevalidated(self) -> None:
-        """Adopt the dispatched batch's results once available.  The
+    def _resolve_prevalidated(self, seq: int) -> None:
+        """Adopt the dispatched batch's results once available (`seq`
+        is the ledger about to apply, the first to use them).  The
         first probe grants a short grace (`batch_grace` seconds) — worth
         a bounded stall to catch a nearly-landed batch — after which the
         probe is non-blocking and the sync fallback covers the in-flight
         gap, so apply never waits on the device."""
         if self._pending_batch is None:
             return
-        from ..tx.signature_checker import (PrevalidatedVerifier,
-                                            default_verify)
         tuples, fut = self._pending_batch
         try:
             if self._grace_spent or self.batch_grace <= 0:
@@ -636,24 +661,60 @@ class ApplyCheckpointWork(BasicWork):
                         "collection; native fallback", self.checkpoint,
                         exc_info=True)
             self._pending_batch = None
+            # an empty table would only cost a key and a miss per
+            # check: publish what it was asked so far and go back to
+            # the plain verifier
+            self._retire_prevalidated(drop=True)
             return
         self._pending_batch = None
-        pv = PrevalidatedVerifier(fallback=self.verify or default_verify)
-        pv.add_results(tuples, results)
-        self.prevalidated = pv
+        self.prevalidated.add_results(tuples, results)
+        # dispatch to adoption: the dispatch's own wall time
+        # (crypto.verify.dispatch.wall) plus the time the landed batch
+        # waited for apply to look
+        self.app.metrics.new_timer("catchup.batch.adoptLag").update(
+            time.perf_counter() - self._batch_t0)
+        if tracing.ENABLED:
+            rec = self.app.flight_recorder
+            if rec.active:
+                rec.instant("catchup.batch.adopted", {
+                    "checkpoint": self.checkpoint,
+                    "batch": self._batch_id, "seq": seq,
+                    "n": len(tuples)})
         log.info("checkpoint %d: batch-verified %d signatures",
                  self.checkpoint, len(tuples))
+
+    def _retire_prevalidated(self, drop: bool = False) -> None:
+        """Publish what the table was asked (crypto.prevalidated.hit /
+        .miss). Called once per table: when this work ends (the hooks
+        below; the table stays readable), or with `drop` where the
+        table goes before the work ends."""
+        if self.prevalidated is not None:
+            self.prevalidated.publish(self.app.metrics)
+            if drop:
+                self.prevalidated = None
+
+    def on_success(self) -> None:
+        self._retire_prevalidated()
+
+    def on_failure_raise(self) -> None:
+        self._retire_prevalidated()
+
+    def on_abort(self) -> None:
+        self._retire_prevalidated()
 
     def drain(self, timeout: float) -> None:
         """Wait (bounded) for a dispatched batch that replay outran:
         its collect thread is inside the device runtime, and a process
         that exits under it aborts instead of returning its exit code;
-        and only a settled batch shows in the supervisor's status."""
+        and only a settled batch shows in the supervisor's status. A
+        work abandoned before its end publishes its table here."""
         if self._pending_batch is not None:
             self._pending_batch[1].wait(timeout)
+        if not self.is_done():
+            self._retire_prevalidated(drop=True)
 
     def _apply_one(self, lm, seq: int, hhe) -> bool:
-        self._resolve_prevalidated()
+        self._resolve_prevalidated(seq)
         the = self._txs_by_seq.get(seq)
         frame = self._frame_sets.pop(seq, None) if the is not None else None
         if frame is None:

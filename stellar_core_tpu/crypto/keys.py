@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import hashlib
 import os
+import time
 from typing import Optional
 
 try:
@@ -42,11 +43,44 @@ VERIFY_CACHE_SIZE = 0xFFFF
 _verify_cache: RandomEvictionCache = RandomEvictionCache(VERIFY_CACHE_SIZE)
 
 
+# per-signature host verifies (every `verify_sig_uncached`): how many,
+# and the seconds they took. Plain module attributes beside
+# `_verify_cache.hits/.misses`, like them process-wide and like them
+# drained by `publish_verify_counts`; no lock, so two threads that
+# verify at once (staged apply's workers) can lose an update.
+_native_count = 0
+_native_seconds = 0.0
+
+
 def flush_verify_cache_counts() -> tuple:
     """Return (hits, misses) and reset (reference: SecretKey.cpp:324-331)."""
     h, m = _verify_cache.hits, _verify_cache.misses
     _verify_cache.reset_counters()
     return h, m
+
+
+def publish_verify_counts(metrics, perf) -> None:
+    """Drain the process-wide verify counts into a node's two
+    registries: the zone `crypto.verify.native` of `perf` (per-signature
+    host verifies and their seconds, through `ZoneRegistry.add`) and the
+    meters `crypto.verify.cache.hit` / `.miss` of `metrics`. The one
+    reader of those counts: every ledger close and the `metrics` admin
+    route call it, so a count lands in exactly one node. In a process
+    with several nodes that is whichever node drains next, not the node
+    that verified. The meters always exist (zero-valued) so scrapers
+    see stable families."""
+    global _native_count, _native_seconds
+    n, sec = _native_count, _native_seconds
+    _native_count, _native_seconds = 0, 0.0
+    h, m = flush_verify_cache_counts()
+    hit = metrics.meter("crypto", "verify", "cache", "hit")
+    miss = metrics.meter("crypto", "verify", "cache", "miss")
+    if n:
+        perf.add("crypto.verify.native", sec, n)
+    if h:
+        hit.mark(h)
+    if m:
+        miss.mark(m)
 
 
 def clear_verify_cache() -> None:
@@ -190,10 +224,20 @@ class PubKeyUtils:
 
 
 def verify_sig_uncached(pub: bytes, sig: bytes, msg: bytes) -> bool:
+    """One signature verified on the host, counted and timed (see
+    `publish_verify_counts`). `verify_sig`'s miss path, the device
+    verifiers' small-batch bypass and the supervisor's native answers
+    all come through here."""
+    global _native_count, _native_seconds
+    t0 = time.perf_counter()
     lib = _native_verify()
     if lib is not None:
-        return lib.verify(pub, sig, msg)
-    return _verify_strict_openssl(pub, sig, msg)
+        ok = lib.verify(pub, sig, msg)
+    else:
+        ok = _verify_strict_openssl(pub, sig, msg)
+    _native_count += 1
+    _native_seconds += time.perf_counter() - t0
+    return ok
 
 
 def _verify_strict_openssl(pub: bytes, sig: bytes, msg: bytes) -> bool:

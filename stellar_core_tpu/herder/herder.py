@@ -99,6 +99,12 @@ class Herder:
         # _ledger_closed for the e2e timer + trace track, pruned so
         # never-externalized txs cannot grow it without bound
         self._tx_submit_times: dict = {}
+        # recv_transaction's own count and seconds since the last
+        # close: plain attributes on the per-transaction path,
+        # published as the zone `herder.recvTransaction` once per close
+        # (_ledger_closed)
+        self._recv_count = 0
+        self._recv_seconds = 0.0
         # hash-keyed propagation tracker (overlay/propagation.py), set
         # by Application; admission/externalize stamps land here so the
         # mesh observatory sees the full flood→admit→externalize path
@@ -179,7 +185,18 @@ class Herder:
         Herder::recvTransaction :523). `verify` overrides the
         per-signature backend for this admission (the batched flood
         path passes a PrevalidatedVerifier seeded by one device
-        batch)."""
+        batch). Timed here with two clock reads into plain attributes
+        (see `_recv_count`) and no span, not even while a trace is on:
+        60,000 spans a checkpoint on the closing thread are more than
+        a reader of the recording should have to wade through."""
+        t0 = time.perf_counter()
+        try:
+            return self._recv_transaction(tx, verify)
+        finally:
+            self._recv_seconds += time.perf_counter() - t0
+            self._recv_count += 1
+
+    def _recv_transaction(self, tx, verify) -> AddResult:
         if verify is None and self.controller is not None and \
                 self.controller.roll_tx_shed():
             # surge shedding (ops/controller.py): an overloaded node
@@ -242,6 +259,7 @@ class Herder:
         into try_add so nothing verifies twice)."""
         verify = self._verify
         svc = self.verify_service
+        pv = None
         if frames and (svc is not None or bad_sig is not None):
             from ..tx.signature_checker import (PrevalidatedVerifier,
                                                 collect_signature_tuples,
@@ -284,7 +302,11 @@ class Herder:
                 for ts in per_frame:
                     rs = [next(it) for _ in ts]
                     bad_sig.append(bool(ts) and not all(rs))
-        return [self.recv_transaction(f, verify=verify) for f in frames]
+        out = [self.recv_transaction(f, verify=verify) for f in frames]
+        if pv is not None:
+            # end of the burst: what the batch answered at admission
+            pv.publish(self._metrics)
+        return out
 
     def _advert_or_queue(self, tx) -> None:
         """Advert now, or queue into the lane's budgeted flood drain
@@ -362,30 +384,36 @@ class Herder:
         directly; under SCP this is where nomination starts."""
         lcl_header = self.ledger_manager.get_last_closed_ledger_header()
         next_seq = lcl_header.ledgerSeq + 1
-        candidates, invalid = trim_invalid(
-            self.tx_queue.get_transactions(), self.ledger_manager.root,
-            verify=self._verify)
-        if invalid:
-            # reference: Herder::triggerNextLedger bans trimInvalid's
-            # output so stale txs stop being re-validated every trigger
-            self.tx_queue.ban(invalid)
-        frame, applicable, excluded = make_tx_set_from_transactions(
-            candidates, lcl_header, self.network_id)
+        targs = {"seq": next_seq} if tracing.ENABLED else None
+        with self.perf.zone("herder.triggerNextLedger", targs=targs):
+            with self.perf.zone("herder.trimInvalid", targs=targs):
+                candidates, invalid = trim_invalid(
+                    self.tx_queue.get_transactions(),
+                    self.ledger_manager.root, verify=self._verify)
+            if invalid:
+                # reference: Herder::triggerNextLedger bans trimInvalid's
+                # output so stale txs stop being re-validated every
+                # trigger
+                self.tx_queue.ban(invalid)
+            with self.perf.zone("herder.makeTxSet", targs=targs):
+                frame, applicable, excluded = make_tx_set_from_transactions(
+                    candidates, lcl_header, self.network_id)
 
-        close_time = self._next_close_time(lcl_header)
-        upgrade_steps = self._propose_upgrades(lcl_header, close_time)
-        value = StellarValue(
-            txSetHash=frame.get_contents_hash(),
-            closeTime=close_time,
-            upgrades=[u.to_bytes() for u in upgrade_steps],
-            ext=_StellarValueExt(StellarValueType.STELLAR_VALUE_BASIC))
-        self.externalize_value(next_seq, value, applicable)
-        # manual/standalone close is a synchronous contract: the caller
-        # (admin `manualclose`, tests) reads close artifacts the moment
-        # this returns, so join the deferred completion tail. The
-        # SCP-driven path keeps the pipeline — the next close's own
-        # barrier gates it instead.
-        self.ledger_manager.join_completion()
+            close_time = self._next_close_time(lcl_header)
+            upgrade_steps = self._propose_upgrades(lcl_header, close_time)
+            value = StellarValue(
+                txSetHash=frame.get_contents_hash(),
+                closeTime=close_time,
+                upgrades=[u.to_bytes() for u in upgrade_steps],
+                ext=_StellarValueExt(StellarValueType.STELLAR_VALUE_BASIC))
+            self.externalize_value(next_seq, value, applicable)
+            # manual/standalone close is a synchronous contract: the
+            # caller (admin `manualclose`, tests) reads close artifacts
+            # the moment this returns, so join the deferred completion
+            # tail. The SCP-driven path keeps the pipeline — the next
+            # close's own barrier gates it instead.
+            with self.perf.zone("herder.joinCompletion", targs=targs):
+                self.ledger_manager.join_completion()
 
     def _propose_upgrades(self, lcl_header, close_time: int):
         """Vote upgrades against current ledger state (the Soroban
@@ -404,12 +432,18 @@ class Herder:
         if self._verify is not None:
             kwargs["verify"] = self._verify
         self.ledger_manager.close_ledger(lcd, **kwargs)
-        self._ledger_closed(tx_set)
+        targs = {"seq": ledger_seq} if tracing.ENABLED else None
+        with self.perf.zone("herder.ledgerClosed", targs=targs):
+            self._ledger_closed(tx_set)
 
     def _ledger_closed(self, tx_set) -> None:
         """Queue maintenance after close (reference:
         TransactionQueue::removeApplied + shift, called from
         HerderImpl::updateTransactionQueue)."""
+        if self._recv_count:
+            self.perf.add("herder.recvTransaction", self._recv_seconds,
+                          self._recv_count)
+            self._recv_count, self._recv_seconds = 0, 0.0
         self._record_tx_e2e(tx_set)
         self.tx_queue.remove_applied(tx_set.txs)
         self.tx_queue.shift()

@@ -11,6 +11,7 @@ from __future__ import annotations
 
 import os
 import tempfile
+import time
 from typing import Optional
 
 from ..bucket.manager import BucketManager
@@ -66,7 +67,12 @@ class Application:
     @classmethod
     def create(cls, clock: VirtualClock, config: Config,
                new_db: bool = True) -> "Application":
-        return cls(clock, config, new_db=new_db)
+        # reported at the end: the zone registry is the app's own and
+        # does not exist when this begins
+        t0 = time.perf_counter()
+        app = cls(clock, config, new_db=new_db)
+        app.perf.add("app.create", time.perf_counter() - t0)
+        return app
 
     def __init__(self, clock: VirtualClock, config: Config,
                  new_db: bool = True):
@@ -445,52 +451,53 @@ class Application:
     def start(self) -> None:
         """reference: ApplicationImpl::start :782 — load LCL or create
         genesis, then bring the herder up."""
-        if not self.ledger_manager.load_last_known_ledger():
-            # reference: USE_CONFIG_FOR_GENESIS — off means a protocol-0
-            # genesis whose upgrades arrive through consensus voting
-            genesis_protocol = self.config.LEDGER_PROTOCOL_VERSION \
-                if self.config.USE_CONFIG_FOR_GENESIS else 0
-            self.ledger_manager.start_new_ledger(
-                self.config.network_id(), genesis_protocol)
-            self.persistent_state.set(
-                StateEntry.LAST_CLOSED_LEDGER,
-                self.ledger_manager.get_last_closed_ledger_hash().hex())
-        # boot snapshot: the read tier answers from the LCL before the
-        # first close of this process ever lands
-        self.snapshots.on_ledger_closed(
-            self.ledger_manager.get_last_closed_ledger_header(),
-            self.ledger_manager.get_last_closed_ledger_hash())
-        self.herder.start()
-        if self.overlay_manager is not None:
-            self.overlay_manager.start()
-        if self.config.FORCE_SCP and not self.config.MANUAL_CLOSE \
-                and self.herder.scp is not None \
-                and self.config.NODE_IS_VALIDATOR:
-            self.herder.bootstrap()
-        self.state = AppState.APP_SYNCED_STATE
-        self.telemetry.start()
-        self.controller.start()
-        if self.config.AUTOMATIC_SELF_CHECK_PERIOD > 0:
-            self._arm_self_check_timer()
-        if self.config.AUTOMATIC_MAINTENANCE_PERIOD > 0:
-            # cron-like history GC (reference: Maintainer::start with
-            # AUTOMATIC_MAINTENANCE_PERIOD/_COUNT)
-            self.maintainer.start(
-                self.config.AUTOMATIC_MAINTENANCE_PERIOD,
-                self.config.AUTOMATIC_MAINTENANCE_COUNT)
-        if self.config.ARTIFICIALLY_SLEEP_MAIN_THREAD_FOR_TESTING_US > 0:
-            # models a slow main thread: every crank pays the sleep
-            # (reference: ARTIFICIALLY_SLEEP_MAIN_THREAD_FOR_TESTING)
-            import time as _time
-            us = self.config.ARTIFICIALLY_SLEEP_MAIN_THREAD_FOR_TESTING_US
+        with self.perf.zone("app.start"):
+            if not self.ledger_manager.load_last_known_ledger():
+                # reference: USE_CONFIG_FOR_GENESIS — off means a protocol-0
+                # genesis whose upgrades arrive through consensus voting
+                genesis_protocol = self.config.LEDGER_PROTOCOL_VERSION \
+                    if self.config.USE_CONFIG_FOR_GENESIS else 0
+                self.ledger_manager.start_new_ledger(
+                    self.config.network_id(), genesis_protocol)
+                self.persistent_state.set(
+                    StateEntry.LAST_CLOSED_LEDGER,
+                    self.ledger_manager.get_last_closed_ledger_hash().hex())
+            # boot snapshot: the read tier answers from the LCL before the
+            # first close of this process ever lands
+            self.snapshots.on_ledger_closed(
+                self.ledger_manager.get_last_closed_ledger_header(),
+                self.ledger_manager.get_last_closed_ledger_hash())
+            self.herder.start()
+            if self.overlay_manager is not None:
+                self.overlay_manager.start()
+            if self.config.FORCE_SCP and not self.config.MANUAL_CLOSE \
+                    and self.herder.scp is not None \
+                    and self.config.NODE_IS_VALIDATOR:
+                self.herder.bootstrap()
+            self.state = AppState.APP_SYNCED_STATE
+            self.telemetry.start()
+            self.controller.start()
+            if self.config.AUTOMATIC_SELF_CHECK_PERIOD > 0:
+                self._arm_self_check_timer()
+            if self.config.AUTOMATIC_MAINTENANCE_PERIOD > 0:
+                # cron-like history GC (reference: Maintainer::start with
+                # AUTOMATIC_MAINTENANCE_PERIOD/_COUNT)
+                self.maintainer.start(
+                    self.config.AUTOMATIC_MAINTENANCE_PERIOD,
+                    self.config.AUTOMATIC_MAINTENANCE_COUNT)
+            if self.config.ARTIFICIALLY_SLEEP_MAIN_THREAD_FOR_TESTING_US > 0:
+                # models a slow main thread: every crank pays the sleep
+                # (reference: ARTIFICIALLY_SLEEP_MAIN_THREAD_FOR_TESTING)
+                import time as _time
+                us = self.config.ARTIFICIALLY_SLEEP_MAIN_THREAD_FOR_TESTING_US
 
-            def _sleepy_poller() -> int:
-                _time.sleep(us / 1e6)
-                return 0
+                def _sleepy_poller() -> int:
+                    _time.sleep(us / 1e6)
+                    return 0
 
-            self.clock.add_io_poller(_sleepy_poller)
-        log.info("application started at ledger %d",
-                 self.ledger_manager.get_last_closed_ledger_num())
+                self.clock.add_io_poller(_sleepy_poller)
+            log.info("application started at ledger %d",
+                     self.ledger_manager.get_last_closed_ledger_num())
 
     def _arm_self_check_timer(self) -> None:
         """Recurring background self-check (reference: scheduleSelfCheck,
@@ -536,13 +543,23 @@ class Application:
         return n
 
     def shutdown(self) -> None:
+        try:
+            with self.perf.zone("app.shutdown"):
+                self._shutdown()
+        finally:
+            # last, so that a recording holds the whole shutdown (the
+            # completion tail it waits for is the longest wait of a
+            # catchup); in a `finally`, to release the process-wide
+            # tracing.ENABLED refcount even if shutdown raises — a dead
+            # app must not keep every other node paying for spans. The
+            # buffer stays dumpable after stop().
+            if self.flight_recorder.active:
+                self.flight_recorder.stop()
+
+    def _shutdown(self) -> None:
         self.state = AppState.APP_STOPPING_STATE
         self.telemetry.stop()
         self.controller.stop()
-        if self.flight_recorder.active:
-            # release the process-wide tracing.ENABLED refcount — a
-            # dead app must not keep every other node paying for spans
-            self.flight_recorder.stop()
         if getattr(self, "_self_check_timer", None) is not None:
             self._self_check_timer.cancel()
             self._self_check_timer = None
@@ -564,7 +581,8 @@ class Application:
         self.bucket_manager.shutdown()
         # drain the deferred close-completion tail before touching the
         # meta stream/debug files or closing the database under it
-        self.ledger_manager.join_completion(reraise=False)
+        with self.perf.zone("app.shutdown.joinCompletion"):
+            self.ledger_manager.join_completion(reraise=False)
         self.ledger_manager.flush_delayed_meta()
         if self._meta_file is not None:
             self._meta_file.close()

@@ -95,38 +95,38 @@ class CommandHandler:
     def _info(self, params) -> dict:
         return {"info": self.app.info()}
 
-    def _sync_verify_cache_meters(self) -> None:
-        """Drain the process-wide verify-cache hit/miss counters (only
-        reachable via flush_verify_cache_counts before) into
-        crypto.verify.cache.{hit,miss} meters, so they ride the metrics
-        route and the Prometheus exposition like every other metric.
-        The meters always exist (zero-valued) so scrapers see stable
-        families."""
-        from ..crypto.keys import flush_verify_cache_counts
-        h, m = flush_verify_cache_counts()
-        hit = self.app.metrics.meter("crypto", "verify", "cache", "hit")
-        miss = self.app.metrics.meter("crypto", "verify", "cache", "miss")
-        if h:
-            hit.mark(h)
-        if m:
-            miss.mark(m)
+    @staticmethod
+    def _process_zones() -> dict:
+        """The process-wide `jax.*` zones (JAX's tracing, lowering,
+        compiles and persistent-cache lookups: util/jax_cache.py).
+        They belong to no one node, so they ride beside `perf_zones`
+        under a key of their own, and `clearmetrics` / `perf?reset=1`
+        leave them alone."""
+        from ..util.perf import default_registry
+        return {name: z for name, z in default_registry.report().items()
+                if name.startswith("jax.")}
 
     def _metrics(self, params) -> dict:
         # perf zones ride along so the per-phase closeLedger breakdown
         # (ledger.close.applyTx / .seal / .complete, …) is visible from
         # the same admin endpoint operators already scrape
-        self._sync_verify_cache_meters()
+        # the process-wide verify counts (zone crypto.verify.native,
+        # meters crypto.verify.cache.hit/.miss) ride the metrics route
+        # and the Prometheus exposition like every other
+        from ..crypto.keys import publish_verify_counts
+        publish_verify_counts(self.app.metrics, self.app.perf)
         if params.get("format") == "prometheus":
             # text exposition for scrapers: the whole MetricsRegistry
             # plus the zone report as labeled gauge families
             from ..util.metrics import render_prometheus
             return {"_raw_body": render_prometheus(
                         self.app.metrics.to_json(),
-                        self.app.perf.report()),
+                        self.app.perf.report(), self._process_zones()),
                     "_content_type":
                         "text/plain; version=0.0.4; charset=utf-8"}
         out = {"metrics": self.app.metrics.to_json(),
-               "perf_zones": self.app.perf.report()}
+               "perf_zones": self.app.perf.report(),
+               "process_zones": self._process_zones()}
         from ..util import chaos
         if chaos.ENABLED:
             # chaos.injected.* counters surface beside the metrics an
@@ -135,6 +135,11 @@ class CommandHandler:
         return out
 
     def _clear_metrics(self, params) -> dict:
+        # what the process counted before the clear (crypto/keys.py) is
+        # drained first, so that it goes with the rest and the next
+        # `metrics` call does not bring it back
+        from ..crypto.keys import publish_verify_counts
+        publish_verify_counts(self.app.metrics, self.app.perf)
         self.app.metrics.clear()
         # the zone registry is the same operator surface: clearing one
         # and not the other left `perf` reporting stale zones forever
@@ -641,7 +646,7 @@ class CommandHandler:
         report = self.app.perf.report()
         if params.get("reset") in ("1", "true"):
             self.app.perf.reset()
-        return {"perf": report}
+        return {"perf": report, "process_zones": self._process_zones()}
 
     def _chaos(self, params) -> dict:
         """Runtime chaos control: chaos?mode=status|install|clear.

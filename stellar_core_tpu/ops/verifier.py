@@ -41,6 +41,7 @@ runs the identical per-lane kernel; only the shard layout moves.
 from __future__ import annotations
 
 import time as _time
+from types import SimpleNamespace
 from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -51,7 +52,7 @@ from jax import shard_map
 
 from . import ed25519_kernel
 from .shard_math import shard_shares
-from ..util import chaos
+from ..util import chaos, tracing
 
 MIN_BUCKET = 8
 
@@ -227,47 +228,69 @@ class TpuBatchVerifier:
         burnt on the power-of-two bucket), and dispatch→collect wall
         time — the per-device health signals a per-device breaker will
         consume. None = accounting off (the bench/test constructors)."""
+        # running number of the batches given to verify_tuples_async:
+        # the `batch` arg of every span in the life of one batch (pack,
+        # enqueue, collect here; adoption in catchup), on whichever
+        # thread it runs. Read it right after the dispatch returns.
+        self.last_batch_id = 0
         if metrics is None:
             self._m_batch = self._m_padding = self._m_wall = None
+            self._m_host = None
             return
         self._m_batch = metrics.new_histogram(
             "crypto.verify.dispatch.batch")
         self._m_padding = metrics.new_histogram(
             "crypto.verify.dispatch.padding")
         self._m_wall = metrics.new_timer("crypto.verify.dispatch.wall")
+        # entry of verify_tuples_async to the return of the enqueue:
+        # what a dispatch costs the thread that makes it
+        self._m_host = metrics.new_timer("crypto.verify.dispatch.host")
 
     def verify_batch(self, pubs: np.ndarray, sigs: np.ndarray,
                      msgs: Sequence[bytes]) -> np.ndarray:
         return self.verify_batch_async(pubs, sigs, msgs)()
 
     def verify_batch_async(self, pubs: np.ndarray, sigs: np.ndarray,
-                           msgs: Sequence[bytes]):
+                           msgs: Sequence[bytes], _active=None):
         """Dispatch a batch without blocking; returns a zero-arg callable
         that yields the (n,) bool results. Callers with several batches in
         flight (catchup prevalidation, the bench harness) overlap host
-        SHA-512 + transfer of batch i+1 with device compute of batch i."""
-        n = len(msgs)
-        if n == 0:
+        SHA-512 + transfer of batch i+1 with device compute of batch i.
+        `_active` pins an explicit device set on a mesh verifier (the
+        per-device canary probe path); None uses the live mesh."""
+        if len(msgs) == 0:
             return lambda: np.zeros(0, dtype=bool)
+        return self._enqueue(self._pack(pubs, sigs, msgs, _active))
+
+    def _pack(self, pubs: np.ndarray, sigs: np.ndarray,
+              msgs: Sequence[bytes], active=None) -> SimpleNamespace:
+        """Host half of a dispatch: the padded arrays of the batch's
+        bucket and the program that takes them."""
+        n = len(msgs)
         pubs = np.asarray(pubs, dtype=np.uint8).reshape(n, 32)
         sigs = np.asarray(sigs, dtype=np.uint8).reshape(n, 64)
         bucket = _bucket_size(n, self._min_bucket)
         if self._device_sha and all(len(m) == 32 for m in msgs):
             # tx-hash hot path: ship M raw, SHA-512 + mod L on device —
             # zero per-signature host work (docs/KERNEL_PROFILE.md §4)
-            m = np.frombuffer(b"".join(msgs), dtype=np.uint8).reshape(n, 32)
-            out = self._jit_msg32(
-                _pad_u8(pubs, bucket),
-                _pad_u8(sigs[:, :32], bucket),
-                _pad_u8(np.ascontiguousarray(sigs[:, 32:]), bucket),
-                _pad_u8(m, bucket))
+            fn = self._jit_msg32
+            last = np.frombuffer(b"".join(msgs),
+                                 dtype=np.uint8).reshape(n, 32)
         else:
-            k = host_k(pubs, sigs, msgs)
-            out = self._jit(
-                _pad_u8(pubs, bucket),
+            fn = self._jit
+            last = host_k(pubs, sigs, msgs)
+        args = (_pad_u8(pubs, bucket),
                 _pad_u8(sigs[:, :32], bucket),
                 _pad_u8(np.ascontiguousarray(sigs[:, 32:]), bucket),
-                _pad_u8(k, bucket))
+                _pad_u8(last, bucket))
+        return SimpleNamespace(fn=fn, args=args, n=n, bucket=bucket)
+
+    def _enqueue(self, d: SimpleNamespace):
+        """Device half: the jit call (transfer and launch; on a shape's
+        first call also its trace, lowering and compile) and the
+        collect callable."""
+        out = d.fn(*d.args)
+        n = d.n
         if self._m_batch is None:
             return lambda: np.asarray(out)[:n]
         # dispatch accounting: occupancy and padding recorded at
@@ -275,7 +298,7 @@ class TpuBatchVerifier:
         # collect blocks on device completion, so first-collect wall
         # is the true dispatch→results latency)
         self._m_batch.update(n)
-        self._m_padding.update(bucket - n)
+        self._m_padding.update(d.bucket - n)
         t0 = _time.perf_counter()
         state = {"done": False}
 
@@ -308,29 +331,42 @@ class TpuBatchVerifier:
             # Fired before the small-batch bypass decision so the seam
             # contract is batch-size independent.
             chaos.point("ops.verifier.batch", n=len(items))
-        from ..util import tracing
+        t_in = _time.perf_counter()
         from ..util.perf import default_registry
         registry = self.perf or default_registry
-        targs = {"batch": len(items)} if tracing.ENABLED else None
         if len(items) < self._device_min_batch:
             # small-batch CPU bypass: the fixed device dispatch cost
             # loses to the native verifier below the cutoff, so tiny
             # flushes (the verify service's deadline stragglers) stay
             # on host — same strict accept/reject either way
             from ..crypto.keys import verify_sig_uncached
+            targs = {"n": len(items)} if tracing.ENABLED else None
             with registry.zone("crypto.batchVerify.native", targs=targs):
                 res = [verify_sig_uncached(p, s, m) for p, s, m in items]
             return lambda: res
+        self.last_batch_id = batch = self.last_batch_id + 1
+        # one dict for every span of this batch, on both threads;
+        # `bucket` is known once the batch is packed
+        targs = {"batch": batch, "n": len(items)} \
+            if tracing.ENABLED else None
         with registry.zone("crypto.batchVerify", targs=targs):
-            pubs = np.frombuffer(b"".join(p for p, _, _ in items),
-                                 dtype=np.uint8).reshape(-1, 32)
-            sigs = np.frombuffer(b"".join(s for _, s, _ in items),
-                                 dtype=np.uint8).reshape(-1, 64)
-            handle = self.verify_batch_async(pubs, sigs,
-                                             [m for _, _, m in items])
+            with registry.zone("crypto.batchVerify.pack", targs=targs):
+                pubs = np.frombuffer(b"".join(p for p, _, _ in items),
+                                     dtype=np.uint8).reshape(-1, 32)
+                sigs = np.frombuffer(b"".join(s for _, s, _ in items),
+                                     dtype=np.uint8).reshape(-1, 64)
+                packed = self._pack(pubs, sigs, [m for _, _, m in items])
+                if targs is not None:
+                    targs["bucket"] = packed.bucket
+            with registry.zone("crypto.batchVerify.enqueue", targs=targs):
+                handle = self._enqueue(packed)
+        if self._m_host is not None:
+            self._m_host.update(_time.perf_counter() - t_in)
 
         def collect():
-            with registry.zone("crypto.batchVerify", targs=targs):
+            with registry.zone("crypto.batchVerify", targs=targs), \
+                    registry.zone("crypto.batchVerify.collect",
+                                  targs=targs):
                 return list(handle())
         return collect
 
@@ -488,15 +524,13 @@ class ShardedBatchVerifier(TpuBatchVerifier):
             for i in range(self.ndev)]
 
     # -------------------------------------------------------- dispatch --
-    def verify_batch_async(self, pubs: np.ndarray, sigs: np.ndarray,
-                           msgs: Sequence[bytes], _active=None):
-        """Mesh dispatch: padded per-shard buckets over the active
-        devices. `_active` pins an explicit set (the per-device canary
-        probe path); None uses the live mesh."""
+    def _pack(self, pubs: np.ndarray, sigs: np.ndarray,
+              msgs: Sequence[bytes], active=None) -> SimpleNamespace:
+        """Mesh dispatch, host half: padded per-shard buckets over the
+        active devices (`active` pins an explicit set, None uses the
+        live mesh) and the program of that set."""
         n = len(msgs)
-        if n == 0:
-            return lambda: np.zeros(0, dtype=bool)
-        active = tuple(_active) if _active is not None else self._active
+        active = tuple(active) if active is not None else self._active
         nact = len(active)
         pubs = np.asarray(pubs, dtype=np.uint8).reshape(n, 32)
         sigs = np.asarray(sigs, dtype=np.uint8).reshape(n, 64)
@@ -519,7 +553,7 @@ class ShardedBatchVerifier(TpuBatchVerifier):
         msg32 = self._device_sha and all(len(m) == 32 for m in msgs)
         if msg32:
             # tx-hash hot path: SHA-512 + mod L on device (see
-            # TpuBatchVerifier.verify_batch_async)
+            # TpuBatchVerifier._pack)
             last = np.frombuffer(b"".join(msgs),
                                  dtype=np.uint8).reshape(n, 32)
         else:
@@ -527,9 +561,16 @@ class ShardedBatchVerifier(TpuBatchVerifier):
         args = (layout(pubs), layout(sigs[:, :32]),
                 layout(np.ascontiguousarray(sigs[:, 32:])), layout(last))
         fn, pin = self._program(active, msg32)
-        if pin is not None:
-            args = tuple(jax.device_put(a, pin) for a in args)
-        out = fn(*args)
+        return SimpleNamespace(fn=fn, args=args, n=n, bucket=bucket,
+                               pin=pin, active=active, rows=rows,
+                               counts=counts)
+
+    def _enqueue(self, d: SimpleNamespace):
+        args, active, rows, counts = d.args, d.active, d.rows, d.counts
+        nact = len(active)
+        if d.pin is not None:
+            args = tuple(jax.device_put(a, d.pin) for a in args)
+        out = d.fn(*args)
 
         def unshard(res: np.ndarray) -> np.ndarray:
             parts = [res[s * rows:s * rows + counts[s]]
@@ -538,8 +579,8 @@ class ShardedBatchVerifier(TpuBatchVerifier):
 
         if self._m_batch is None:
             return lambda: unshard(np.asarray(out))
-        self._m_batch.update(n)
-        self._m_padding.update(bucket - n)
+        self._m_batch.update(d.n)
+        self._m_padding.update(d.bucket - d.n)
         for s, c in enumerate(counts):
             dm = self._m_dev[active[s]]
             dm["batch"].update(c)

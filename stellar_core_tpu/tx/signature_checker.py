@@ -32,7 +32,10 @@ def default_verify(pub: bytes, sig: bytes, msg: bytes) -> bool:
 class PrevalidatedVerifier:
     """Lookup table of (pub, sig, msg) -> bool filled by one TPU batch
     verify; falls back to the sync path on miss (stragglers keep exact
-    semantics, SURVEY.md §7 'latency vs batch')."""
+    semantics, SURVEY.md §7 'latency vs batch').
+
+    `hits` and `misses` are plain attributes (the call is per
+    signature); the owner calls `publish` when it retires the table."""
 
     def __init__(self, fallback: VerifyFn = default_verify):
         self._results: Dict[bytes, bool] = {}
@@ -56,6 +59,17 @@ class PrevalidatedVerifier:
             return r
         self.misses += 1
         return self._fallback(pub, sig, msg)
+
+    def publish(self, metrics) -> None:
+        """Add `hits` and `misses` to the counters
+        `crypto.prevalidated.hit` / `.miss` of `metrics`: the checks
+        the batch answered, and those it was asked and had to hand to
+        the fallback. The owner calls it once, when it retires the
+        table."""
+        if metrics is None:
+            return
+        metrics.new_counter("crypto.prevalidated.hit").inc(self.hits)
+        metrics.new_counter("crypto.prevalidated.miss").inc(self.misses)
 
 
 def signed_payload_hint(pubkey_raw: bytes, payload: bytes) -> bytes:

@@ -364,16 +364,20 @@ def _prom_num(v) -> str:
 
 
 def render_prometheus(metrics_json: Dict[str, dict],
-                      zones: Optional[Dict[str, dict]] = None) -> str:
+                      zones: Optional[Dict[str, dict]] = None,
+                      process_zones: Optional[Dict[str, dict]] = None
+                      ) -> str:
     """Render a MetricsRegistry.to_json() document (plus an optional
-    ZoneRegistry.report()) in Prometheus text exposition format 0.0.4,
+    ZoneRegistry.report() of the node, and one of the zones that belong
+    to the whole process) in Prometheus text exposition format 0.0.4,
     for `metrics?format=prometheus` scraping.
 
     Mapping: counters are gauges (ours can dec); meters are a
     `<name>_total` counter plus `<name>_rate{window=…}` gauges; timers
     and histograms are summaries — quantiles as labeled samples plus
     `_count`/`_sum` (timers in seconds, `_seconds` suffix). Perf zones
-    ride along as three labeled gauge families keyed by `zone=`.
+    ride along as three labeled gauge families keyed by `zone=`
+    (`perf_zone_*`; the process-wide ones as `process_zone_*`).
     """
     lines: List[str] = []
 
@@ -439,22 +443,21 @@ def render_prometheus(metrics_json: Dict[str, dict],
                     if key in rate:
                         lines.append(f'{p}_rate{{window="{window}"}} '
                                      f"{_prom_num(rate[key])}")
-    if zones:
-        family("perf_zone_count", "gauge",
-               "perf zone hit count (util/perf.py)")
-        for zname in sorted(zones):
-            lines.append(f'perf_zone_count{{zone="{_prom_label(zname)}"}}'
-                         f' {_prom_num(zones[zname]["count"])}')
-        family("perf_zone_total_seconds", "gauge",
-               "perf zone cumulative time")
-        for zname in sorted(zones):
-            lines.append(
-                f'perf_zone_total_seconds{{zone="{_prom_label(zname)}"}} '
-                f"{_prom_num(zones[zname]['total_ms'] / 1000.0)}")
-        family("perf_zone_max_seconds", "gauge",
-               "perf zone worst single hit")
-        for zname in sorted(zones):
-            lines.append(
-                f'perf_zone_max_seconds{{zone="{_prom_label(zname)}"}} '
-                f"{_prom_num(zones[zname]['max_ms'] / 1000.0)}")
+    for prefix, what, report in (
+            ("perf_zone", "perf zone", zones),
+            ("process_zone", "process-wide zone", process_zones)):
+        if not report:
+            continue
+        for suffix, help_text, value in (
+                ("count", "hit count (util/perf.py)",
+                 lambda z: z["count"]),
+                ("total_seconds", "cumulative time",
+                 lambda z: z["total_ms"] / 1000.0),
+                ("max_seconds", "worst single hit",
+                 lambda z: z["max_ms"] / 1000.0)):
+            family(f"{prefix}_{suffix}", "gauge", f"{what} {help_text}")
+            for zname in sorted(report):
+                lines.append(
+                    f'{prefix}_{suffix}{{zone="{_prom_label(zname)}"}} '
+                    f"{_prom_num(value(report[zname]))}")
     return "\n".join(lines) + "\n"

@@ -67,14 +67,26 @@ class ZoneRegistry:
             dt = time.perf_counter() - t0
             if tr is not None:
                 tr.end(name)
-            with self._lock:
-                st = self._zones.get(name)
-                if st is None:
-                    st = self._zones[name] = _ZoneStats()
-                st.count += 1
-                st.total += dt
-                if dt > st.max:
-                    st.max = dt
+            self.add(name, dt)
+
+    def add(self, name: str, seconds: float, count: int = 1) -> None:
+        """Report `count` hits of zone `name` that took `seconds` in
+        all, for a site that measured the time itself: a per-item path
+        that may not pay a context manager and this lock per item
+        accumulates in plain attributes and reports once per close
+        (`max` then sees the mean of the report), and a span that
+        begins before its registry exists reports at its end. Emits no
+        recorder event."""
+        if count <= 0:
+            return
+        with self._lock:
+            st = self._zones.get(name)
+            if st is None:
+                st = self._zones[name] = _ZoneStats()
+            st.count += count
+            st.total += seconds
+            if seconds / count > st.max:
+                st.max = seconds / count
 
     @contextmanager
     def zone_into(self, name: str, sink: Optional[dict] = None,

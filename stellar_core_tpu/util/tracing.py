@@ -39,27 +39,35 @@ from typing import Dict, List, Optional
 # Module-level constant guard: instrumented hot paths check ONLY this
 # before paying anything. _retain()/_release() are the sole writers.
 ENABLED = False
-_active_count = 0
+_active: list = []          # the recorders that are recording
 _state_lock = threading.Lock()
 
 # default ring capacity: ~256k events ≈ tens of seconds of a busy node,
-# a few MB of tuples — bounded no matter how long a trace stays on
+# ~40 MB worst case; STARTTRACE?capacity=N overrides per recording
 DEFAULT_CAPACITY = 262_144
 
 
-def _retain() -> None:
-    global ENABLED, _active_count
+def _retain(rec: "FlightRecorder") -> None:
+    global ENABLED
     with _state_lock:
-        _active_count += 1
+        _active.append(rec)
         ENABLED = True
 
 
-def _release() -> None:
-    global ENABLED, _active_count
+def _release(rec: "FlightRecorder") -> None:
+    global ENABLED
     with _state_lock:
-        _active_count = max(0, _active_count - 1)
-        if _active_count == 0:
-            ENABLED = False
+        if rec in _active:
+            _active.remove(rec)
+        ENABLED = bool(_active)
+
+
+def active_recorders() -> list:
+    """The recorders recording now, for process-wide sites that belong
+    to no one Application (JAX's compile listener, util/jax_cache.py).
+    Call it under ``if tracing.ENABLED:``."""
+    with _state_lock:
+        return list(_active)
 
 
 class FlightRecorder:
@@ -109,7 +117,7 @@ class FlightRecorder:
             self._t0_wall = time.time()
             if not self.active:
                 self.active = True
-                _retain()
+                _retain(self)
 
     def stop(self) -> dict:
         """Stop recording; the buffer stays dumpable until the next
@@ -117,7 +125,7 @@ class FlightRecorder:
         with self._lock:
             if self.active:
                 self.active = False
-                _release()
+                _release(self)
             return {"events": len(self._buf), "dropped": self.dropped,
                     "capacity": self._capacity}
 
