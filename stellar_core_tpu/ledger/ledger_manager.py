@@ -10,6 +10,7 @@ startNewLedger.
 
 from __future__ import annotations
 
+import struct
 import threading
 import time
 from contextlib import nullcontext
@@ -21,7 +22,8 @@ from ..invariant.manager import InvariantManager
 from ..tx.signature_checker import VerifyFn, default_verify
 from ..util import chaos, threads, tracing
 from ..util.logging import get_logger
-from ..xdr.ledger import (LedgerCloseMeta, LedgerCloseMetaV0, LedgerHeader,
+from ..xdr.ledger import (LedgerCloseMeta, LedgerCloseMetaV0,
+                          LedgerEntryChanges, LedgerHeader,
                           LedgerHeaderHistoryEntry, LedgerUpgrade,
                           StellarValue, TransactionMeta, TransactionMetaV2,
                           TransactionResultMeta, TransactionResultPair,
@@ -668,15 +670,36 @@ class LedgerManager:
         with self.perf.zone("ledger.close.complete", targs=targs), \
                 self.perf.log_slow_execution(
                     f"closeLedger {seq} completion", 2.0):
+            # each transaction's history artifacts are built once and
+            # serialised once (native codec), for both sinks (history
+            # rows, close meta), and only if one is on; bytes only
+            # where a sink writes bytes (rows, the debug segment)
+            stores = self.db is not None and self.stores_history_misc
+            emits = self.meta_stream is not None \
+                or self.meta_debug_dir is not None
+            txset_bytes = tx_bytes = None
+            if stores or emits:
+                with self.perf.zone("ledger.close.complete.encode"):
+                    tx_metas = [_encode_tx_meta(m, apply_version)
+                                for m in tx_metas]
+                    if stores or self.meta_debug_dir is not None:
+                        txset_bytes = applicable.to_wire().to_bytes()
+                        fee_bytes = LedgerEntryChanges.to_bytes
+                        tx_bytes = [
+                            (pair.to_bytes(), fee_bytes(fees),
+                             meta.to_bytes())
+                            for pair, fees, meta in zip(
+                                result_pairs, fee_metas, tx_metas)]
             # meta FIRST: the marker commits last, so a crash anywhere
             # in this job leaves the marker behind the LCL and the
             # restart gap-check reports the incomplete tail (meta
             # emitted for a gap ledger is harmless; meta silently LOST
             # for a marker-complete ledger would not be)
             with self.perf.zone("ledger.close.meta"):
-                self._emit_meta(closed, lcd, applicable, txs,
-                                result_pairs, fee_metas, tx_metas,
-                                upgrade_metas, apply_version)
+                if emits:
+                    self._emit_meta(closed, applicable, result_pairs,
+                                    fee_metas, tx_metas, upgrade_metas,
+                                    txset_bytes, tx_bytes)
             if chaos.ENABLED:
                 self._chaos_crash_point(
                     "ledger.close.crash.complete.meta", seq)
@@ -685,16 +708,20 @@ class LedgerManager:
             for hook in self.completion_hooks:
                 hook(seq, closed.scpValue.closeTime, result_pairs)
             with self.perf.zone("ledger.close.txHistory"):
-                dbtx = self.db.transaction() if self.db is not None \
-                    else nullcontext()
-                with dbtx:
-                    self._store_tx_history(seq, applicable, txs,
-                                           result_pairs, fee_metas,
-                                           tx_metas, apply_version)
-                    if self.persistent_state is not None:
-                        from ..main.persistent_state import StateEntry
-                        self.persistent_state.set(
-                            StateEntry.LAST_CLOSE_COMPLETED, str(seq))
+                if stores:
+                    with self.perf.zone("ledger.close.txHistory.rows"):
+                        rows = self._tx_history_rows(
+                            seq, applicable, txs, txset_bytes, tx_bytes)
+                with self.perf.zone("ledger.close.txHistory.sql"):
+                    dbtx = self.db.transaction() if self.db is not None \
+                        else nullcontext()
+                    with dbtx:
+                        if stores:
+                            self._store_tx_history(seq, *rows)
+                        if self.persistent_state is not None:
+                            from ..main.persistent_state import StateEntry
+                            self.persistent_state.set(
+                                StateEntry.LAST_CLOSE_COMPLETED, str(seq))
             if chaos.ENABLED:
                 self._chaos_crash_point(
                     "ledger.close.crash.complete.marker", seq)
@@ -1141,27 +1168,27 @@ class LedgerManager:
              header.ledgerSeq, header.scpValue.closeTime,
              header.to_bytes()))
 
-    def _store_tx_history(self, seq: int, applicable, txs, result_pairs,
-                          fee_metas, tx_metas, apply_version: int) -> None:
-        if self.db is None or not self.stores_history_misc:
-            return
-        from ..xdr.ledger import LedgerEntryChanges
-        from ..xdr.runtime import Writer
-        wire = applicable.to_wire()
-        self.db.execute(
-            "INSERT OR REPLACE INTO txsethistory "
-            "(ledgerseq, isgeneralized, txset) VALUES (?,?,?)",
-            (seq, 1 if wire.is_generalized else 0, wire.to_bytes()))
+    @staticmethod
+    def _tx_history_rows(seq: int, applicable, txs, txset_bytes: bytes,
+                         tx_bytes) -> tuple:
+        """The close's history rows from the bytes of
+        `_complete_close`'s one encoding pass."""
+        set_row = (seq, 1 if applicable.to_wire().is_generalized else 0,
+                   txset_bytes)
         tx_rows = []
         fee_rows = []
-        for i, tx in enumerate(txs):
-            tx_rows.append(
-                (tx.full_hash(), seq, i, tx.envelope_bytes(),
-                 result_pairs[i].to_bytes(),
-                 _encode_tx_meta(tx_metas[i], apply_version).to_bytes()))
-            w = Writer()
-            LedgerEntryChanges.pack(w, fee_metas[i])
-            fee_rows.append((tx.full_hash(), seq, i, bytes(w.buf)))
+        for i, (tx, (result, fee_changes, meta)) in enumerate(
+                zip(txs, tx_bytes)):
+            txid = tx.full_hash()
+            tx_rows.append((txid, seq, i, tx.envelope_bytes(), result, meta))
+            fee_rows.append((txid, seq, i, fee_changes))
+        return set_row, tx_rows, fee_rows
+
+    def _store_tx_history(self, seq: int, set_row, tx_rows,
+                          fee_rows) -> None:
+        self.db.execute(
+            "INSERT OR REPLACE INTO txsethistory "
+            "(ledgerseq, isgeneralized, txset) VALUES (?,?,?)", set_row)
         self.db.executemany(
             "INSERT OR REPLACE INTO txhistory "
             "(txid, ledgerseq, txindex, txbody, txresult, txmeta) "
@@ -1171,21 +1198,15 @@ class LedgerManager:
             "(txid, ledgerseq, txindex, txchanges) VALUES (?,?,?,?)",
             fee_rows)
 
-    def _emit_meta(self, header, lcd, applicable, txs, result_pairs,
-                   fee_metas, tx_metas, upgrade_metas,
-                   apply_version: int) -> None:
-        if self.meta_stream is None and self.meta_debug_dir is None:
-            return
+    def _emit_meta(self, header, applicable, result_pairs, fee_metas,
+                   tx_metas, upgrade_metas, txset_bytes, tx_bytes) -> None:
         hhe = LedgerHeaderHistoryEntry(
             hash=ledger_header_hash(header), header=header,
             ext=ExtensionPoint(0))
         tx_processing = [
-            TransactionResultMeta(
-                result=result_pairs[i],
-                feeProcessing=fee_metas[i],
-                txApplyProcessing=_encode_tx_meta(
-                    tx_metas[i], apply_version))
-            for i in range(len(txs))
+            TransactionResultMeta(result=pair, feeProcessing=fees,
+                                  txApplyProcessing=meta)
+            for pair, fees, meta in zip(result_pairs, fee_metas, tx_metas)
         ]
         wire = applicable.to_wire()
         if wire.is_generalized:
@@ -1205,35 +1226,40 @@ class LedgerManager:
                 txProcessing=tx_processing,
                 upgradesProcessing=upgrade_metas, scpInfo=[])
             meta = LedgerCloseMeta(0, v0)
+        # the debug segment's record: `meta.to_bytes()`, spliced from
+        # the bytes the history rows use
+        record = _close_meta_bytes(meta, txset_bytes, tx_bytes) \
+            if self.meta_debug_dir is not None else None
+        emitted = (meta, record)
         if self.delay_meta:
             # one-ledger holdback: consumers only ever see meta for
             # ledgers strictly behind the LCL (reference:
             # EXPERIMENTAL_PRECAUTION_DELAY_META)
             with self._meta_lock:
-                meta, self._delayed_meta = self._delayed_meta, meta
-            if meta is None:
+                emitted, self._delayed_meta = self._delayed_meta, emitted
+            if emitted is None:
                 return
-        self._deliver_meta(meta)
+        self._deliver_meta(*emitted)
 
     def flush_delayed_meta(self) -> None:
         """Emit any held-back meta (clean shutdown must not leave a
         permanent gap in the stream)."""
         with self._meta_lock:
-            meta, self._delayed_meta = self._delayed_meta, None
-        if meta is not None:
-            self._deliver_meta(meta)
+            emitted, self._delayed_meta = self._delayed_meta, None
+        if emitted is not None:
+            self._deliver_meta(*emitted)
 
-    def _deliver_meta(self, meta) -> None:
+    def _deliver_meta(self, meta, record: Optional[bytes]) -> None:
         if self.meta_stream is not None:
             self.meta_stream(meta)
         if self.meta_debug_dir is not None:
             # key by the meta's OWN ledger seq: with delay-meta on, the
             # emitted meta is one ledger behind the closing header
             self._write_debug_meta(
-                meta, meta.value.ledgerHeader.header.ledgerSeq)
+                record, meta.value.ledgerHeader.header.ledgerSeq)
 
     # ------------------------------------------------------- debug meta --
-    def _write_debug_meta(self, meta, seq: int) -> None:
+    def _write_debug_meta(self, record: bytes, seq: int) -> None:
         """Append the close meta to the current debug segment; rotate +
         gzip at checkpoint boundaries and GC old segments (reference:
         LedgerManagerImpl.cpp:1100-1160 + FlushAndRotateMetaDebugWork)."""
@@ -1256,7 +1282,7 @@ class LedgerManager:
                     _truncate_partial_tail(path)
                 self._meta_debug_file = open(path, "ab")
                 self._meta_debug_segment = segment
-            write_record(self._meta_debug_file, meta.to_bytes())
+            write_record(self._meta_debug_file, record)
             # flush per record: a crash loses at most the in-flight
             # record
             self._meta_debug_file.flush()
@@ -1321,6 +1347,26 @@ def _truncate_partial_tail(path: str) -> None:
             good = f.tell()
     os.truncate(path, good)
     log.warning("dropped partial tail record from %s", path)
+
+
+def _close_meta_bytes(meta: LedgerCloseMeta, txset_bytes: bytes,
+                      tx_bytes) -> bytes:
+    """`meta.to_bytes()` without packing `txSet` and the `txProcessing`
+    trees again: those two fields are spliced from their bytes
+    (`tx_bytes[i]` holds a TransactionResultMeta's three fields in wire
+    order), every other field is packed here."""
+    body = meta.value
+    parts = [struct.pack(">i", meta.disc)]
+    for name, ftype in type(body)._FIELDS:
+        if name == "txSet":
+            parts.append(txset_bytes)
+        elif name == "txProcessing":
+            parts.append(struct.pack(">I", len(tx_bytes)))
+            for three in tx_bytes:
+                parts.extend(three)
+        else:
+            parts.append(ftype.to_bytes(getattr(body, name)))
+    return b"".join(parts)
 
 
 def _encode_tx_meta(meta: dict,

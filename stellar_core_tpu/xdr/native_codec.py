@@ -68,6 +68,8 @@ class NativeCodec:
         self.pack = None
         self.unpack = None
         self.clone = None
+        # id(XdrType instance) -> node index, for XdrType.to_bytes
+        self.type_idx: dict = {}
 
     def refresh(self) -> None:
         from . import runtime
@@ -94,13 +96,14 @@ class NativeCodec:
         def t_idx(t) -> int:
             while isinstance(t, runtime.Lazy):
                 t = t._get()
-            if isinstance(t, runtime._Composite):
-                return c_idx(t.cls)
             k = id(t)
             got = memo_t.get(k)
             if got is not None:
                 return got
             keep.append(t)
+            if isinstance(t, runtime._Composite):
+                memo_t[k] = c_idx(t.cls)
+                return memo_t[k]
             if isinstance(t, runtime._Int32):
                 node = (K_I32,)
             elif isinstance(t, runtime._Uint32):
@@ -183,6 +186,7 @@ class NativeCodec:
             cls._nidx = c_idx(cls)
         cap = self.ext.build(nodes, runtime.XdrError)
         self._keep = (nodes, keep)
+        self.type_idx = memo_t
         return cap
 
 

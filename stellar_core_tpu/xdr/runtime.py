@@ -155,6 +155,23 @@ class XdrType:
     def default(self) -> Any:
         raise NotImplementedError
 
+    def to_bytes(self, v: Any) -> bytes:
+        """Wire form of one value of this type, for values that are no
+        Struct/Union of their own (a `VarArray` field's list): through
+        the native codec where the schema program holds this type,
+        else through `pack`, which also re-raises with context."""
+        nc = _nc()
+        if nc is not None:
+            idx = nc.type_idx.get(id(self))
+            if idx is not None:
+                try:
+                    return nc.pack(nc.cap, idx, v)
+                except Exception:
+                    pass
+        w = Writer()
+        self.pack(w, v)
+        return bytes(w.buf)
+
 
 class _Int32(XdrType):
     def pack(self, w: Writer, v: Any) -> None:

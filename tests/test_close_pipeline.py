@@ -181,6 +181,88 @@ def test_deferred_path_byte_identical_to_synchronous():
     assert deferred[2] == inline[2]
 
 
+# ------------------------------------------- one encoding for both sinks --
+
+def _count_encodes(monkeypatch):
+    from stellar_core_tpu.ledger import ledger_manager as lm_mod
+    calls = []
+    orig = lm_mod._encode_tx_meta
+
+    def counting(meta, ledger_version=0):
+        calls.append(ledger_version)
+        return orig(meta, ledger_version)
+
+    monkeypatch.setattr(lm_mod, "_encode_tx_meta", counting)
+    return calls
+
+
+def _close_three_payments(lm):
+    mk = lc.master_key()
+    seq = lc.master_seq(lm)
+    lc.close_with(lm, [
+        lc.make_tx(lm, mk, seq + 1 + i, [op_payment(
+            lc.MuxedAccount.from_ed25519(mk.public_key().raw), 1 + i)])
+        for i in range(3)])
+    lm.join_completion()
+
+
+def test_tx_meta_is_encoded_once_per_transaction(tmp_path, monkeypatch):
+    """Both sinks on (history rows, meta stream and debug segment): one
+    `_encode_tx_meta` call per transaction per close."""
+    db = Database(":memory:")
+    db.initialize()
+    lm = lc.make_manager(db=db)
+    assert lm.stores_history_misc
+    lm.meta_stream = [].append
+    lm.meta_debug_dir = str(tmp_path / "meta-debug")
+    calls = _count_encodes(monkeypatch)
+    _close_three_payments(lm)
+    assert calls == [21, 21, 21]
+    _close_three_payments(lm)
+    assert len(calls) == 6
+    assert db.query_one("SELECT COUNT(*) FROM txhistory")[0] == 6
+    assert db.query_one("SELECT COUNT(*) FROM txfeehistory")[0] == 6
+
+
+def test_tx_meta_is_not_encoded_without_a_sink(monkeypatch):
+    db = Database(":memory:")
+    db.initialize()
+    lm = lc.make_manager(db=db)
+    lm.stores_history_misc = False
+    assert lm.meta_stream is None and lm.meta_debug_dir is None
+    calls = _count_encodes(monkeypatch)
+    _close_three_payments(lm)
+    assert calls == []
+    assert db.query_one("SELECT COUNT(*) FROM txhistory")[0] == 0
+    assert lm.get_last_closed_ledger_num() == 2
+
+
+@pytest.mark.parametrize("delay_meta", [False, True])
+def test_meta_stream_receives_the_same_close_meta(delay_meta):
+    """A `meta_stream` consumer keeps receiving a LedgerCloseMeta
+    object, equal to the one built with an encoding pass of its own
+    (as before the tail shared one), one ledger late under
+    `delay_meta`."""
+    import test_history_tail as ht
+    db = Database(":memory:")
+    db.initialize()
+    lm = lc.make_manager(db=db)
+    lm.delay_meta = delay_meta
+    streamed = []
+    lm.meta_stream = streamed.append
+    tails = ht.capture_tail(lm)
+    for _ in range(3):
+        _close_three_payments(lm)
+    want = [ht.plain_close_meta(t) for t in tails]
+    assert len(want) == 3
+    if delay_meta:
+        assert streamed == want[:2]
+        lm.flush_delayed_meta()
+    assert streamed == want
+    assert [m.to_bytes() for m in streamed] == \
+        [ht.plain_bytes(m) for m in want]
+
+
 # -------------------------------------------------- crash mid-completion --
 
 def _file_cfg(tmp_path):
