@@ -557,6 +557,7 @@ class _BodyVisitor(ast.NodeVisitor):
                                    text, node.lineno)
 
         # scheduler reroutes: callbacks land back on the crank loop
+        queue_submit = None
         if attr == "post" and node.args:
             self._add_edge(POST, self._callback_targets(node.args[0]),
                            text, node.lineno)
@@ -573,6 +574,10 @@ class _BodyVisitor(ast.NodeVisitor):
             if len(node.args) >= 2:
                 self._add_edge(SPAWN, self._callback_targets(node.args[1]),
                                text, node.lineno)
+            # the seam names its callee: no call edge to every other
+            # `submit` of the package
+            queue_submit = self.o.index.class_methods.get(
+                ("CloseCompletionQueue", "submit"))
 
         # mutating method call on self.attr -> write
         if attr in _MUTATORS and isinstance(node.func, ast.Attribute):
@@ -581,7 +586,7 @@ class _BodyVisitor(ast.NodeVisitor):
 
         # plain call edge
         targets, text2 = self.o.resolve_callee(node.func, self.cls)
-        self._add_edge(CALL, targets, text2, node.lineno)
+        self._add_edge(CALL, queue_submit or targets, text2, node.lineno)
 
         if isinstance(node.func, ast.Attribute):
             # chained receivers can hold further calls: a.b(x).c(y)

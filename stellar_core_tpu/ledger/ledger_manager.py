@@ -13,7 +13,8 @@ from __future__ import annotations
 import struct
 import threading
 import time
-from contextlib import nullcontext
+from contextlib import nullcontext, suppress
+from functools import partial
 from typing import Callable, List, Optional
 
 from ..crypto.keys import publish_verify_counts
@@ -160,6 +161,7 @@ class LedgerManager:
         self.chaos_label = ""
         self._meta_debug_file = None
         self._meta_debug_segment = None
+        self._meta_debug_gzip = None    # the open segment's _SegmentGzip
         # read-tier taps (query/): closed_hooks fire on the crank
         # thread right after the consensus-critical commit (snapshot
         # capture — callable(closed_header, lcl_hash)); completion_hooks
@@ -1260,8 +1262,9 @@ class LedgerManager:
 
     # ------------------------------------------------------- debug meta --
     def _write_debug_meta(self, record: bytes, seq: int) -> None:
-        """Append the close meta to the current debug segment; rotate +
-        gzip at checkpoint boundaries and GC old segments (reference:
+        """Append the close meta to the current debug segment, hand the
+        new bytes to the segment's compressor, and at checkpoint
+        boundaries rotate to `.xdr.gz` and GC old segments (reference:
         LedgerManagerImpl.cpp:1100-1160 + FlushAndRotateMetaDebugWork)."""
         import os
         from ..history.archive import (CHECKPOINT_FREQUENCY,
@@ -1273,6 +1276,11 @@ class LedgerManager:
                     self._meta_debug_segment != segment:
                 self._close_debug_meta()
                 os.makedirs(self.meta_debug_dir, exist_ok=True)
+                for f in os.listdir(self.meta_debug_dir):
+                    # no segment is open, so any `.tmp` is the
+                    # compressed side of a process that died
+                    if f.startswith("meta-debug-") and f.endswith(".tmp"):
+                        os.unlink(os.path.join(self.meta_debug_dir, f))
                 path = os.path.join(self.meta_debug_dir,
                                     f"meta-debug-{segment:08x}.xdr")
                 if os.path.exists(path):
@@ -1282,12 +1290,16 @@ class LedgerManager:
                     _truncate_partial_tail(path)
                 self._meta_debug_file = open(path, "ab")
                 self._meta_debug_segment = segment
+                self._meta_debug_gzip = _SegmentGzip(
+                    path, self.perf, self._metrics)
             write_record(self._meta_debug_file, record)
             # flush per record: a crash loses at most the in-flight
             # record
             self._meta_debug_file.flush()
+            self._meta_debug_gzip.compress_to(
+                self._meta_debug_file.tell(), seq)
             if seq == segment:
-                # segment complete: compress and GC (keep enough
+                # segment complete: rotate and GC (keep enough
                 # segments to cover meta_debug_ledgers)
                 self._close_debug_meta(compress=True, seq=seq)
                 keep = max(1, (self.meta_debug_ledgers +
@@ -1301,25 +1313,28 @@ class LedgerManager:
 
     def _close_debug_meta(self, compress: bool = False,
                           seq: Optional[int] = None) -> None:
-        import gzip
-        import os
+        """Close the open segment. With `compress` (its checkpoint
+        ledger has closed) it becomes `.xdr.gz`; without (shutdown or a
+        jump to another segment) the raw file stays as it is and what
+        was compressed of it is dropped."""
         with self._meta_lock:
             if self._meta_debug_file is None:
                 return
-            path = self._meta_debug_file.name
             self._meta_debug_file.close()
+            gz, self._meta_debug_gzip = self._meta_debug_gzip, None
             self._meta_debug_file = None
             self._meta_debug_segment = None
-        if compress:
-            import shutil
-            # seconds at a checkpoint ledger (a whole segment of
-            # 1,000-payment ledgers: 7 s), on the completion worker
+            if not compress:
+                gz.drop_gz()
+                return
+            # milliseconds at a checkpoint ledger (a whole segment of
+            # 1,000-payment ledgers: 34 ms; 7 s when the segment was
+            # gzipped here): the compressor has taken in every earlier
+            # record beside the closes, so this waits for the last
+            # record, the stream's end and the rename
             targs = {"seq": seq} if tracing.ENABLED else None
-            with self.perf.zone("ledger.close.meta.compress", targs=targs), \
-                    open(path, "rb") as src, \
-                    gzip.open(path + ".gz", "wb") as dst:
-                shutil.copyfileobj(src, dst)
-            os.unlink(path)
+            with self.perf.zone("ledger.close.meta.compress", targs=targs):
+                gz.finish_gz()
 
 
 def _phase_summary(phases: dict) -> str:
@@ -1347,6 +1362,127 @@ def _truncate_partial_tail(path: str) -> None:
             good = f.tell()
     os.truncate(path, good)
     log.warning("dropped partial tail record from %s", path)
+
+
+# zlib level of a debug segment's `.xdr.gz`: gzip(1)'s default, which
+# is what the reference runs (FlushAndRotateMetaDebugWork's GzipFileWork
+# spawns `gzip` with no level flag). Python's `gzip.open` defaults to 9:
+# six times the seconds for 2 % fewer bytes.
+META_DEBUG_GZIP_LEVEL = 6
+_META_DEBUG_GZIP_CHUNK = 1 << 22    # a 1,000-payment record is 1.5 MB
+
+
+class _SegmentGzip:
+    """The compressed side of one debug-meta segment. The raw `.xdr`
+    is appended to and flushed by the close's tail as ever; this reads
+    it behind the writer, through a descriptor of its own, into one
+    gzip stream `<raw>.gz.tmp`, which `finish_gz` renames to `<raw>.gz`.
+
+    `compress_to`, `finish_gz` and `drop_gz` are called by whoever holds
+    `LedgerManager._meta_lock`. The work runs as jobs of the segment's
+    own single-worker FIFO, which nothing joins before `finish_gz`; the
+    jobs alone touch the stream's state below, are handed a byte count
+    and take no lock of the LedgerManager. A segment found on disk (a
+    restart in its middle) is no other path: its first job reads from
+    byte 0. A failed job drops the `.tmp`, idles the segment's later
+    jobs and surfaces at `finish_gz`, as a completion failure does at
+    the completion queue's join; the raw file stays."""
+
+    # the stream's state, written by the jobs only
+    _done = 0               # raw bytes compressed so far
+    _src = _dst = _zlib = None
+    _failed = False
+
+    def __init__(self, path: str, perf, metrics):
+        import os
+        from .completion import CloseCompletionQueue
+        self._path = path
+        self._tmp = path + ".gz.tmp"
+        self._perf = perf
+        self._metrics = metrics
+        # exits when idle, so a finished segment parks no thread
+        self._completion = CloseCompletionQueue("meta-compress")
+        # does the stream begin with the file, in this process's life?
+        self._streamed = os.path.getsize(path) == 0
+
+    def compress_to(self, size: int, seq: int) -> None:
+        """Queue the raw file's bytes up to `size`, where ledger
+        `seq`'s record ends."""
+        self._completion.submit(seq, partial(self._compress_to, size, seq))
+
+    def finish_gz(self) -> None:
+        """Wait until the stream holds the whole raw file and `<raw>.gz`
+        has replaced both files. Raises if a job of the segment failed."""
+        self._completion.submit(0, self._finish)
+        self._completion.join()
+
+    def drop_gz(self) -> None:
+        """Drop what was compressed; the raw file stays."""
+        self._completion.discard_pending()
+        self._completion.submit(0, self._drop)
+        self._completion.join(reraise=False)
+
+    def _compress_to(self, size: int, seq: int) -> None:  # thread-domain: completion-worker
+        if self._failed:
+            return
+        import zlib
+        targs = {"seq": seq} if tracing.ENABLED else None
+        try:
+            with self._perf.zone("ledger.debugMeta.compress", targs=targs):
+                if self._zlib is None:
+                    self._src = open(self._path, "rb")
+                    self._dst = open(self._tmp, "wb")
+                    # wbits 31: gzip's header and trailer round the
+                    # deflate stream
+                    self._zlib = zlib.compressobj(
+                        META_DEBUG_GZIP_LEVEL, zlib.DEFLATED, 31)
+                todo = size - self._done
+                while todo > 0:
+                    chunk = self._src.read(min(todo, _META_DEBUG_GZIP_CHUNK))
+                    if not chunk:
+                        raise IOError(
+                            f"{self._path} is {todo} bytes short of the "
+                            f"{size} written to it")
+                    self._dst.write(self._zlib.compress(chunk))
+                    todo -= len(chunk)
+                if self._metrics is not None:
+                    self._metrics.counter(
+                        "ledger", "debugMeta", "bytes").inc(size - self._done)
+                self._done = size
+        except BaseException:
+            self._failed = True
+            self._drop()
+            raise
+
+    def _finish(self) -> None:  # thread-domain: completion-worker
+        import os
+        if self._failed:
+            return
+        try:
+            self._dst.write(self._zlib.flush())
+            self._dst.close()
+            os.replace(self._tmp, self._path + ".gz")
+        except BaseException:
+            self._drop()
+            raise
+        self._src.close()
+        os.unlink(self._path)
+        if self._metrics is not None:
+            if self._streamed:
+                self._metrics.counter(
+                    "ledger", "debugMeta", "segment", "streamed").inc()
+            else:
+                self._metrics.counter(
+                    "ledger", "debugMeta", "segment", "caughtUp").inc()
+
+    def _drop(self) -> None:  # thread-domain: completion-worker
+        import os
+        for f in (self._src, self._dst):
+            if f is not None:
+                f.close()
+        self._src = self._dst = self._zlib = None
+        with suppress(FileNotFoundError):
+            os.unlink(self._tmp)
 
 
 def _close_meta_bytes(meta: LedgerCloseMeta, txset_bytes: bytes,

@@ -705,7 +705,7 @@ def cmd_replay_debug_meta(args) -> int:
         return 1
     files = sorted(
         _os.path.join(meta_dir, f) for f in _os.listdir(meta_dir)
-        if f.startswith("meta-debug-"))
+        if f.startswith("meta-debug-") and not f.endswith(".tmp"))
     if not files:
         print("no debug meta files found", file=sys.stderr)
         return 1

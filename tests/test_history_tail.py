@@ -132,6 +132,8 @@ def stored_rows(db, seq: int) -> dict:
 def debug_records(meta_dir: str) -> list:
     out = []
     for name in sorted(os.listdir(meta_dir)):
+        if name.endswith(".xdr.gz.tmp"):
+            continue        # the open segment's compressed side
         assert name.endswith(".xdr"), name
         with open(os.path.join(meta_dir, name), "rb") as f:
             while True:
