@@ -5,8 +5,8 @@ single-device one and the oracle.
 
 `SIGNATURE_VERIFY_MESH = "auto"` puts every node on a multi-chip host
 on `ShardedBatchVerifier` (main/application.py `_make_batch_verifier`);
-this is what `chip_smoke.py --chips 4` runs to prove that path on real
-chips. ONE process owns all the chips (a chip belongs to one process),
+this is the one check of that path on real chips (`chiprun --chips 4
+-- python scripts/mesh_differential.py --devices 4`). ONE process owns all the chips (a chip belongs to one process),
 verifies the adversarial corpus (ops/testvectors.py, tpu_differential's
 fast tier) and a valid batch with both verifiers, and checks:
 

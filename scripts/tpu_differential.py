@@ -1,4 +1,4 @@
-"""Real-hardware Ed25519 differential job (VERDICT round-1 weak #3):
+"""Real-hardware Ed25519 differential job:
 run the valid/corrupted/non-canonical/small-order vector suite on the
 ACTUAL TPU chip (not the forced-CPU pytest platform), and cross-check
 chip results against the CPU-mesh lowering and the pure-Python oracle
@@ -24,12 +24,13 @@ one process, and a parent that had touched JAX would keep it from the
 chip child. The children get the plain environment (no JAX_PLATFORMS:
 JAX then takes the chip, and fails at start-up where there is none).
 
-`run` is also what `chip_smoke.py` starts on the chip after every
-catchup phase: the multiply that ships on the chip (`fe8._mul_rolled`)
-is not the one tier 1 exercises, so every chip run proves it
-(consensus-safety: XLA:TPU and XLA:CPU are not guaranteed identical
-lowerings of the int32 pipeline — this job is the proof they agree on
-this kernel, on this chip, for every rejection class).
+The multiply that ships on the chip (`fe8._mul_rolled`) is not the one
+tier 1 exercises, and this job is the only run that puts mixed-length
+messages through `verify_kernel_full` on a chip (the benchmark's cells
+check the 32-byte kernel on every run). Consensus safety: XLA:TPU and
+XLA:CPU are not guaranteed identical lowerings of the int32 pipeline —
+this job is the proof they agree on this kernel, on this chip, for
+every rejection class.
 """
 
 import argparse
@@ -141,7 +142,7 @@ def _orchestrate(n: int, out_dir: str) -> None:
 
 
 def _fast(n: int, out_dir: str) -> None:
-    """Fast chip tier (VERDICT r04 #8): the full strict-check corpus
+    """Fast chip tier: the full strict-check corpus
     (non-canonical A/R/S, small order, torsion defects, mixed
     valid/invalid — the adversarial tail is appended whole regardless
     of n) at a small bucket, chip vs oracle only. Warm-cache target:

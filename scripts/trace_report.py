@@ -3,15 +3,13 @@
 scripts/DiffTracyCSV.py, which diffs two Tracy capture CSVs —
 scripts/README.md:14-19; here over Chrome trace-event JSON).
 
-Inputs are trace files from the admin API or the bench harness:
+Inputs are trace files from the admin API:
 
     curl -s 'localhost:11626/starttrace'
     ... run a workload ...
     curl -s 'localhost:11626/dumptrace?path=/tmp/run.json'
     python scripts/trace_report.py /tmp/run.json
-
-    python bench.py --tps-multi --trace     # writes trace_tpsm.json
-    python scripts/trace_report.py trace_tpsm.json [other.json]
+    python scripts/trace_report.py /tmp/run.json /tmp/other.json
 
 With one trace: top zones by total time, the ledger-close critical
 path (per-phase breakdown of every ledger.close.* span), and
@@ -19,11 +17,11 @@ barrier-wait gaps (time closes spent blocked on the completion
 worker). With two: a per-zone count/total/mean delta table, sorted so
 regressions stand out the same way DiffTracyCSV's diffs do.
 
-Cluster views over a MERGED trace (Simulation.merged_trace /
-bench.py --trace — one process lane per node):
+Cluster views over a MERGED trace (Simulation.merged_trace or
+Cluster.merged_trace, one process lane per node):
 
-    python scripts/trace_report.py trace_tpsm.json --slots
-    python scripts/trace_report.py trace_tpsm.json --flood
+    python scripts/trace_report.py merged.json --slots
+    python scripts/trace_report.py merged.json --flood
 
 `--slots` tabulates per-slot SCP phase latencies (nominate / prepare /
 confirm spans per node lane) with slowest-node attribution per slot;

@@ -262,8 +262,8 @@ def _check_metrics(index: PackageIndex, repo_root: str) -> List[Finding]:
     # strict doc claims live in metric TABLES (header row contains
     # "metric"); backticked dotted names in prose are soft coverage —
     # they satisfy the emitted→documented direction but a prose
-    # mention of `bench.py` or a trace-zone name is not a claim that
-    # a registry metric exists.
+    # mention of a file or a trace-zone name is not a claim that a
+    # registry metric exists.
     obs = os.path.join(repo_root, "docs", "OBSERVABILITY.md")
     documented: Dict[str, Tuple[str, int]] = {}
     soft_doc: Dict[str, Tuple[str, int]] = {}

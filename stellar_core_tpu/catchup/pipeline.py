@@ -91,7 +91,7 @@ class PipelineStats:
         self.busy_s = {s: 0.0 for s in self.STAGES}
         self.items = {s: 0 for s in self.STAGES}
         # device-prevalidate / apply busy while >=1 download in flight:
-        # the stage-overlap evidence the CATCHUP artifact must show
+        # the stage-overlap evidence of `report()`
         self.overlap_device_download_s = 0.0
         self.overlap_apply_download_s = 0.0
         self.bytes_buffered = 0
@@ -136,8 +136,8 @@ class PipelineStats:
         self.ready_hwm = max(self.ready_hwm, self.ready)
 
     def report(self) -> dict:
-        """The CATCHUP artifact's `stages` section
-        (scripts/check_artifacts.py pins the shape SINCE r19)."""
+        """Busy seconds, occupancy and items per stage, the queues'
+        high-water marks and the overlap seconds."""
         wall = (self._t1 - self._t0) if self._t0 is not None else 0.0
         stages = {}
         for s in self.STAGES:

@@ -1,4 +1,4 @@
-"""Hermetic PostgreSQL wire-protocol stub server (VERDICT r02 #8).
+"""Hermetic PostgreSQL wire-protocol stub server.
 
 Speaks enough of the v3 protocol for THIS repo's libpq binding
 (db/libpq.py: PQconnectdb, PQprepare, PQexecPrepared, PQexecParams —

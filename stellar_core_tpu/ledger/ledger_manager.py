@@ -139,18 +139,12 @@ class LedgerManager:
         # service when one exists (set by Application)
         self.verify_service = None
         self._apply_pool = None
-        # last close's staging shape (tests + APPLYPAR bench artifact)
+        # last close's staging shape (read by tests)
         self.last_apply_stages = 0
         self.last_stage_widths: List[int] = []
         # stages that failed the merge-time footprint/header audit and
         # were re-applied sequentially (0 = every claim held)
         self.apply_fallbacks = 0
-        # cumulative staged-apply accounting across closes (the
-        # CATCHUP artifact's `parallel_apply` section — proves the
-        # replay inner loop actually rode the conflict-staged engine)
-        self.parallel_ledgers = 0
-        self.parallel_stages_total = 0
-        self.parallel_width_max = 0
         # probe count of the most recent bounded eviction scan
         # (observability + the O(scan-size) test's hook)
         self.last_eviction_probes = 0
@@ -843,10 +837,6 @@ class LedgerManager:
         stages = partition_stages(footprints)
         self.last_apply_stages = len(stages)
         self.last_stage_widths = [len(s) for s in stages]
-        self.parallel_ledgers += 1
-        self.parallel_stages_total += len(stages)
-        self.parallel_width_max = max(self.parallel_width_max,
-                                      max(len(s) for s in stages))
         if self.apply_stages_hist is not None:
             self.apply_stages_hist.update(len(stages))
             for s in stages:
@@ -884,16 +874,6 @@ class LedgerManager:
             self._record_applied(txs[i], meta, elapsed,
                                  result_pairs, tx_metas)
         return result_pairs, tx_metas
-
-    def parallel_apply_report(self) -> dict:
-        """Cumulative conflict-staged apply shape since start/reset —
-        the CATCHUP artifact's `parallel_apply` section
-        (scripts/check_artifacts.py pins it SINCE r19)."""
-        return {"workers": self.apply_parallel,
-                "ledgers": self.parallel_ledgers,
-                "stages_total": self.parallel_stages_total,
-                "width_max": self.parallel_width_max,
-                "fallbacks": self.apply_fallbacks}
 
     def _apply_stage(self, ltx, applicable, txs, verify, footprints,
                      stage, sleep_cum, out: dict) -> None:

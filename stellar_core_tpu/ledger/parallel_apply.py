@@ -34,8 +34,8 @@ three pieces the LedgerManager's staged apply path composes:
 
 The GIL note: stage concurrency pays off only in the portions that
 release the GIL — native signature verification, the OP_APPLY_SLEEP
-synthetic cost model, SQL in other configurations — which is exactly
-what the APPLYPAR bench measures.
+synthetic cost model, SQL in other configurations. What it gains in
+a benchmark cell is not measured on the chip.
 """
 
 from __future__ import annotations

@@ -92,7 +92,7 @@ class Application:
         from ..util.tracing import FlightRecorder
         self.perf = ZoneRegistry()
         # flight recorder (util/tracing.py): idle until the admin
-        # `starttrace` route / bench --trace starts it; the perf zones
+        # `starttrace` route (or a caller) starts it; the perf zones
         # route their begin/end events through it while recording
         self.flight_recorder = FlightRecorder()
         self.perf.tracer = self.flight_recorder

@@ -682,8 +682,7 @@ class CommandHandler:
         drained/readmitted — gated behind ALLOW_CHAOS_INJECTION like
         the chaos route: a production node must not accept forced
         degradation over HTTP. Plain status is always served; the
-        cluster harness (simulation/cluster.py) polls it per node into
-        CLUSTER artifacts."""
+        cluster harness (simulation/cluster.py) polls it per node."""
         sup = getattr(self.app, "batch_verifier", None)
         if sup is None or not hasattr(sup, "breaker_state"):
             return {"exception": "no supervised device backend "
@@ -720,8 +719,8 @@ class CommandHandler:
         instead. `limit=N` serves the OLDEST N pending samples with
         the cursor pointing at the last one served (`truncated:
         true`), so chained limited scrapes walk the series gap-free.
-        `summary=1` returns the bounded series summary (the bench
-        artifact form) rather than raw samples."""
+        `summary=1` returns the bounded series summary rather than
+        raw samples."""
         tel = self.app.telemetry
         if params.get("summary") in ("1", "true"):
             from ..util.timeseries import summarize_samples
@@ -752,7 +751,7 @@ class CommandHandler:
         ALLOW_CHAOS_INJECTION like the chaos/backendstatus actions: a
         production node must not accept control-plane overrides over
         HTTP. Plain status is always served; simulation/cluster.py
-        polls it into CLUSTER artifacts."""
+        polls it per node."""
         ctl = self.app.controller
         action = params.get("action")
         if action:

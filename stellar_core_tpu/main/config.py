@@ -352,10 +352,9 @@ class Config:
         # VERIFY_BATCH_DEADLINE_MS, then dispatch as one device batch
         self.VERIFY_BATCH_DEADLINE_MS = 2.0
         self.VERIFY_MAX_BATCH = 256
-        # flushes below this many signatures run native per-signature —
-        # the fixed device dispatch cost loses to the host verifier
-        # there (bench.py --min-batch records the measured crossover;
-        # VERIFY_DEVICE_MIN_BATCH=<n> in the environment overrides)
+        # flushes below this many signatures run native per-signature:
+        # a dispatch's fixed cost is paid per batch (the crossover is
+        # not measured on the chip)
         self.VERIFY_DEVICE_MIN_BATCH = 16
 
         # device-backend supervisor (ops/backend_supervisor.py): the
@@ -424,7 +423,7 @@ class Config:
         # flood-admission shed probabilities from the SLO watchdog's
         # WARN/BREACH verdicts plus a learned-backlog surge gate.
         # 0 leaves the timer unarmed — tick() still works, which is
-        # how the surge bench and virtual-time tests drive
+        # how virtual-time tests drive
         # deterministic control steps (the TELEMETRY_SAMPLE_PERIOD
         # discipline). Frozen/reset over the `controller` admin route.
         self.CONTROLLER_TICK_PERIOD = 1.0

@@ -722,9 +722,8 @@ static void batch_prepare_range(const uint8_t* pubs, const uint8_t* sigs,
 }
 
 // Per-signature SHA-512 prep is embarrassingly parallel; split across
-// hardware threads so the ~47k sig/s single-core ceiling documented in
-// docs/KERNEL_PROFILE.md §4 scales with the host instead of bounding the
-// whole pipeline (the ctypes caller already releases the GIL). One core
+// hardware threads so that it scales with the host instead of being
+// bounded by one core (the ctypes caller already releases the GIL). One core
 // (or small batches, where thread spawn would dominate) keeps the serial
 // path.
 void sc_ed25519_batch_prepare(const uint8_t* pubs, const uint8_t* sigs,

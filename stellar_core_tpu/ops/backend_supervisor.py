@@ -76,8 +76,8 @@ degradation never reads as a full outage — transition counters,
 dispatch/skip counters, failure classes, probe timer), plus per-device
 ``crypto.verify_backend.device<N>.{dispatch,skip}`` counters. Breaker
 transitions append to a bounded log with PER-DEVICE dispatch-counter
-snapshots — the zero-dispatch-while-OPEN proof the chaos verdicts and
-the MESH artifact audit — and emit flight-recorder instants
+snapshots — the zero-dispatch-while-OPEN proof the chaos verdicts
+audit — and emit flight-recorder instants
 (``backend.breaker``) on aggregate changes. The ``backendstatus``
 admin route reports per-device rows and accepts forced
 ``trip``/``reset`` actions, whole-mesh or ``device=N``-targeted,
@@ -232,7 +232,7 @@ class BackendSupervisor:
         self._shut_down = False
         # [(clock time, from, to, reason, total dispatches so far,
         #   device index, THAT device's dispatches so far)] — the chaos
-        # scenario and the MESH artifact assert zero dispatches while
+        # scenario asserts zero dispatches while
         # OPEN from the per-device counter snapshots in here. Bounded
         # like the flight recorder's ring buffer: a flapping device
         # appends forever, and status() serializes the whole list on

@@ -73,11 +73,11 @@ Observability: ``controller.*`` counters/gauges (metrics route +
 Prometheus), flight-recorder instants on every knob/shed change, a
 bounded decision log, and the ``controller`` admin route
 (``?action=freeze|reset`` behind ``ALLOW_CHAOS_INJECTION``) that
-``simulation/cluster.py`` polls into CLUSTER artifacts.
+``simulation/cluster.py`` polls per node.
 ``clearmetrics`` routes through ``reset()``: learned knob values,
 shed probabilities and the decision log all drop and the controller
 epoch rotates — exactly the PR 10 time-series contract, so
-back-to-back bench legs in one process cannot leak tuning.
+back-to-back measured windows in one process cannot leak tuning.
 """
 
 from __future__ import annotations
@@ -594,7 +594,7 @@ class AdaptiveController:
     # ----------------------------------------------------------------- view --
     def status(self) -> dict:
         """The `controller` admin route document (also what
-        simulation/cluster.py polls into CLUSTER artifacts)."""
+        simulation/cluster.py polls per node)."""
         return {
             "enabled": self.period_s > 0,
             "period_s": self.period_s,

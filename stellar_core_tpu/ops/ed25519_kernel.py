@@ -62,10 +62,9 @@ D2 = fe8.const((2 * ((-121665 * pow(121666, _ref.P - 2, _ref.P)) % _ref.P))
 # kept switchable for future hardware.
 WIDE_MULS = False
 
-# ladder scan unrolling (XLA scheduling freedom across iterations);
-# round-2 measurement on v5e: see docs/KERNEL_NOTES.md
-import os as _os
-SCAN_UNROLL = int(_os.environ.get("ED25519_SCAN_UNROLL", "1"))
+# ladder scan unrolling (XLA scheduling freedom across iterations):
+# one step an iteration; other factors are not measured on the chip
+SCAN_UNROLL = 1
 
 
 def _mulw(xs, ys):

@@ -120,9 +120,9 @@ del _i
 def _use_rolled() -> bool:
     """Pick the mul formulation for the backend this trace targets.
 
-    The rolled-FMA form is the TPU shape (zero dynamic-update-slices —
-    docs/KERNEL_PROFILE.md measured the scatter-add form spending 70%
-    of ladder time in data movement). The XLA *CPU* backend is the
+    The rolled-FMA form is the TPU shape (zero dynamic-update-slices;
+    the scatter-add form lowers to 32 of them a multiply, not measured
+    on today's chip). The XLA *CPU* backend is the
     opposite: it compiles the 32-distinct-roll scan body pathologically
     slowly (minutes per bucket shape vs seconds for the scatter-add
     form), and tests/dryrun always run on the CPU mesh. Decided at
@@ -138,8 +138,7 @@ def _mul_rolled(a, b):
         c[k] = sum_i a_i * b_{(k-i) mod 32} * W[i,k]
     (W applies x38 to wrapped columns). This shape matters on TPU: the
     63-column scatter-add version (`c.at[i:i+32].add(...)`) lowered to
-    32 dynamic-update-slices PER MULTIPLY and the device trace showed
-    70% of ladder time in pure data movement (docs/KERNEL_PROFILE.md);
+    32 dynamic-update-slices PER MULTIPLY, pure data movement;
     rolls + multiply-adds fuse into one elementwise loop instead.
 
     Carry schedule (round 4): with MUL_INPUT_BOUND = 1349 inputs every

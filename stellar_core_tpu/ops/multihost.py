@@ -28,7 +28,8 @@ from jax.sharding import Mesh, PartitionSpec as PSpec
 from jax import shard_map
 
 from . import ed25519_kernel
-from .verifier import MIN_BUCKET, ShardedBatchVerifier, TpuBatchVerifier
+from .verifier import (DEVICE_MIN_BATCH, MIN_BUCKET, ShardedBatchVerifier,
+                       TpuBatchVerifier)
 
 
 def initialize_distributed(coordinator: Optional[str] = None,
@@ -88,11 +89,10 @@ class HybridShardedVerifier(ShardedBatchVerifier):
     workload has no cross-shard traffic to place anyway)."""
 
     def __init__(self, mesh: Optional[Mesh] = None, perf=None,
-                 device_sha=None, device_min_batch=None, metrics=None):
+                 device_min_batch=DEVICE_MIN_BATCH, metrics=None):
         full = mesh if mesh is not None else make_hybrid_mesh()
         super().__init__(devices=list(full.devices.flat), axis="dp",
-                         perf=perf, device_sha=device_sha,
-                         device_min_batch=device_min_batch,
+                         perf=perf, device_min_batch=device_min_batch,
                          metrics=metrics)
         self.mesh = full
 

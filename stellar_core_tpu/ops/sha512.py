@@ -1,9 +1,8 @@
 """Batch SHA-512 + exact mod-L reduction on device (TPU, JAX/XLA).
 
 Closes the last host-side per-signature cost in the verify pipeline:
-k = SHA512(R‖A‖M) mod L was computed by one host core at ~47 k sig/s
-(docs/KERNEL_PROFILE.md §4), bounding end-to-end throughput regardless
-of kernel speed. For the dominant workload — transaction signatures,
+k = SHA512(R‖A‖M) mod L was computed by one host core, per signature,
+whatever the kernel's speed. For the dominant workload — transaction signatures,
 which verify over a fixed 32-byte contents hash (SURVEY.md §3.2
 "message shapes"; reference: transactions/TransactionFrame.cpp:99-107)
 — R‖A‖M is exactly 96 bytes, one SHA-512 block after padding, with a
@@ -116,14 +115,12 @@ def _small_sigma1(h, l):
 _K_ARR = np.array([[k >> 32, k & 0xFFFFFFFF] for k in _K], dtype=np.uint32)
 
 
-import os as _os
-
 # Scan-unroll factor for the 80 compression rounds: the sweet spot
 # between compile time (fully unrolled ≈5k serially-dependent uint32 ops
 # send XLA CPU past 9 minutes and stall the chip compile too) and
 # scan-step overhead (each step copies the (16,2,B) schedule ring).
-# Factors of 80 only. Swept on chip — see docs/KERNEL_PROFILE.md §5.
-SHA_UNROLL = int(_os.environ.get("ED25519_SHA_UNROLL", "8"))
+# Factors of 80 only; other factors are not measured on the chip.
+SHA_UNROLL = 8
 
 
 def sha512_96(r_u8, a_u8, m_u8):

@@ -56,44 +56,15 @@ from ..util import chaos, tracing
 
 MIN_BUCKET = 8
 
-# On-device SHA-512 for fixed-32-byte messages (the tx-hash hot path).
-# Default ON: in the node the host core is the apply/consensus
-# bottleneck, and freeing it from per-signature SHA-512 prep measured
-# +13% catchup throughput (docs/KERNEL_PROFILE.md §5). A harness whose
-# host is otherwise idle (the isolated verify bench) does better with
-# host-side prep overlapped behind device compute — pass
-# device_sha=False there. ED25519_DEVICE_SHA=0/1 overrides both for A/B.
-# Semantics are identical either way (differentially enforced in
-# tests/test_tpu_verifier.py).
-import os as _os
-
-
-def _device_sha_default(explicit):
-    env = _os.environ.get("ED25519_DEVICE_SHA")
-    if env is not None:
-        return env != "0"
-    return True if explicit is None else explicit
-
-
-# Small-batch CPU bypass for verify_tuples_async: below this many
-# signatures the fixed dispatch cost (array packing, transfer, XLA
-# launch, result sync) loses to the native per-signature verifier, so
-# tiny batches run on host instead (bench.py --min-batch measures the
-# crossover; docs/APPLY_PERF.md records it). Semantics are identical
-# either way — both paths are the same strict verify. The module
-# default of 1 means "never bypass" so the kernel test tier keeps
-# exercising the device path down to batch size 1; the node wires its
-# VERIFY_DEVICE_MIN_BATCH config knob through Application.
-# VERIFY_DEVICE_MIN_BATCH=<n> in the environment overrides both for A/B,
-# like ED25519_DEVICE_SHA.
+# Below this many signatures verify_tuples_async skips the device: the
+# fixed cost of a dispatch (packing, transfer, launch, result sync) is
+# paid per batch, so tiny batches go to the native per-signature
+# verifier instead. The crossover is not measured on the chip. Both
+# paths are the same strict verify. The module default of 1 means
+# "never bypass", so the kernel tests exercise the device path down to
+# a batch of one; the node passes its VERIFY_DEVICE_MIN_BATCH config
+# field as the constructor's `device_min_batch`.
 DEVICE_MIN_BATCH = 1
-
-
-def _device_min_batch_default(explicit):
-    env = _os.environ.get("VERIFY_DEVICE_MIN_BATCH")
-    if env is not None:
-        return int(env)
-    return DEVICE_MIN_BATCH if explicit is None else int(explicit)
 
 
 def _bucket_size(n: int, minimum: int = MIN_BUCKET) -> int:
@@ -196,14 +167,13 @@ class TpuBatchVerifier:
             TpuBatchVerifier._shared_jit_msg32 = jax.jit(
                 ed25519_kernel.verify_kernel_msg32)
 
-    def __init__(self, perf=None, device_sha=None, device_min_batch=None,
+    def __init__(self, perf=None, device_min_batch=DEVICE_MIN_BATCH,
                  metrics=None):
         self._ensure_shared_jits()
         self._jit = TpuBatchVerifier._shared_jit
         self._jit_msg32 = TpuBatchVerifier._shared_jit_msg32
         self._min_bucket = MIN_BUCKET
-        self._device_sha = _device_sha_default(device_sha)
-        self._device_min_batch = _device_min_batch_default(device_min_batch)
+        self._device_min_batch = int(device_min_batch)
         self.perf = perf  # per-app zone registry (None = process default)
         self._init_dispatch_metrics(metrics)
 
@@ -227,7 +197,7 @@ class TpuBatchVerifier:
         ROADMAP item 1 groundwork): batch size, padding waste (lanes
         burnt on the power-of-two bucket), and dispatch→collect wall
         time — the per-device health signals a per-device breaker will
-        consume. None = accounting off (the bench/test constructors)."""
+        consume. None = accounting off (constructors outside a node)."""
         # running number of the batches given to verify_tuples_async:
         # the `batch` arg of every span in the life of one batch (pack,
         # enqueue, collect here; adoption in catchup), on whichever
@@ -254,7 +224,7 @@ class TpuBatchVerifier:
                            msgs: Sequence[bytes], _active=None):
         """Dispatch a batch without blocking; returns a zero-arg callable
         that yields the (n,) bool results. Callers with several batches in
-        flight (catchup prevalidation, the bench harness) overlap host
+        flight (catchup prevalidation) overlap host
         SHA-512 + transfer of batch i+1 with device compute of batch i.
         `_active` pins an explicit device set on a mesh verifier (the
         per-device canary probe path); None uses the live mesh."""
@@ -270,9 +240,9 @@ class TpuBatchVerifier:
         pubs = np.asarray(pubs, dtype=np.uint8).reshape(n, 32)
         sigs = np.asarray(sigs, dtype=np.uint8).reshape(n, 64)
         bucket = _bucket_size(n, self._min_bucket)
-        if self._device_sha and all(len(m) == 32 for m in msgs):
+        if all(len(m) == 32 for m in msgs):
             # tx-hash hot path: ship M raw, SHA-512 + mod L on device —
-            # zero per-signature host work (docs/KERNEL_PROFILE.md §4)
+            # no per-signature host work
             fn = self._jit_msg32
             last = np.frombuffer(b"".join(msgs),
                                  dtype=np.uint8).reshape(n, 32)
@@ -410,11 +380,9 @@ class ShardedBatchVerifier(TpuBatchVerifier):
     count ≥ MIN_BUCKET, never from a power of two."""
 
     def __init__(self, devices: Optional[list] = None, axis: str = "dp",
-                 perf=None, device_sha=None, device_min_batch=None,
-                 metrics=None):
+                 perf=None, device_min_batch=DEVICE_MIN_BATCH, metrics=None):
         self.perf = perf
-        self._device_sha = _device_sha_default(device_sha)
-        self._device_min_batch = _device_min_batch_default(device_min_batch)
+        self._device_min_batch = int(device_min_batch)
         self.devices = list(devices) if devices is not None \
             else list(jax.devices())
         self.ndev = len(self.devices)
@@ -550,7 +518,7 @@ class ShardedBatchVerifier(TpuBatchVerifier):
                 off += c
             return out
 
-        msg32 = self._device_sha and all(len(m) == 32 for m in msgs)
+        msg32 = all(len(m) == 32 for m in msgs)
         if msg32:
             # tx-hash hot path: SHA-512 + mod L on device (see
             # TpuBatchVerifier._pack)

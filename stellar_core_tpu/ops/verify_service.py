@@ -424,7 +424,7 @@ class VerifyService:
                                     for fl in self._inflight)}
 
     def stats(self) -> dict:
-        """Service counters for self-check / bench artifacts."""
+        """Service counters for self-check and the scenario runners."""
         occ = self._occupancy.to_json()
         qw = self._queue_wait.to_json()
         return {

@@ -40,9 +40,8 @@ def merge_flood_evidence(into: dict, add: dict) -> None:
     """Sum numeric leaves of one node's flood-evidence dict (the
     `demand_report`/`encode_report`/`flood_kind_report` shapes) into a
     cross-node total — nested dicts recursed, bools and
-    `DERIVED_EVIDENCE_KEYS` excluded. Shared by bench's in-process
-    `_flood_report` and the cluster harness's over-HTTP `flood_report`
-    so the two artifact families can't drift."""
+    `DERIVED_EVIDENCE_KEYS` excluded. Used by the cluster harness's
+    over-HTTP `flood_report`."""
     for k, v in (add or {}).items():
         if k in DERIVED_EVIDENCE_KEYS:
             continue
@@ -368,7 +367,7 @@ class OverlayManager:
 
     def demand_report(self) -> dict:
         """Aggregate single-flight demand snapshot (peers route /
-        bench + cluster flood sections): `outstanding` is the live
+        the cluster harness's flood section): `outstanding` is the live
         table size; `suppressed` counts demands single-flight avoided
         (each one used to be a guaranteed duplicate body);
         `single_flight_efficiency` = share of advertised fetches the

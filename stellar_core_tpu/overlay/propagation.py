@@ -19,8 +19,7 @@ Duplicate accounting answers ROADMAP item 3's question — how much of
 the wire path is redundant delivery: `overlay.flood.unique` vs
 `overlay.flood.duplicate` counters (metrics route + Prometheus),
 per-peer `duplicates` on the `peers` route, and a redundancy ratio in
-`report()` (surfaced by `clusterstatus` and the TPSM/TPSMT bench
-artifacts as the before-picture for pull-mode flooding).
+`report()` (surfaced by `clusterstatus`).
 """
 
 from __future__ import annotations
@@ -148,8 +147,8 @@ class PropagationTracker:
 
     # ------------------------------------------------------------ report --
     def report(self) -> dict:
-        """Flood-redundancy snapshot (clusterstatus route, bench
-        artifacts): duplicate_ratio is redundant deliveries per unique
+        """Flood-redundancy snapshot (clusterstatus route):
+        duplicate_ratio is redundant deliveries per unique
         message — the number pull-mode flooding must drive toward 0."""
         total = self.unique + self.duplicates
         return {

@@ -13,8 +13,8 @@ Recording the inputs therefore makes every run an offline unit test:
   external tx/admin submission sites)
 - ``replay.replayer`` — rebuilds the node from the recorded config
   snapshot and re-feeds the log on a fresh VirtualClock
-- ``replay.scenario`` — the recorded 4-node seeded chaos scenario the
-  tier-1 round-trip test and ``bench.py --replay`` share
+- ``replay.scenario`` — the recorded 4-node seeded chaos scenario of
+  the tier-1 round-trip test
 
 All four modules are in the determinism analyzer's STRICT scope
 (analysis/determinism.py): a wall-clock or RNG read anywhere in this

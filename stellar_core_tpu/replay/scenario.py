@@ -1,8 +1,8 @@
 """The canonical recorded scenario: a 4-node seeded chaos run with
 every node's inputs captured for replay.
 
-This is the tier-1 round-trip fixture AND the `bench.py --replay`
-workload: record once live, replay each node twice, assert the header
+This is the tier-1 round-trip fixture: record once live, replay each
+node twice, assert the header
 chains and controller decision logs match the live run byte-for-byte
 and the two replays' flight-recorder traces are zero-diff.
 

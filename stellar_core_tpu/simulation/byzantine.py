@@ -25,10 +25,6 @@ Scenario shapes:
   (`SimulatedChurn`), restarted from its persisted DB + bucket dir a
   few slots later, and must catch back up over the overlay while the
   equivocator is still active.
-- ``run_byzantine_bench`` — the ``bench.py --byzantine`` artifact:
-  measured slots-to-externalize under equivocation (vs a clean leg),
-  verify-service throughput under the bad-sig flood, and churn
-  recovery time.
 """
 
 from __future__ import annotations
@@ -40,7 +36,7 @@ from ..crypto.keys import SecretKey, clear_verify_cache
 from ..herder.tx_queue import AddResult
 from ..tx.frame import make_frame
 from ..util import chaos
-from ..util.chaos import ChaosEngine, FaultSpec, SimulatedCrash
+from ..util.chaos import ChaosEngine, FaultSpec
 from ..util.logging import get_logger
 from ..xdr.ledger_entries import Asset, AssetType, LedgerKey
 from ..xdr.transaction import (DecoratedSignature, Memo, MemoType,
@@ -417,68 +413,3 @@ def run_tiered_chaos(seed: int = 11, n_orgs: int = 3,
     finally:
         chaos.uninstall()
         sim.stop_all_nodes()
-
-
-def run_byzantine_bench(seed: int = 7) -> dict:
-    """``bench.py --byzantine`` artifact: all figures MEASURED in this
-    process — slots-to-externalize under equivocation vs a clean run
-    of the same topology, verify-service throughput under the bad-sig
-    flood (valid+forged submissions over the faulted leg's wall time),
-    and churn recovery time on a 9-node tiered network with persisted
-    node state."""
-    import shutil
-    import tempfile
-
-    clean = run_smoke(seed=seed, with_faults=False)
-    byz = run_smoke(seed=seed, with_faults=True)
-    flood_wall = byz["wall_seconds"]
-    verify_tput = round(byz["verify_submitted"] / flood_wall, 1) \
-        if flood_wall else None
-    clean_tput = round(clean["verify_submitted"] /
-                       clean["wall_seconds"], 1) \
-        if clean["wall_seconds"] else None
-    root = tempfile.mkdtemp(prefix="byz-churn-")
-    try:
-        churn = run_tiered_chaos(
-            seed=seed + 1, n_orgs=3, validators_per_org=3, watchers=0,
-            target_slots=6, data_dir=root, churn_down_slots=1)
-    except (Exception, SimulatedCrash) as e:      # noqa: BLE001
-        churn = {"ok": False, "error": repr(e)}
-    finally:
-        shutil.rmtree(root, ignore_errors=True)
-    ok = bool(byz["ok"] and byz["flooder_dropped"] and
-              churn.get("ok"))
-    return {
-        "metric": "byzantine_convergence",
-        "value": 1.0 if ok else 0.0,
-        "unit": "pass",
-        "vs_baseline": 1.0 if ok else 0.0,
-        "slots_to_externalize": {
-            "clean_virtual_s_per_slot": clean["virtual_s_per_slot"],
-            "byzantine_virtual_s_per_slot": byz["virtual_s_per_slot"],
-            "slowdown": round(byz["virtual_s_per_slot"] /
-                              clean["virtual_s_per_slot"], 3)
-            if clean["virtual_s_per_slot"] else None,
-        },
-        "verify_under_flood": {
-            "submitted": byz["verify_submitted"],
-            "flushes": byz["verify_flushes"],
-            "verifies_per_s_wall": verify_tput,
-            "clean_verifies_per_s_wall": clean_tput,
-            "bad_sig_drops": byz["bad_sig_drops"],
-            "flooder_dropped": byz["flooder_dropped"],
-        },
-        "churn": {
-            "recovery_virtual_s":
-                (churn.get("churn") or {}).get("recovery_virtual_s"),
-            "caught_up": (churn.get("churn") or {}).get("caught_up"),
-            "safety_ok": churn.get("safety_ok"),
-        },
-        "smoke": {k: byz[k] for k in
-                  ("ok", "safety_ok", "injected", "virtual_seconds")},
-        "tiered_churn": churn,
-        # the faulted leg's merged time-series summary + SLO section
-        # (ISSUE 10 artifact contract, linted by check_artifacts)
-        "timeseries": byz.get("timeseries", {"samples": 0}),
-        "slo": byz.get("slo", {"overall": "OK", "rules": {}}),
-    }

@@ -467,9 +467,8 @@ def run_scenario(seed: int = 6, target: int = DEFAULT_TARGET,
                     chaos_b["injected"] == chaos_a["injected"])
 
     classes = sorted(k.split(".")[-1] for k in chaos_a["injected"])
-    # the archive leg is part of the verdict (see below for the
-    # single-node device-outage leg, run_device_outage): a fetch that
-    # never recovers from the injected failure is a failed fault class
+    # the archive leg is part of the verdict: a fetch that never
+    # recovers from the injected failure is a failed fault class
     archive_ok = chaos_a["archive"] is None or \
         bool(chaos_a["archive"]["ok"])
     # node 0's circuit breaker must have tripped on the outage window,
@@ -497,139 +496,6 @@ def run_scenario(seed: int = 6, target: int = DEFAULT_TARGET,
         "virtual_seconds": chaos_a["virtual_end"],
         "baseline_virtual_seconds": baseline["virtual_end"],
     }
-
-
-def run_device_outage(seed: int = 9, ledgers: int = 14,
-                      outage_at: int = 4) -> dict:
-    """Single-node device-outage leg for ``bench.py --chaos`` (ISSUE 5
-    satellite): fail the supervised backend mid-run and measure the
-    operational envelope the breaker buys — time-to-trip (how long the
-    node pays failure latency), degraded-mode tps (ledgers closed while
-    the breaker is OPEN and every verify is native), and
-    time-to-recovery (outage end → breaker re-CLOSED via a canary
-    probe).
-
-    A MANUAL_CLOSE standalone node closes `ledgers` ledgers, each
-    carrying one root self-payment admitted through
-    ``herder.recv_transactions`` so the envelope signature rides the
-    verify service into the supervised backend (one dispatch per
-    ledger). From ledger `outage_at` a seeded chaos schedule fails
-    ``DEVICE_OUTAGE_FAULTS`` consecutive dispatches; between ledgers
-    the virtual clock advances one second so the breaker's backoff
-    probes fire on schedule. Times are VIRTUAL seconds (deterministic);
-    tps is wall-clock (the artifact's measurement)."""
-    import time as _time
-
-    from ..ledger.ledger_txn import LedgerTxn
-    from ..main import Application, get_test_config
-    from ..util.timer import ClockMode, VirtualClock
-    from ..xdr.types import PublicKey
-
-    from ..crypto.keys import clear_verify_cache
-    clear_verify_cache()
-    cfg = get_test_config()
-    cfg.SIGNATURE_VERIFY_BACKEND = "tpu"
-    # every dispatch stays on the host (no XLA compiles in the bench
-    # leg); the breaker semantics under test are identical either way
-    cfg.VERIFY_DEVICE_MIN_BATCH = 1 << 20
-    cfg.VERIFY_BREAKER_CANARY_BATCH = 4
-    cfg.VERIFY_BREAKER_PROBE_BASE_MS = 500.0
-    cfg.VERIFY_BREAKER_PROBE_MAX_MS = 2000.0
-    clock = VirtualClock(ClockMode.VIRTUAL_TIME)
-    app = Application.create(clock, cfg)
-    app.start()
-    sup = app.batch_verifier
-    key = SecretKey.from_seed(cfg.network_id())
-    with LedgerTxn(app.ledger_manager.root) as ltx:
-        le = ltx.load_without_record(LedgerKey.account(
-            PublicKey.ed25519(key.public_key().raw)))
-        seq = le.data.value.seqNum
-    phase_wall: Dict[str, List[float]] = {}
-    outage_started_at = None
-    try:
-        for i in range(ledgers):
-            if i == outage_at:
-                chaos.install(ChaosEngine(seed, [FaultSpec(
-                    "ops.backend.dispatch", "io_error", start=0,
-                    count=DEVICE_OUTAGE_FAULTS,
-                    match={"node": cfg.node_id().hex()})]))
-                outage_started_at = clock.now()
-            seq += 1
-            muxed = MuxedAccount.from_ed25519(key.public_key().raw)
-            tx = Transaction(
-                sourceAccount=muxed, fee=100, seqNum=seq,
-                cond=Preconditions(PreconditionType.PRECOND_NONE),
-                memo=Memo(MemoType.MEMO_NONE),
-                operations=[Operation(
-                    sourceAccount=None,
-                    body=_OperationBody(
-                        OperationType.PAYMENT, PaymentOp(
-                            destination=muxed,
-                            asset=Asset(AssetType.ASSET_TYPE_NATIVE),
-                            amount=1)))],
-                ext=_TxExt(0))
-            env = TransactionEnvelope(
-                EnvelopeType.ENVELOPE_TYPE_TX,
-                TransactionV1Envelope(tx=tx, signatures=[]))
-            probe = make_frame(env, cfg.network_id())
-            env.value.signatures = [DecoratedSignature(
-                hint=key.public_key().hint(),
-                signature=key.sign(probe.contents_hash()))]
-            frame = make_frame(env, cfg.network_id())
-            # classify by breaker state AT DISPATCH: the ledger whose
-            # failing verify trips the breaker pays failure latency
-            # with the breaker still CLOSED on entry — it belongs in
-            # "failing", not in the degraded-tps "open" bucket
-            state = sup.state
-            tripped = any(t[2] == "OPEN" for t in sup.transitions)
-            t0 = _time.perf_counter()
-            res = app.herder.recv_transactions([frame])[0]
-            if res != AddResult.ADD_STATUS_PENDING:
-                raise RuntimeError(f"outage-leg tx rejected: {res}")
-            app.manual_close()
-            if outage_started_at is None:
-                ph = "before"
-            elif state != "CLOSED":
-                ph = "open"                # degraded mode: native, no
-                #                            device attempt
-            elif tripped:
-                ph = "after"               # breaker re-closed, healthy
-            else:
-                ph = "failing"             # outage active, not yet
-                #                            tripped: the full failure
-                #                            latency the breaker exists
-                #                            to eliminate
-            phase_wall.setdefault(ph, []).append(
-                _time.perf_counter() - t0)
-            # advance virtual time so backoff probe timers fire
-            clock.crank_for(1.0)
-        verdict = _breaker_verdict(sup.status())
-        trans = {(t["from"], t["to"]): t["t"]
-                 for t in reversed(verdict.get("transitions", []))}
-        tripped_at = trans.get(("CLOSED", "OPEN"))
-        reclosed_at = None
-        for t in verdict.get("transitions", []):
-            if t["to"] == "CLOSED":
-                reclosed_at = t["t"]
-        tps = {ph: round(len(v) / sum(v), 1)
-               for ph, v in phase_wall.items() if v}
-        return {
-            "ok": bool(verdict["ok"]),
-            "ledgers": ledgers,
-            "outage_faults": DEVICE_OUTAGE_FAULTS,
-            "time_to_trip_s": round(tripped_at - outage_started_at, 3)
-            if tripped_at is not None and outage_started_at is not None
-            else None,
-            "time_to_recovery_s": round(reclosed_at - tripped_at, 3)
-            if reclosed_at is not None and tripped_at is not None
-            else None,
-            "degraded_tps": tps.get("open"),
-            "tps": tps,
-            "breaker": verdict,
-        }
-    finally:
-        chaos.uninstall()
-        app.shutdown()
 
 
 class _HostMeshVerifier:

@@ -35,10 +35,8 @@ through the admin HTTP API a real operator would use:
   into ONE cluster-wide Chrome trace by
   ``util/tracemerge.merge_trace_docs`` (wall-clock-anchored lanes).
 
-Consumers: ``bench.py --tps-cluster`` (the CLUSTER artifact: the first
-wall-clock-faithful multinode numbers beside the in-process TPSM/TPSMT
-ones), ``tests/test_cluster_harness.py`` (tier-1 3-process smoke, slow
-9-node chaos leg).
+Consumer: ``tests/test_cluster_harness.py`` (tier-1 3-process smoke,
+slow 9-node chaos leg).
 """
 
 from __future__ import annotations
@@ -275,10 +273,6 @@ class Cluster:
             # ONLY in rendered harness configs — the chaos route's
             # install/clear modes stay refused on production nodes
             "ALLOW_CHAOS_INJECTION": True,
-            # input recording armed on every harness node (ISSUE 20
-            # satellite): a failed matrix cell ships each node's
-            # per-process replay log alongside its data_dir
-            "ALLOW_INPUT_RECORDING": True,
             "MAX_TX_SET_SIZE": self.max_tx_set_size,
             "TESTING_UPGRADE_MAX_TX_SET_SIZE": self.max_tx_set_size,
             # generous overlay catchup window: a kill -9'd node must be
@@ -677,71 +671,6 @@ class Cluster:
                 except (OSError, ValueError, ClusterError):
                     pass
 
-    # ---------------------------------------------------------- recording --
-    def record_all(self) -> Dict[str, str]:
-        """Arm streaming input recording on every live node
-        (`recordstart?path=<data_dir>/input.rec`, the ISSUE 18 flight
-        recorder): a failed matrix cell keeps each node's replay log
-        next to its sqlite/bucket state. Best-effort — a node already
-        recording (restart) just keeps its existing log."""
-        paths: Dict[str, str] = {}
-        for node in self.nodes:
-            if not node.alive:
-                continue
-            path = os.path.join(node.data_dir, "input.rec")
-            try:
-                node.get("recordstart", {"path": path})
-                paths[node.name] = path
-            except (OSError, ValueError, ClusterError):
-                if os.path.exists(path):
-                    paths[node.name] = path   # armed on a prior boot
-        return paths
-
-    def recordstop_all(self) -> None:
-        """Seal every node's streaming record (writes the END frame so
-        replay knows the log is complete, not truncated by a crash)."""
-        for node in self.nodes:
-            if node.alive:
-                try:
-                    node.get("recordstop")
-                except (OSError, ValueError, ClusterError):
-                    pass
-
-    def flow_report(self, deadline_s: float = 15.0) -> dict:
-        """Per-link outbound backpressure evidence off the `peers`
-        route (ISSUE 20): cluster-wide queue high-water vs the byte
-        budget, plus per-class shed totals. The verdicts the
-        backpressure cell gates on: a slow peer's queue never exceeds
-        its budget, and SCP is never shed while lower classes were
-        available to shed (the drop-priority contract — scp drops
-        require gossip+tx shed first, so scp_dropped stays 0 in every
-        matrix cell)."""
-        docs = self._sweep("peers", None, deadline_s,
-                           ok=lambda d: "authenticated_peers" in d)
-        high = 0
-        budget = 0
-        drops = {"scp": 0, "tx": 0, "gossip": 0}
-        for _name, doc in docs.items():
-            if doc is None:
-                continue
-            peers = doc["authenticated_peers"]
-            for row in peers.get("inbound", []) + \
-                    peers.get("outbound", []):
-                fl = row.get("flow") or {}
-                high = max(high, int(fl.get("queue_high_water", 0)))
-                budget = int(fl.get("queue_budget", 0)) or budget
-                for cls, n in (fl.get("drops") or {}).items():
-                    if cls in drops:
-                        drops[cls] += int(n)
-        return {
-            "queue_high_water_max": high,
-            "queue_budget": budget,
-            "drops": drops,
-            "within_budget": budget == 0 or high <= budget,
-            "scp_never_shed_first": drops["scp"] == 0
-            or (drops["tx"] + drops["gossip"]) > 0,
-        }
-
     # ------------------------------------------------------------ verdicts --
     def _sweep(self, command: str, params: Optional[dict],
                deadline_s: float,
@@ -802,8 +731,7 @@ class Cluster:
 
     def flood_report(self, deadline_s: float = 15.0) -> dict:
         """Aggregate flood redundancy + per-peer byte counters from
-        every live node's `peers` route (the bench _flood_report shape,
-        collected over HTTP)."""
+        every live node's `peers` route, collected over HTTP."""
         from ..overlay.manager import (finalize_flood_evidence,
                                        merge_flood_evidence)
         docs = self._sweep("peers", None, deadline_s,
@@ -896,8 +824,8 @@ class Cluster:
         return new[0]
 
     def series_summary(self) -> dict:
-        """Cluster-wide bounded series summary (the CLUSTER artifact
-        form): per-node summaries plus the aggregate envelope."""
+        """Cluster-wide bounded series summary: per-node summaries
+        plus the aggregate envelope."""
         from ..util.timeseries import (aggregate_summaries,
                                        summarize_samples)
         per_node = {n.name: summarize_samples(n.ts_samples)
@@ -911,7 +839,7 @@ class Cluster:
         """Sweep every live node's `controller` route (ISSUE 11): the
         adaptive control plane's live knob values, shed levels, and
         decision tallies, merged into per-node docs plus cluster-wide
-        shed/tune totals for the CLUSTER artifact."""
+        shed/tune totals."""
         docs = self._sweep("controller", None, deadline_s,
                            ok=lambda d: "controller" in d)
         per_node = {}
@@ -941,7 +869,7 @@ class Cluster:
         """Sweep every live node's `backendstatus` route (ISSUE 13):
         aggregate breaker state, the surviving-mesh summary and the
         per-device breaker rows, merged into per-node docs plus
-        cluster-wide degradation totals for the CLUSTER artifact. A
+        cluster-wide degradation totals. A
         node without a supervised device backend reports None."""
         docs = self._sweep("backendstatus", None, deadline_s,
                            ok=lambda d: "backend" in d
@@ -1060,12 +988,11 @@ def run_cluster_scenario(root_dir: str, n_orgs: int = 3,
                          trace_path: Optional[str] = None,
                          boot_deadline_s: float = 180.0,
                          log_level: str = "warning") -> dict:
-    """The full harness scenario (bench --tps-cluster / the slow test):
+    """The full harness scenario (tests/test_cluster_harness.py):
     boot a tiered process-per-node cluster over real TCP, measure pay
     TPS over the wire, run the chaos leg (seeded bad-sig flood over
     HTTP + a real kill -9 churn with catchup over the wire), and
-    collect all verdicts from the admin APIs. Returns the CLUSTER
-    artifact core (bench adds metric/host_load wrapping)."""
+    collect all verdicts from the admin APIs."""
     import time as _wall
 
     n_nodes = n_orgs * validators_per_org
@@ -1273,274 +1200,3 @@ def run_cluster_scenario(root_dir: str, n_orgs: int = 3,
         and (not churn or result["churn"]["caught_up"])
         and result.get("graceful_shutdown_ok"))
     return result
-
-
-# ---------------------------------------------------- scenario matrix --
-def run_matrix_cell(root_dir: str, cell: dict) -> dict:
-    """One cell of the wide-area survival matrix (ISSUE 20): boot a
-    real-process tiered mesh, drive the cell's load shape (uniform or
-    Zipf-skewed, optional surge burst), overlay its fault legs
-    (partition / flap / slow-link / sick-device — any subset), and
-    return a TYPED verdict doc the MATRIX artifact schema checks
-    per-cell:
-
-    - ``survival_ok`` — the quorum-holding side kept externalizing
-      through every fault window and no node process crashed;
-    - ``rejoin_ok`` — every partitioned/flapped-out node caught back
-      up to the network LCL within the cell's bounded rejoin window
-      (vacuously true for cells without a link fault);
-    - ``safety_ok`` — byte-identical header chains across ALL live
-      nodes over the common prefix (the byzantine.py verdict), which
-      is what makes a rejoin count: agreeing late is still agreeing;
-    - ``slo_ok`` — the cluster-wide SLO aggregate did not BREACH;
-    - ``crashes`` — node processes dead at verdict time (must be 0:
-      a minority partition STALLS safely, it never dies).
-
-    Every node records its input stream (`recordstart`, ISSUE 18) so a
-    failing cell ships per-node replay logs in ``record_paths``."""
-    import time as _wall
-
-    name = cell["name"]
-    n_orgs = int(cell.get("n_orgs", 3))
-    vpo = int(cell.get("validators_per_org", 1))
-    n_nodes = n_orgs * vpo
-    close_time = float(cell.get("close_time", 1.0))
-    target_slots = int(cell.get("target_slots", 3))
-    seed = int(cell.get("chaos_seed", 20))
-    cluster = Cluster(n_orgs, vpo, root_dir, close_time=close_time,
-                      log_level=cell.get("log_level", "warning"))
-    wall0 = _wall.perf_counter()
-    doc: dict = {"name": name, "nodes": n_nodes,
-                 "topology": f"tiered {n_orgs}x{vpo}",
-                 "survival_ok": False, "rejoin_ok": True,
-                 "safety_ok": False, "slo_ok": False,
-                 "crashes": n_nodes, "ok": False, "faults": []}
-    survival_ok = True
-    rejoin_ok = True
-    with cluster:
-        cluster.start_all(float(cell.get("boot_deadline_s", 240.0)))
-        cluster.wait_mesh(90.0 + 5.0 * n_nodes)
-        cluster.wait_slot(2, 120.0)
-        if cell.get("record", True):
-            doc["record_paths"] = cluster.record_all()
-        node0 = cluster.nodes[0]
-
-        # ---- load phase: the cell's traffic shape ------------------
-        cluster.generate_load(node0, "create",
-                              accounts=int(cell.get("accounts", 40)))
-        cluster.wait_slot(cluster.lcl(node0) + 2, 120.0)
-        load_mode = cell.get("load", "uniform")
-        txs_per_round = int(cell.get("txs_per_round", 80))
-        applied = 0
-        t0 = time.monotonic()
-        for _ in range(int(cell.get("rounds", 1))):
-            if load_mode == "zipf":
-                r = cluster.generate_load(
-                    node0, "zipf", txs=txs_per_round,
-                    exponent=float(cell.get("zipf_exponent", 1.2)))
-            else:
-                r = cluster.generate_load(node0, "pay",
-                                          txs=txs_per_round)
-            applied += int(r.get("submitted", 0))
-            if not cluster.drain_pending(node0, 180.0):
-                raise ClusterError(f"{name}: load never drained")
-            cluster.wait_slot(cluster.lcl(node0), 180.0)
-        dt = time.monotonic() - t0
-        doc["tps"] = round(applied / dt, 1) if dt else 0.0
-        doc["applied"] = applied
-
-        # ---- surge leg: one oversized burst ------------------------
-        surge = int(cell.get("surge", 0))
-        if surge:
-            doc["faults"].append("surge")
-            cluster.generate_load(node0, "pay", txs=surge)
-            if not cluster.drain_pending(node0, 240.0):
-                survival_ok = False
-            else:
-                cluster.wait_slot(cluster.lcl(node0), 180.0)
-
-        # ---- slow-link leg: WAN shapes on the real sockets ---------
-        sl = cell.get("slow_link")
-        if sl:
-            doc["faults"].append("slow_link")
-            latency = topologies.LinkLatency(
-                seed=int(sl.get("seed", 7)),
-                intra_org_ms=float(sl.get("intra_org_ms", 2.0)),
-                cross_org_ms=tuple(sl.get("cross_org_ms",
-                                          (30.0, 120.0))),
-                bandwidth_bps=sl.get("bps"))
-            cluster.install_schedules(
-                cluster.shape_schedules(
-                    latency, window_s=float(sl.get("window_s", 0.0))),
-                seed)
-            lcl0 = cluster.lcl(node0)
-            cluster.generate_load(node0, "pay",
-                                  txs=int(sl.get("txs", 60)))
-            try:
-                # shaped links are slow, not dead: consensus must keep
-                # externalizing under the WAN delays
-                cluster.wait_slot(lcl0 + 2, 300.0)
-            except ClusterError:
-                survival_ok = False
-            cluster.clear_all_chaos()
-
-        # ---- flap leg: one node's links cycle down/up under load ---
-        fl = cell.get("flap")
-        if fl:
-            doc["faults"].append("flap")
-            window = float(fl.get("window_s", 10.0))
-            victim = cluster.nodes[-1]
-            others = [n for n in cluster.nodes if n is not victim]
-            cluster.install_schedules(
-                cluster.flap_schedules(
-                    [(victim, nb) for nb in victim.neighbors],
-                    window,
-                    period_s=float(fl.get("period_s", 3.0)),
-                    duty=float(fl.get("duty", 0.4))),
-                seed + 1)
-            lcl0 = cluster.min_lcl(others)
-            cluster.generate_load(node0, "pay",
-                                  txs=int(fl.get("txs", 60)))
-            try:
-                cluster.wait_slot(lcl0 + 2, 240.0, nodes=others)
-            except ClusterError:
-                survival_ok = False
-            # let the windows elapse everywhere, then heal explicitly
-            # (belt and braces) and require the flapped node to catch
-            # back up — a flapping WAN link must degrade, not detach
-            time.sleep(window)
-            cluster.clear_all_chaos()
-            net = cluster.min_lcl(others)
-            caught = victim.poll(
-                "info", deadline=time.monotonic()
-                + float(fl.get("rejoin_s", 150.0)),
-                ok=lambda d: d.get("info", {}).get("ledger", {})
-                .get("num", 0) >= net)
-            if caught is None:
-                rejoin_ok = False
-
-        # ---- partition leg: cut one org off the quorum -------------
-        pt = cell.get("partition")
-        if pt:
-            doc["faults"].append("partition")
-            window = float(pt.get("window_s", 10.0))
-            minority = cluster.nodes[:vpo]           # org 0, < top tier
-            majority = cluster.nodes[vpo:]
-            maj0 = majority[0]
-            cluster.install_schedules(
-                cluster.partition_schedules(minority, window),
-                seed + 2)
-            # traffic originates on the MAJORITY side: the partition
-            # fires at the send/dial seams, so the cut links must see
-            # sends — SCP traffic alone would do it, load makes it
-            # immediate
-            cluster.generate_load(maj0, "create", accounts=8)
-            lcl0 = cluster.min_lcl(majority)
-            try:
-                cluster.wait_slot(lcl0 + 3, 240.0, nodes=majority)
-            except ClusterError:
-                survival_ok = False
-            # the minority must STALL SAFELY: still alive, no crash.
-            # ONE short request per stalled node — they just lost
-            # their quorum, a retried poll would burn the cell budget
-            mlcls = []
-            for n in minority:
-                v = cluster._lcl_or_unknown(n)
-                mlcls.append(v if isinstance(v, int) else 0)
-            doc["partition"] = {
-                "window_s": window,
-                "minority": [n.name for n in minority],
-                "majority_lcl_mid": cluster.min_lcl(majority),
-                "minority_alive_mid": all(n.alive for n in minority),
-                "minority_lcl_mid": min(mlcls),
-            }
-            if not doc["partition"]["minority_alive_mid"]:
-                survival_ok = False
-            # heal: let every window elapse, clear any remainder, and
-            # re-knit the mesh (jittered dial retry + connect nudges)
-            time.sleep(window)
-            cluster.clear_all_chaos()
-            try:
-                cluster.wait_mesh(120.0 + 5.0 * n_nodes)
-            except ClusterError:
-                rejoin_ok = False
-            net = cluster.min_lcl(majority)
-            rejoin_deadline = time.monotonic() \
-                + float(pt.get("rejoin_s", 180.0))
-            t_heal = time.monotonic()
-            for n in minority:
-                ok_doc = n.poll(
-                    "info", deadline=rejoin_deadline,
-                    ok=lambda d: d.get("info", {}).get("ledger", {})
-                    .get("num", 0) >= net)
-                if ok_doc is None:
-                    rejoin_ok = False
-            doc["partition"]["rejoin_wall_s"] = round(
-                time.monotonic() - t_heal, 1)
-            doc["partition"]["network_lcl_at_heal"] = net
-
-        # ---- sick-device leg: trip one node's accel breaker --------
-        sd = cell.get("sick_device")
-        if sd:
-            doc["faults"].append("sick_device")
-            sick = cluster.nodes[-1]
-            tripped = False
-            try:
-                sick.get("backendstatus", {"action": "trip"})
-                tripped = True
-            except (OSError, ValueError, ClusterError):
-                pass    # no supervised backend on this build: the leg
-                        # still asserts plain survival
-            lcl0 = cluster.lcl(node0)
-            try:
-                cluster.wait_slot(lcl0 + 2, 180.0)
-            except ClusterError:
-                survival_ok = False
-            time.sleep(float(sd.get("hold_s", 2.0)))
-            if tripped:
-                try:
-                    sick.get("backendstatus", {"action": "reset"})
-                except (OSError, ValueError, ClusterError):
-                    pass
-            doc["sick_device"] = {"node": sick.name,
-                                  "tripped": tripped}
-
-        # ---- verdict sweep -----------------------------------------
-        try:
-            cluster.wait_slot(2 + target_slots, 240.0)
-        except ClusterError:
-            survival_ok = False
-        live = [n for n in cluster.nodes if n.alive]
-        doc["crashes"] = n_nodes - len(live)
-        upto = cluster.min_lcl(live)
-        statuses = cluster.collect_clusterstatus(45.0,
-                                                 headers=f"2-{upto}")
-        safety_ok = cluster.headers_agree(upto, statuses,
-                                          expected=len(live))
-        flood = cluster.flood_report()
-        doc["duplicate_ratio"] = flood.get("duplicate_ratio", 0.0)
-        doc["flood"] = {k: flood[k] for k in
-                        ("unique", "duplicates", "duplicate_ratio")}
-        doc["flow"] = cluster.flow_report()
-        slo = cluster.collect_slo(20.0)
-        doc["slo"] = {"overall": slo.get("overall"),
-                      "nodes": slo.get("nodes", 0)}
-        doc["slots"] = upto
-        cluster.recordstop_all()
-        rcs = cluster.stop_all(graceful=True)
-        doc["graceful_shutdown_ok"] = all(
-            rc == 0 for rc in rcs.values())
-    doc["survival_ok"] = bool(survival_ok and doc["crashes"] == 0)
-    # a rejoin only COUNTS when the rejoined chain is byte-identical
-    # to the survivors' — agreeing late is still agreeing; diverging
-    # after a heal is the failure this matrix exists to catch
-    doc["safety_ok"] = bool(safety_ok)
-    doc["rejoin_ok"] = bool(rejoin_ok and safety_ok)
-    doc["slo_ok"] = slo.get("overall") != "BREACH"
-    doc["wall_s"] = round(_wall.perf_counter() - wall0, 1)
-    doc["ok"] = bool(
-        doc["survival_ok"] and doc["rejoin_ok"] and doc["safety_ok"]
-        and doc["slo_ok"] and doc["flow"]["within_budget"]
-        and doc["flow"]["scp_never_shed_first"]
-        and doc["graceful_shutdown_ok"])
-    return doc

@@ -1,6 +1,6 @@
 """In-repo hand-assembled contract against the REAL soroban-env ABI.
 
-This is the deliverable VERDICT r02 #2 asks for: a contract that uses
+A contract that uses
 the actual host interface SDK-built binaries use (single-letter import
 modules, positional short names, tagged i64 Vals — see env_abi.py for
 the recovered ground truth) rather than the bespoke long-name module,
@@ -216,7 +216,7 @@ def build_env_toolkit() -> bytes:
 
 def build_env_u256() -> bytes:
     """Third env-ABI contract: computes with the 256-bit host families
-    end-to-end (VERDICT r04 #5). `u256_demo` returns a Vec of
+    end-to-end. `u256_demo` returns a Vec of
     [((1,2,3,4)+(0,0,0,5)) << 7  as U256,  (-2^255) >> 3  as I256];
     `div_zero` must trap through the host's checked division."""
     b = ModuleBuilder()

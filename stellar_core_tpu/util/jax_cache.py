@@ -29,8 +29,7 @@ stalled it:
 
 `cache_dir_for_backend` asks `jax.default_backend()`, which starts the
 backend and on a chip machine TAKES THE CHIP: only a process that is
-meant to own the chip may call it. (`chip_smoke.py`, which must stay
-off JAX and stand alone, restates `CACHE_ROOT` to count entries.)
+meant to own the chip may call it.
 """
 
 from __future__ import annotations

@@ -26,11 +26,12 @@ process restart and on ``clearmetrics``. A scraper passes the opaque
 returns only newer samples — or the full buffer with ``reset: true``
 when the epoch changed (restart, metrics clear) or the asked-for
 cursor already fell off the ring. ``simulation/cluster.py`` polls this
-per node into a merged cluster-wide series for CLUSTER artifacts.
+per node into a merged cluster-wide series.
 
 Consumers: the `timeseries`/`slo` admin routes (main/command_handler),
-the SLO watchdog (ops/slo.py observes every appended sample), bench
-artifact summaries (bench.py), and the multi-process cluster harness.
+the SLO watchdog (ops/slo.py observes every appended sample), the
+scenario summaries (``scenario_reports``), and the multi-process
+cluster harness.
 """
 
 from __future__ import annotations
@@ -151,8 +152,8 @@ class TimeSeries:
 
     def clear(self) -> None:
         """`clearmetrics` hook: empty the ring AND rotate the epoch so
-        every outstanding scrape cursor resyncs from scratch — bench
-        legs sharing one process start each window from a clean slate,
+        every outstanding scrape cursor resyncs from scratch — measured
+        windows sharing one process each start from a clean slate,
         and a scraper that cached `epoch:cursor` gets `reset: true` on
         its next poll instead of a silent gap."""
         self._ring.clear()
@@ -363,9 +364,10 @@ class TelemetrySampler:
 # ------------------------------------------------------------ summaries --
 
 def summarize_samples(samples: List[dict]) -> dict:
-    """Bounded per-node series summary for bench artifacts: the
-    attributable facts (host-load envelope, worst tails, queue/backoff
-    evidence) without shipping the whole ring into a committed JSON."""
+    """Bounded per-node series summary (`timeseries?summary=1`, the
+    scenario runners, the cluster harness): the attributable facts
+    (host-load envelope, worst tails, queue/backoff evidence) without
+    shipping the whole ring."""
     if not samples:
         return {"samples": 0}
     loads = [s["host"]["load1"] for s in samples if s.get("host")]
@@ -406,12 +408,12 @@ def summarize_samples(samples: List[dict]) -> dict:
 
 
 def scenario_reports(apps) -> Tuple[dict, dict]:
-    """THE shared artifact-section builder for in-process scenarios
-    (bench legs, the byzantine runner): take a final sample of every
+    """THE shared report-section builder for in-process scenarios
+    (the chaos and byzantine runners): take a final sample of every
     app — manual-close scenarios barely advance the clock, so the
     series must reflect the end state — then return the merged
     ``(timeseries, slo)`` sections. One implementation, so a
-    summary-shape change propagates to every artifact producer."""
+    summary-shape change propagates to every scenario."""
     from ..ops.slo import aggregate_status
     summaries = []
     statuses = []

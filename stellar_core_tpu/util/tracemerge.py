@@ -25,7 +25,7 @@ This module merges them into ONE Chrome trace-event document:
   et al. 2010) drawn from hash-keyed hops instead of propagated
   request ids (no wire-format change).
 
-Consumers: `Simulation.merged_trace()`, `bench.py --trace`, and
+Consumers: `Simulation.merged_trace()`, `Cluster.merged_trace()` and
 `scripts/trace_report.py --slots/--flood`.
 """
 

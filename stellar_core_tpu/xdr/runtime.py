@@ -688,10 +688,9 @@ class Struct(metaclass=_StructMeta):
         """Structural deep copy — no serialize/parse roundtrip (the
         LedgerTxn aliasing-protection hot path). The native-codec check
         is inlined rather than routed through _nc(): clone is the
-        single hottest XDR call in ledger replay (16.5k calls per 64
-        ledgers, scripts/profile_catchup.py) and the extra function
-        call + refresh bookkeeping measured ~60% overhead on top of
-        the native clone itself."""
+        single hottest XDR call in ledger replay, and the extra
+        function call + refresh bookkeeping cost more than half of the
+        native clone itself."""
         cls = self.__class__
         ns = _NC[0]
         if ns is not None and ns is not False and ns.gen == _XDR_GEN[0] \

@@ -1,4 +1,4 @@
-"""BucketListDB read path (VERDICT r02 #7).
+"""BucketListDB read path.
 
 With EXPERIMENTAL_BUCKETLIST_DB on, LedgerTxnRoot answers non-offer
 entry loads from the bucket indexes (bloom-gated, newest level first)

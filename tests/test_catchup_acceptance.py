@@ -1,4 +1,4 @@
-"""History/catchup acceptance tier (VERDICT r02 #9).
+"""History/catchup acceptance tier.
 
 The CatchupSimulation matrix (reference:
 history/test/HistoryTestsUtils.h:52-95 — publish checkpoints, catch up

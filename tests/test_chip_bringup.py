@@ -51,7 +51,7 @@ def test_no_cache_path_under_tests_and_one_place_sets_it():
     for root, dirs, files in os.walk(REPO):
         dirs[:] = [d for d in dirs if not d.startswith(".")
                    and d not in ("__pycache__", "chiprun_out",
-                                 "chip_smoke_out", "_archive")]
+                                 "_archive")]
         for name in files:
             if not name.endswith(".py"):
                 continue

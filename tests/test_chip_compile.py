@@ -4,7 +4,7 @@ The compiler for a described (not attached) `v5e:2x2` is installed
 beside JAX, so what it refuses shows here at no chip time. Tier 1
 compiles the PIECES of the production kernel at real width (B = 1024,
 the bucket a 1,000-transaction set dispatches), each a standalone jit;
-the two whole kernels at the buckets `chip_smoke.py` dispatches and the
+the two whole kernels at 1,024 and 4,096 lanes and the
 four-device shard_map program cost minutes each and are marked `slow`.
 
 `fe8._use_rolled` asks `jax.default_backend()` at trace time and these
@@ -31,7 +31,7 @@ from stellar_core_tpu.ops import fe8, sha512
 from stellar_core_tpu.ops.verifier import _bucket_size, make_sharded_verify
 
 B_TXSET = _bucket_size(1000)         # 1,000-payment transaction set
-B_CHECKPOINT = _bucket_size(4000)    # chip_smoke's replayed checkpoint
+B_CHECKPOINT = _bucket_size(4000)    # a checkpoint of 60 small ledgers
 
 
 @pytest.fixture(scope="module")

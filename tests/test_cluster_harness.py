@@ -10,6 +10,7 @@ route + kill -9 churn with catchup over the wire) is marked `slow`.
 """
 
 import base64
+import json
 import os
 import time
 
@@ -19,6 +20,7 @@ from stellar_core_tpu.main.config import Config
 from stellar_core_tpu.simulation.cluster import (Cluster,
                                                  run_cluster_scenario)
 from stellar_core_tpu.simulation import topologies
+from stellar_core_tpu.util import chaos
 
 pytestmark = pytest.mark.cluster
 
@@ -257,6 +259,70 @@ def _root_self_payment(cluster, node) -> str:
         hint=root.public_key().hint(),
         signature=root.sign(probe.contents_hash()))]
     return base64.b64encode(env.to_bytes()).decode()
+
+
+# ---------------------------------------------- fault-schedule builders --
+
+def test_cluster_fault_schedule_builders(tmp_path):
+    """The schedule builders emit chaos specs that (a) land on BOTH
+    endpoints of each cut edge, (b) name the remote node id in the
+    match, and (c) round-trip through chaos.schedule_from_json — the
+    exact path the `chaos?mode=install` route takes."""
+    c = Cluster(3, 1, str(tmp_path))
+    minority = [c.nodes[0]]
+    edges = c.cut_edges(minority)
+    assert edges
+    for na, nb in edges:
+        assert (na is c.nodes[0]) != (nb is c.nodes[0])
+
+    per = c.partition_schedules(minority, 10.0)
+    # node0 carries one spec per cut edge, each naming the far end
+    specs0 = per[c.nodes[0].name]
+    assert len(specs0) == len(edges)
+    assert {s["match"]["peer"] for s in specs0} == \
+        {n.node_id.hex() for n in c.nodes[1:]
+         if any(n in e for e in edges)}
+    for name, specs in per.items():
+        for s in specs:
+            assert s["point"] == "overlay.link"
+            assert s["kind"] == "partition"
+            assert s["window_s"] == 10.0
+    # and the far endpoints carry the mirror spec back at node0
+    for na, nb in edges:
+        far = nb if na is c.nodes[0] else na
+        assert any(s["match"]["peer"] == c.nodes[0].node_id.hex()
+                   for s in per[far.name])
+
+    flap = c.flap_schedules(edges, 9.0, period_s=3.0, duty=0.4)
+    for specs in flap.values():
+        for s in specs:
+            assert s["kind"] == "flap"
+            assert s["period_s"] == 3.0 and s["duty"] == 0.4
+            assert s["window_s"] == 9.0
+
+    # shape_schedules: LinkLatency speaks bits/s, the chaos Shape
+    # wants bytes/s — the builder must divide by 8
+    lat = topologies.LinkLatency(seed=7, cross_org_ms=(30.0, 30.0),
+                                 bandwidth_bps=8_000_000.0)
+    shapes = c.shape_schedules(lat, window_s=12.0)
+    assert shapes
+    for specs in shapes.values():
+        for s in specs:
+            assert s["point"] == "overlay.send"
+            assert s["kind"] == "slow_link"
+            assert s["bps"] == pytest.approx(1_000_000.0)
+            assert s["window_s"] == 12.0
+            assert s["delay_ms"] > 0
+
+    # merge keeps every family in ONE per-node schedule (install
+    # REPLACES the engine) and the wire shape parses back into specs
+    merged = Cluster.merge_schedules(per, flap, shapes)
+    n0 = c.nodes[0].name
+    assert len(merged[n0]) == (len(per[n0]) + len(flap.get(n0, []))
+                               + len(shapes.get(n0, [])))
+    for specs in merged.values():
+        parsed = chaos.schedule_from_json(json.loads(json.dumps(specs)))
+        assert len(parsed) == len(specs)
 
 
 @pytest.mark.slow

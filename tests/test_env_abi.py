@@ -1,4 +1,4 @@
-"""Real soroban-env ABI tests (VERDICT r02 #2).
+"""Real soroban-env ABI tests.
 
 Three tiers:
  1. Val-encoding unit tests against the facts recovered from the

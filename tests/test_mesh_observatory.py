@@ -1,8 +1,7 @@
 """Mesh observatory tests (ISSUE 8): hash-keyed propagation tracking,
 SCP slot timelines, multi-node trace merge with flow stitching, the
 clusterstatus route, and the observability satellites (stamp-map
-bounds, clearmetrics clean-slate, trace_report cluster modes, flood
-report in bench artifacts)."""
+bounds, clearmetrics clean-slate, trace_report cluster modes)."""
 
 import json
 import os
@@ -283,39 +282,3 @@ def test_clusterstatus_on_bare_node():
         assert cs["close"]["p99_ms"] >= cs["close"]["median_ms"] >= 0
     finally:
         app.shutdown()
-
-
-# ----------------------------------------------------- bench flood report --
-
-def test_bench_flood_report_shape():
-    """Acceptance: the TPSM/TPSMT artifact field carries the flood
-    duplicate ratio and per-peer byte totals."""
-    import bench
-    from stellar_core_tpu.overlay import LoopbackPeerConnection
-    clock, apps = ovl.make_apps(2)
-    try:
-        conn = LoopbackPeerConnection(apps[0], apps[1])
-        conn.crank()
-        apps[0].propagation.on_recv(b"\x05" * 32)
-        apps[0].propagation.on_recv(b"\x05" * 32)
-        rep = bench._flood_report(apps)
-        assert set(rep) == {"unique", "duplicates", "duplicate_ratio",
-                            "bytes_sent_total", "bytes_received_total",
-                            "per_peer_bytes",
-                            # ISSUE 12 wire-path evidence sections
-                            "demand", "encode", "by_kind"}
-        # the artifact-schema contract: demand + encode always dicts
-        assert isinstance(rep["demand"], dict)
-        assert isinstance(rep["encode"], dict)
-        assert rep["encode"]["cache_hit"] + \
-            rep["encode"]["cache_miss"] > 0
-        assert rep["unique"] == 1 and rep["duplicates"] == 1
-        assert rep["duplicate_ratio"] == 1.0
-        assert rep["bytes_sent_total"] > 0
-        assert rep["per_peer_bytes"]
-        row = rep["per_peer_bytes"][0]
-        assert {"node", "peer", "bytes_sent", "bytes_received",
-                "messages_sent", "messages_received",
-                "duplicates"} <= set(row)
-    finally:
-        ovl.shutdown(apps)

@@ -7,7 +7,7 @@ transactions, node boot + ledger closes, restart) targets a real server
 when POSTGRES_TEST_URI is set; otherwise it runs against the in-repo
 wire-protocol stub (db/pg_stub.py), so the binding's network paths are
 exercised in every environment — note stub runs are protocol-level
-coverage, not real-postgres coverage (VERDICT r02 #8)."""
+coverage, not real-postgres coverage."""
 
 import os
 
@@ -139,7 +139,7 @@ def test_factory_selects_backend():
 # POSTGRES_TEST_URI targets a real server when one exists; otherwise the
 # hermetic wire-protocol stub (db/pg_stub.py) serves the same tests so
 # the libpq binding's connect/prepared/transaction paths always run
-# (VERDICT r02 #8 — previously these skipped loudly in this image).
+#.
 
 
 @pytest.fixture

@@ -1,4 +1,4 @@
-"""Protocol-next tree slice: the hot-archive bucket list (VERDICT r02 #6).
+"""Protocol-next tree slice: the hot-archive bucket list.
 
 Three guarantees:
   1. curr's wire language is untouched — pinned curr encodings stay

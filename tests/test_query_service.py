@@ -419,7 +419,7 @@ def test_http_routes_answer_reads():
 def test_concurrent_readers_against_closing_ledgers():
     """Four reader threads hammer the pool while the main thread
     closes ledgers — every response seq must name a closed ledger and
-    nothing deadlocks (the miniature of bench.py --read)."""
+    nothing deadlocks."""
     app, gen = _pay_app()
     try:
         seed_accounts_bulk(app, 100)

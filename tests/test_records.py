@@ -1,0 +1,242 @@
+"""What the repo's records point at exists.
+
+Two kinds of record are read by nothing on the CPU and break in
+silence: the benchmark's per-layer readers, each of which looks a zone,
+counter or span up by a string the program must print
+(`benchmark/layer_metrics/*.py`; a renamed zone reads `null` on the
+chip), and the documents, which cite files by path. Both are checked
+here from the sources alone, with `ast`: nothing under `benchmark/` is
+imported or edited, and no node runs.
+"""
+
+import ast
+import os
+import re
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+PACKAGE = os.path.join(ROOT, "stellar_core_tpu")
+READERS = os.path.join(ROOT, "benchmark", "layer_metrics")
+
+# a zone, timer, histogram, meter or counter name: dotted, lower-case head
+NAME = re.compile(r"^[a-z][A-Za-z0-9]*(\.[A-Za-z0-9]+)+$")
+# the lookups benchmark/harness/cell.py offers a reader
+LOOKUPS = {"cell.zones.get", "cell.counters.get",
+           "cell.spans.total", "cell.spans.named"}
+# MetricsRegistry and ZoneRegistry methods whose first argument names
+# what they open
+METRIC_NEW = {"new_counter", "new_meter", "new_timer", "new_histogram"}
+METRIC_PARTS = {"counter", "meter", "timer", "histogram"}
+ZONE_OPEN = {"zone", "zone_into"}
+ZONE_ADD_RECEIVERS = {"perf", "default_registry"}
+
+
+def _python_files(top):
+    for dirpath, dirnames, filenames in os.walk(top):
+        dirnames[:] = [d for d in dirnames
+                       if d not in ("__pycache__", "build")]
+        for name in sorted(filenames):
+            if name.endswith(".py"):
+                yield os.path.join(dirpath, name)
+
+
+def _parse(path):
+    with open(path, encoding="utf-8") as fh:
+        return ast.parse(fh.read(), path)
+
+
+def _dotted(node):
+    parts = []
+    while isinstance(node, ast.Attribute):
+        parts.append(node.attr)
+        node = node.value
+    if not isinstance(node, ast.Name):
+        return None
+    return ".".join([node.id] + parts[::-1])
+
+
+def _string(node):
+    if isinstance(node, ast.Constant) and isinstance(node.value, str):
+        return node.value
+    return None
+
+
+def _opened_by_call(node):
+    """The name a call opens in a registry of the program, or None."""
+    if not isinstance(node.func, ast.Attribute) or not node.args:
+        return None
+    method = node.func.attr
+    first = _string(node.args[0])
+    if first is None:
+        return None
+    if method in METRIC_NEW or method in ZONE_OPEN:
+        return first
+    if method in METRIC_PARTS:
+        parts = [_string(a) for a in node.args]
+        return ".".join(parts) if all(parts) else None
+    if method == "add":
+        receiver = _dotted(node.func.value) or ""
+        if receiver.split(".")[-1] in ZONE_ADD_RECEIVERS:
+            return first
+    return None
+
+
+@pytest.fixture(scope="module")
+def program_names():
+    """Every name `stellar_core_tpu/` opens as a zone, timer,
+    histogram, meter or counter."""
+    names = set()
+    for path in _python_files(PACKAGE):
+        tree = _parse(path)
+        for node in ast.walk(tree):
+            if isinstance(node, ast.Call):
+                name = _opened_by_call(node)
+                if name and NAME.match(name):
+                    names.add(name)
+            # util/jax_cache.py maps jax.monitoring events to zones
+            elif isinstance(node, ast.Assign) and any(
+                    isinstance(t, ast.Name) and t.id.endswith("_ZONES")
+                    for t in node.targets) \
+                    and isinstance(node.value, ast.Dict):
+                names.update(filter(None, map(_string, node.value.values)))
+    return names
+
+
+@pytest.fixture(scope="module")
+def bench_spans():
+    """Every `bench.*` span the benchmark's drivers and harness add."""
+    names = set()
+    for sub in ("generators", "harness"):
+        for path in _python_files(os.path.join(ROOT, "benchmark", sub)):
+            for node in ast.walk(_parse(path)):
+                if isinstance(node, ast.Call) and node.args \
+                        and (_dotted(node.func) or "").endswith("spans.add"):
+                    name = _string(node.args[0])
+                    if name:
+                        names.add(name)
+    return names
+
+
+def _class_members(path, cls_name):
+    """Attributes `self.x = ...` of `__init__` and the methods and
+    properties of one class."""
+    members = set()
+    for node in ast.walk(_parse(path)):
+        if isinstance(node, ast.ClassDef) and node.name == cls_name:
+            for item in ast.walk(node):
+                if isinstance(item, (ast.FunctionDef, ast.AsyncFunctionDef)):
+                    members.add(item.name)
+                elif isinstance(item, ast.Attribute) \
+                        and isinstance(item.ctx, ast.Store) \
+                        and isinstance(item.value, ast.Name) \
+                        and item.value.id == "self":
+                    members.add(item.attr)
+    return members
+
+
+@pytest.fixture(scope="module")
+def cell_members():
+    return _class_members(
+        os.path.join(ROOT, "benchmark", "harness", "cell.py"), "Cell") \
+        | {"spec"}                      # set by harness/main.py run_cell
+
+
+@pytest.fixture(scope="module")
+def trace_members():
+    return _class_members(
+        os.path.join(ROOT, "benchmark", "harness", "trace.py"),
+        "DeviceTrace")
+
+
+def _reader_files():
+    return sorted(f for f in os.listdir(READERS) if f.endswith(".py"))
+
+
+@pytest.mark.parametrize("reader", _reader_files())
+def test_layer_metric_reads_names_the_program_prints(
+        reader, program_names, bench_spans, cell_members, trace_members):
+    tree = _parse(os.path.join(READERS, reader))
+    docstrings = {id(n.value) for n in ast.walk(tree)
+                  if isinstance(n, ast.Expr) and _string(n.value) is not None}
+    names, program = set(), None
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Call) and _dotted(node.func) in LOOKUPS:
+            name = _string(node.args[0]) if node.args else None
+            assert name is not None, (
+                f"{reader}:{node.lineno}: {_dotted(node.func)} of something "
+                "other than a string literal cannot be checked")
+            names.add(name)
+        elif isinstance(node, ast.Constant) and id(node) not in docstrings \
+                and isinstance(node.value, str) and NAME.match(node.value):
+            # a name looked up some other way: `report[z]`, an event's name
+            names.add(node.value)
+        elif isinstance(node, ast.Assign) and any(
+                isinstance(t, ast.Name) and t.id == "PROGRAM"
+                for t in node.targets):
+            program = _string(node.value)
+    for name in sorted(names):
+        if name.startswith("bench."):
+            assert name in bench_spans, (
+                f"{reader} reads span {name!r}; benchmark/generators and "
+                f"benchmark/harness add {sorted(bench_spans)}")
+        else:
+            assert name in program_names, (
+                f"{reader} reads {name!r}, which stellar_core_tpu/ opens as "
+                "no zone, timer, histogram, meter or counter")
+    if program is not None:
+        # trace.module_runs matches the XLA module `jit_<PROGRAM>`, which
+        # JAX names after the function the verifier jits
+        from stellar_core_tpu.ops import ed25519_kernel
+        from stellar_core_tpu.ops.verifier import TpuBatchVerifier
+        TpuBatchVerifier._ensure_shared_jits()
+        jitted = TpuBatchVerifier._shared_jit_msg32
+        assert jitted.__wrapped__ is ed25519_kernel.verify_kernel_msg32
+        assert "jit_" + program == "jit_" + jitted.__name__ \
+            == "jit_verify_kernel_msg32"
+    # whatever else a reader takes from the cell or its device trace
+    # exists there
+    touched = 0
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Attribute) \
+                and isinstance(node.value, ast.Name):
+            if node.value.id == "cell":
+                assert node.attr in cell_members, (reader, node.attr)
+                touched += 1
+            elif node.value.id == "trace":
+                assert node.attr in trace_members, (reader, node.attr)
+                touched += 1
+    assert names or program or touched, f"{reader} reads nothing checkable"
+
+
+# ------------------------------------------------------------ documents --
+
+CITED = re.compile(r"`([^`\s]+)`")
+PATH = re.compile(r"^[\w./-]+(\.(py|md|json|cfg)|/)$")
+# where a document's short paths are rooted
+BASES = ("", "stellar_core_tpu", "docs", "tests", "scripts", "benchmark")
+
+
+def _documents():
+    docs = sorted("docs/" + f for f in os.listdir(os.path.join(ROOT, "docs"))
+                  if f.endswith(".md"))
+    return ["README.md"] + docs
+
+
+def _cited_paths(text):
+    for token in CITED.findall(text):
+        token = re.sub(r"(:\d+(-\d+)?(,\d+(-\d+)?)*)$", "", token)
+        if PATH.match(token) and not token.startswith(("/", "~", "-")):
+            yield token
+
+
+@pytest.mark.parametrize("doc", _documents())
+def test_document_cites_files_that_exist(doc):
+    with open(os.path.join(ROOT, doc), encoding="utf-8") as fh:
+        text = fh.read()
+    missing = sorted({
+        path for path in _cited_paths(text)
+        if not any(os.path.exists(os.path.join(ROOT, base, path))
+                   for base in BASES)})
+    assert not missing, f"{doc} cites files that are not in the tree: " \
+                        f"{missing}"

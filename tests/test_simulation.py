@@ -114,7 +114,7 @@ def test_loadgen_pretend_mixed_soroban_modes():
 
 
 def test_loadgen_sac_and_invoke_modes():
-    """SAC-transfer + contract-invoke loadgen (VERDICT r04 #7): the
+    """SAC-transfer + contract-invoke loadgen: the
     measured workloads exercise the wasm VM and the built-in SAC."""
     from stellar_core_tpu.main import Application, get_test_config
     from stellar_core_tpu.util.timer import ClockMode, VirtualClock

@@ -4,15 +4,7 @@ Covers the tentpole contracts: the sampler ring stays bounded with
 eviction accounting, the `since=` scrape cursor resyncs across
 restarts/clears instead of silently gapping, SLO verdicts are
 deterministic under VirtualClock (dwell timing reads sample time, not
-the wall), the verifier's per-dispatch accounting lands in metrics,
-and scripts/bench_trend.py both detects synthetic regressions and
-runs green — structurally tier-1 — over every committed artifact."""
-
-import json
-import os
-import sys
-
-import pytest
+the wall), and the verifier's per-dispatch accounting lands in metrics."""
 
 from stellar_core_tpu.main import Application, get_test_config
 from stellar_core_tpu.ops.slo import (BREACH, OK, WARN, SloRule,
@@ -22,13 +14,6 @@ from stellar_core_tpu.util.timer import ClockMode, VirtualClock
 from stellar_core_tpu.util.timeseries import (TimeSeries,
                                               aggregate_summaries,
                                               summarize_samples)
-
-sys.path.insert(0, os.path.join(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__))), "scripts"))
-
-import bench_trend                                         # noqa: E402
-
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _app(cfg=None):
@@ -340,113 +325,3 @@ def test_summarize_and_aggregate():
     agg = aggregate_summaries([s, summarize_samples([])])
     assert agg["samples"] == 2 and agg["nodes"] == 1
     assert summarize_samples([]) == {"samples": 0}
-
-
-# --------------------------------------------------------- bench trend --
-
-def test_trend_covers_every_committed_family_and_gate_green():
-    """THE tier-1 trajectory gate (ISSUE 10 acceptance): every
-    committed *_rNN.json family appears with its rounds, and the
-    regression gate holds on the committed record — the trajectory
-    can never silently go dark again."""
-    trend = bench_trend.build_trend(ROOT)
-    on_disk = set()
-    for f in os.listdir(ROOT):
-        m = bench_trend.FAMILY_RE.match(f)
-        if m and m.group(1) not in bench_trend.SKIP_FAMILIES:
-            on_disk.add(m.group(1))
-    assert on_disk, "no artifacts committed?"
-    assert set(trend["families"]) == on_disk
-    assert trend["artifacts_total"] >= len(on_disk)
-    for fam, doc in trend["families"].items():
-        assert doc["rounds"], fam
-    # artifact form satisfies the schema checker
-    art = bench_trend.trend_artifact(trend)
-    assert art["metric"] == "bench_trend"
-    assert trend["regressions"] == [], \
-        "committed artifacts regressed: %s" % trend["regressions"]
-
-
-def _write_rounds(tmp_path, fam, values, host_busy=None):
-    for i, v in enumerate(values, start=1):
-        doc = {"metric": "m", "unit": "u", "vs_baseline": 1.0}
-        if isinstance(v, str):
-            doc.update({"error": v})
-        else:
-            doc["value"] = v
-        if host_busy and i in host_busy:
-            doc["host_busy"] = True
-            doc["host_load"] = {"start": {"loadavg": [9.0, 1, 1],
-                                          "spin_ms": 99.0}}
-        (tmp_path / ("%s_r%02d.json" % (fam, i))).write_text(
-            json.dumps(doc))
-
-
-def test_trend_flags_synthetic_regression(tmp_path):
-    _write_rounds(tmp_path, "TPSM", [200.0, 210.0, 100.0])
-    trend = bench_trend.build_trend(str(tmp_path), tolerance=0.30)
-    doc = trend["families"]["TPSM"]
-    assert doc["regressed_vs_prev"] and doc["regressed_vs_best"]
-    assert doc["regressed"]
-    assert len(trend["regressions"]) == 1
-    r = trend["regressions"][0]
-    assert r["family"] == "TPSM" and r["round"] == 3
-    assert r["delta_vs_prev"] < -0.30
-    # table + strict exit code carry the flag
-    assert "REGRESSED" in bench_trend.render_table(trend)
-    assert bench_trend.main(["--root", str(tmp_path),
-                             "--strict"]) == 1
-
-
-def test_trend_tolerance_and_noise_handling(tmp_path):
-    # within tolerance: not a regression
-    _write_rounds(tmp_path, "TPS", [1000.0, 800.0])
-    # drop vs prev only (best IS prev) — still gated, both must hold
-    _write_rounds(tmp_path, "TPSS", [50.0, 300.0, 290.0])
-    # a host_busy latest round never gates
-    _write_rounds(tmp_path, "TPSMT", [200.0, 210.0, 90.0],
-                  host_busy={3})
-    # recorded-failure rounds are carried but skipped by the math
-    _write_rounds(tmp_path, "CATCHUP", [100.0, "boom", 95.0])
-    trend = bench_trend.build_trend(str(tmp_path), tolerance=0.30)
-    assert trend["regressions"] == []
-    assert trend["families"]["TPSMT"]["regressed_vs_prev"]
-    assert not trend["families"]["TPSMT"]["regressed"]
-    cat = trend["families"]["CATCHUP"]
-    assert cat["measured_rounds"] == 2
-    assert cat["rounds"]["2"]["error"] == "boom"
-    assert cat["latest_value"] == 95.0
-    # per-round dips recorded as data even when the gate stays green
-    _write_rounds(tmp_path, "VERIFY", [100.0, 20.0, 120.0])
-    trend = bench_trend.build_trend(str(tmp_path), tolerance=0.30)
-    assert trend["families"]["VERIFY"]["dips"][0]["round"] == 2
-    assert not trend["families"]["VERIFY"]["regressed"]
-
-
-def test_trend_degraded_device_round_not_gated(tmp_path):
-    """A latest round whose artifact carries the r19 device-probe
-    verdict (warm device verify slower than native C — the
-    accelerator is absent/sick) is annotated, never gated: the drop
-    belongs to the hardware, not the code."""
-    _write_rounds(tmp_path, "CATCHUP", [200.0, 210.0])
-    doc = {"metric": "m", "unit": "u", "vs_baseline": 1.0,
-           "value": 90.0,
-           "device_probe": {"bucket": 1024,
-                            "device_sigs_per_sec": 43.6,
-                            "native_sigs_per_sec": 495289.7,
-                            "degraded": True}}
-    (tmp_path / "CATCHUP_r03.json").write_text(json.dumps(doc))
-    trend = bench_trend.build_trend(str(tmp_path), tolerance=0.30)
-    cat = trend["families"]["CATCHUP"]
-    assert cat["regressed_vs_prev"] and cat["regressed_vs_best"]
-    assert not cat["regressed"]
-    assert trend["regressions"] == []
-    assert cat["rounds"]["3"]["device_degraded"] is True
-    assert "r03:90↓~" in bench_trend.render_table(trend)
-    assert bench_trend.main(["--root", str(tmp_path),
-                             "--strict"]) == 0
-
-
-def test_trend_empty_root_is_loud(tmp_path):
-    with pytest.raises(RuntimeError):
-        bench_trend.build_trend(str(tmp_path))

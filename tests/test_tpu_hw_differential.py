@@ -1,4 +1,4 @@
-"""Real-chip differential job wrapper (VERDICT round-1 weak #3).
+"""Real-chip differential job wrapper.
 
 The normal suite forces JAX to the CPU platform (conftest.py), so the
 hardware job runs in subprocesses with their own env.  Enabled with
@@ -35,7 +35,7 @@ def test_differential_suite_on_real_chip():
                     reason="needs the real TPU (set RUN_TPU_TESTS=1)")
 def test_differential_fast_on_real_chip():
     """Small-bucket chip tier: full strict-check corpus vs the oracle,
-    <2 min warm (VERDICT r04 #8) — `RUN_TPU_TESTS=1 pytest -k fast`."""
+    <2 min warm — `RUN_TPU_TESTS=1 pytest -k fast`."""
     r = subprocess.run(
         [sys.executable, SCRIPT, "fast"],
         capture_output=True, text=True, timeout=600)

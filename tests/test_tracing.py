@@ -480,20 +480,3 @@ def test_metrics_route_prometheus_format():
         assert "ledger_transaction_e2e_seconds" in out["_raw_body"]
     finally:
         app.shutdown()
-
-
-def test_bench_e2e_report_shape():
-    import bench
-    app = Application.create(VirtualClock(ClockMode.VIRTUAL_TIME),
-                             get_test_config())
-    app.start()
-    try:
-        assert bench._tx_e2e_report(app) == {}     # no samples yet
-        app.herder.tx_e2e_timer.update(0.100)
-        app.herder.tx_e2e_timer.update(0.300)
-        rep = bench._tx_e2e_report(app)
-        assert rep["count"] == 2
-        assert rep["median_ms"] in (100.0, 300.0)
-        assert rep["p99_ms"] >= rep["median_ms"]
-    finally:
-        app.shutdown()
