@@ -27,6 +27,7 @@ from __future__ import annotations
 import os
 import sqlite3
 import threading
+from contextlib import contextmanager
 from typing import Any, Iterable, Optional
 
 from ..util import chaos
@@ -198,6 +199,16 @@ class SchemaMixin:
         for fn in barriers:
             fn()
 
+    def tail_transaction(self):
+        """The scope of the close-completion tail's one transaction. A
+        backend with one connection runs it as any other."""
+        return self.transaction()
+
+    def insert_rows(self, sql: str, rows: Iterable[Iterable[Any]]) -> None:
+        """`executemany` of a one-row `INSERT ... VALUES (?,...)`, in
+        whatever shape the backend runs a bulk insert best."""
+        self.executemany(sql, rows)
+
     def query_one(self, sql: str, params: Iterable[Any] = ()):
         return self.execute(sql, params).fetchone()
 
@@ -266,13 +277,25 @@ class SchemaMixin:
         return _ENTRY_TABLES
 
 
-class Database(SchemaMixin):
-    """One sqlite connection per Database instance.
+# what either connection of a file-backed database waits for the file's
+# other writer before it gives up (ms). The tail should never find one
+# (Database.tail_transaction); a writer of the first connection may
+# find the tail, where it used to wait for the session lock
+BUSY_TIMEOUT_MS = 30000
 
-    check_same_thread=False with an explicit lock: the node is
-    single-main-threaded by design (docs/architecture.md:24-36), but
-    background work (bucket apply, tests) may touch the DB under the
-    session lock.
+# rows of one multi-row VALUES statement (Database.insert_rows)
+PACKED_INSERT_ROWS = 1000
+
+
+class Database(SchemaMixin):
+    """The sqlite backend: one connection for everything the node does,
+    and for a file-backed database a second one that only the ledger
+    close's completion tail writes through (`tail_transaction`).
+
+    check_same_thread=False with an explicit lock per connection: the
+    node is single-main-threaded by design (docs/architecture.md:24-36),
+    but background work (bucket apply, tests) may touch the DB under
+    the session lock.
     """
 
     _missing_table_errors = (sqlite3.OperationalError,)
@@ -282,34 +305,82 @@ class Database(SchemaMixin):
         self.path = path
         if path != ":memory:":
             os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-        self._conn = sqlite3.connect(
-            path, check_same_thread=False, cached_statements=256)
-        self._conn.isolation_level = None   # explicit transaction control
+        self._conn = self._connect(path, timeout=BUSY_TIMEOUT_MS / 1000)
+        self._variable_limit = self._conn.getlimit(
+            sqlite3.SQLITE_LIMIT_VARIABLE_NUMBER)
         self._lock = threading.RLock()
         self._tx_depth = 0
         self._metrics = metrics
         self._query_meter = (metrics.meter("database", "query", "exec")
                             if metrics else None)
-        self.execute("PRAGMA journal_mode=WAL")
-        self.execute("PRAGMA synchronous=NORMAL")
+        # the tail's connection: WAL lets the closing thread read
+        # through `_conn` beside the tail's write. A `:memory:` database
+        # is private to its connection, so it keeps the one
+        self._tail_conn = None
+        self._tail_lock = threading.Lock()
+        self._tail_owner = None         # the thread inside the tail's scope
+        self._tail_statements = 0       # of the open scope, for the meter
+        self._tail_busy = (metrics.counter("database", "tail", "busy")
+                           if metrics else None)
+        if path != ":memory:":
+            # it never waits in a statement: `_begin_tail` takes the
+            # write lock at BEGIN and counts a wait there
+            self._tail_conn = self._connect(path, timeout=0)
+
+    @staticmethod
+    def _connect(path: str, **kw) -> sqlite3.Connection:
+        conn = sqlite3.connect(
+            path, check_same_thread=False, cached_statements=256, **kw)
+        conn.isolation_level = None     # explicit transaction control
+        conn.execute("PRAGMA journal_mode=WAL")
+        conn.execute("PRAGMA synchronous=NORMAL")
+        return conn
 
     # ---------------------------------------------------------------- core --
-    def execute(self, sql: str, params: Iterable[Any] = ()) -> sqlite3.Cursor:
+    def execute(self, sql: str, params: Iterable[Any] = (),
+                rows: int = 1) -> sqlite3.Cursor:
+        """`rows`: what the statement counts for in `database.query.exec`."""
+        if self._tail_owner is threading.current_thread():
+            self._tail_statements += rows
+            return self._tail_conn.execute(sql, tuple(params))
         self._completion_barrier(sql)
         with self._lock:
             if self._query_meter:
-                self._query_meter.mark()
+                self._query_meter.mark(rows)
             return self._conn.execute(sql, tuple(params))
 
     def executemany(self, sql: str, rows: Iterable[Iterable[Any]]) -> None:
-        self._completion_barrier(sql)
         rows = list(rows)
+        if self._tail_owner is threading.current_thread():
+            self._tail_statements += len(rows)
+            self._tail_conn.executemany(sql, rows)
+            return
+        self._completion_barrier(sql)
         with self._lock:
             if self._query_meter:
                 # meter per row so batched writes stay visible in the
                 # database.query metrics an operator watches
                 self._query_meter.mark(len(rows))
             self._conn.executemany(sql, rows)
+
+    def insert_rows(self, sql: str, rows: Iterable[Iterable[Any]]) -> None:
+        """The rows of `executemany(sql, rows)` as multi-row VALUES
+        statements, `PACKED_INSERT_ROWS` at a time: the same rows in
+        the same order, in one `sqlite3_step` a statement instead of
+        one a row. Every step hands the GIL away and has to take it
+        back, which beside a thread that is busy in Python (the next
+        close's apply, when the caller is the completion tail) costs up
+        to the interpreter's switch interval each time
+        (docs/CLOSE_PIPELINE.md, "Two connections"). Metered a row, as
+        `executemany` is."""
+        head, _, one = sql.rpartition("VALUES")
+        per = max(1, min(PACKED_INSERT_ROWS,
+                         self._variable_limit // one.count("?")))
+        rows = list(rows)
+        for i in range(0, len(rows), per):
+            part = rows[i:i + per]
+            self.execute(head + "VALUES" + ",".join([one] * len(part)),
+                         [v for row in part for v in row], rows=len(part))
 
     # -------------------------------------------------------- transactions --
     class _TxScope:
@@ -349,16 +420,7 @@ class Database(SchemaMixin):
                 db._tx_depth -= 1
                 if exc_type is None:
                     if db._tx_depth == 0:
-                        if chaos.ENABLED:
-                            # a simulated commit failure must leave the
-                            # connection clean: roll back, then raise —
-                            # exactly what a real failed COMMIT leaves
-                            try:
-                                chaos.point("db.commit", db=db.path)
-                            except BaseException:
-                                db._conn.execute("ROLLBACK")
-                                raise
-                        db._conn.execute("COMMIT")
+                        db._commit(db._conn)
                     else:
                         db._conn.execute(f"RELEASE sp{db._tx_depth}")
                 else:
@@ -380,7 +442,74 @@ class Database(SchemaMixin):
     def transaction(self) -> "_TxScope":
         return Database._TxScope(self)
 
+    def _commit(self, conn: sqlite3.Connection) -> None:
+        if chaos.ENABLED:
+            # a simulated commit failure must leave the connection
+            # clean: roll back, then raise — exactly what a real failed
+            # COMMIT leaves
+            try:
+                chaos.point("db.commit", db=self.path)
+            except BaseException:
+                conn.execute("ROLLBACK")
+                raise
+        conn.execute("COMMIT")
+
+    def _begin_tail(self) -> None:
+        conn = self._tail_conn
+        try:
+            conn.execute("BEGIN IMMEDIATE")
+            return
+        except sqlite3.OperationalError as exc:
+            if exc.sqlite_errorcode & 0xff != sqlite3.SQLITE_BUSY:
+                raise
+        # another writer holds the file. A close never does: its BEGIN
+        # follows its join of this tail, and the next tail is submitted
+        # after its COMMIT. Wait for whoever it is, and say so
+        if self._tail_busy is not None:
+            self._tail_busy.inc()
+        log.warning("the close tail's transaction found %s locked by "
+                    "another writer", self.path)
+        conn.execute(f"PRAGMA busy_timeout={BUSY_TIMEOUT_MS}")
+        try:
+            conn.execute("BEGIN IMMEDIATE")
+        finally:
+            conn.execute("PRAGMA busy_timeout=0")
+
+    @contextmanager
+    def _tail_scope(self):
+        """One transaction on the tail's connection: the statements of
+        the thread inside it go there, with no barrier (what the tail
+        reads of its own is in its own transaction) and without the
+        first connection's lock. Not nested."""
+        with self._tail_lock:
+            self._begin_tail()
+            self._tail_statements = 0
+            self._tail_owner = threading.current_thread()
+            try:
+                yield
+            except BaseException:
+                self._tail_owner = None
+                self._tail_conn.execute("ROLLBACK")
+                raise
+            self._tail_owner = None
+            self._commit(self._tail_conn)
+        if self._query_meter:
+            with self._lock:        # the meter is the first connection's
+                self._query_meter.mark(self._tail_statements)
+
+    def tail_transaction(self):
+        """The completion tail's one transaction (a ledger's history
+        rows and its LAST_CLOSE_COMPLETED marker): through the second
+        connection where there is one, so that the closing thread's
+        reads do not queue behind it."""
+        if self._tail_conn is None:
+            return self.transaction()
+        return self._tail_scope()
+
     # ---------------------------------------------------------------- misc --
     def close(self) -> None:
         with self._lock:
             self._conn.close()
+        if self._tail_conn is not None:
+            with self._tail_lock:
+                self._tail_conn.close()

@@ -124,8 +124,9 @@ class CloseCompletionQueue:
     def last_completed(self) -> int:
         return self._last_completed
 
-    def join(self, reraise: bool = True) -> None:
-        """Block until every submitted completion has run. Re-raises the
+    def join(self, reraise: bool = True) -> bool:
+        """Block until every submitted completion has run, and say
+        whether there was anything to wait for. Re-raises the
         first completion failure (a node must not keep closing ledgers
         whose history it silently failed to persist). The error is
         STICKY: every join re-raises it, so a reader thread (admin
@@ -133,8 +134,9 @@ class CloseCompletionQueue:
         from the consensus path — the next close's barrier still halts
         the node."""
         if threading.current_thread() is self._worker:
-            return              # a job reading its own artifacts: no-op
+            return False        # a job reading its own artifacts: no-op
         with self._cond:
+            waited = self._pending > 0
             while self._pending:
                 self._cond.wait()
             if reraise and self._error is not None:
@@ -142,6 +144,7 @@ class CloseCompletionQueue:
                 raise RuntimeError(
                     f"deferred ledger-close completion for ledger {seq} "
                     "failed") from exc
+        return waited
 
     def reader_barrier(self) -> None:
         """Database pre-statement hook: joins only when work is in

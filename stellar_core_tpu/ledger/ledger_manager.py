@@ -13,7 +13,7 @@ from __future__ import annotations
 import struct
 import threading
 import time
-from contextlib import nullcontext, suppress
+from contextlib import ExitStack, nullcontext, suppress
 from functools import partial
 from typing import Callable, List, Optional
 
@@ -199,6 +199,12 @@ class LedgerManager:
                 "ledger", "apply", "stage_width")
             self.apply_conflict_hist = metrics.histogram(
                 "ledger", "apply", "conflict_ratio")
+            # once a close: did the previous ledger's tail end beside
+            # this close's apply, or did the barrier wait for it
+            self._tail_hidden = metrics.counter(
+                "ledger", "close", "tail", "hidden")
+            self._tail_waited = metrics.counter(
+                "ledger", "close", "tail", "waited")
         else:
             self.tx_apply_timer = None
             self.ledger_close_timer = None
@@ -206,6 +212,7 @@ class LedgerManager:
             self.apply_stages_hist = None
             self.apply_stage_width_hist = None
             self.apply_conflict_hist = None
+            self._tail_hidden = self._tail_waited = None
 
     # ------------------------------------------------------------ LCL state --
     def get_last_closed_ledger_header(self) -> LedgerHeader:
@@ -446,13 +453,6 @@ class LedgerManager:
                       phases: Optional[dict] = None) -> None:
         if phases is None:
             phases = {}
-        # per-ledger barrier: ledger N's completion must be durable
-        # before ledger N+1's close consumes or replaces its artifacts
-        with self.perf.zone_into("ledger.close.completeWait", phases):
-            self._completion.join()
-        # the close-duration clock starts AFTER the barrier: the
-        # previous ledger's completion tail is its own phase zone and
-        # must not inflate ledger.ledger.close
         t0 = time.monotonic()
         lcl = self.root.get_header()
         if lcd.ledger_seq != lcl.ledgerSeq + 1:
@@ -488,10 +488,11 @@ class LedgerManager:
         # the next SCP round) actually depends on, committed atomically
         # (entries + hot-archive state + header + local HAS in ONE SQL
         # transaction — reference: the single commit spanning
-        # LedgerManagerImpl.cpp:715-936)
-        dbtx = self.db.transaction() if self.db is not None \
-            else nullcontext()
-        with dbtx:
+        # LedgerManagerImpl.cpp:715-936). The phases before `seal` work
+        # on the LedgerTxn in memory and read through the root; the SQL
+        # transaction is entered where the close's first write is, after
+        # the barrier below, and ends where it always did
+        with ExitStack() as dbtx:
             with LedgerTxn(self.root) as ltx:
                 header = ltx.load_header()
                 header.ledgerSeq = lcd.ledger_seq
@@ -537,6 +538,20 @@ class LedgerManager:
                 if chaos.ENABLED:
                     self._chaos_crash_point(
                         "ledger.close.crash.evictionScan", lcd.ledger_seq)
+                # per-ledger barrier: ledger N's completion tail has run
+                # beside the phases above; it must be durable before
+                # ledger N+1 commits or replaces what it reads (a
+                # checkpoint's publish reads the bucket levels `seal`
+                # is about to change). A failed tail halts the node
+                # here, with nothing of N+1 written
+                with self.perf.zone_into("ledger.close.completeWait",
+                                         phases):
+                    waited = self._completion.join()
+                if self._tail_waited is not None:
+                    (self._tail_waited if waited
+                     else self._tail_hidden).inc()
+                if self.db is not None:
+                    dbtx.enter_context(self.db.transaction())
                 # Seal: fold the delta into the bucket list, then stamp
                 # the bucketListHash into the header before hashing it.
                 # Children: `seal.fsync` is the bucket-file persistence
@@ -635,7 +650,11 @@ class LedgerManager:
         if self.tx_count_meter is not None:
             self.tx_count_meter.mark(len(txs))
         if self.ledger_close_timer is not None:
-            self.ledger_close_timer.update(time.monotonic() - t0)
+            # the previous ledger's completion tail is its own phase
+            # zone and must not inflate ledger.ledger.close
+            self.ledger_close_timer.update(
+                time.monotonic() - t0
+                - phases["ledger.close.completeWait"])
         if self._metrics is not None:
             # once a close, never per signature: what the host verified
             # by itself since the last close (crypto.verify.native,
@@ -655,9 +674,9 @@ class LedgerManager:
         """The deferred tail of one close (reference: the history/meta
         writes of LedgerManagerImpl.cpp:914-943 + publishQueuedHistory
         :939, here off the consensus critical path). Batched: header-
-        adjacent history rows land in ONE SQL transaction via
-        executemany, with the completion marker the restart gap-check
-        reads."""
+        adjacent history rows land in ONE SQL transaction, a multi-row
+        statement a table, with the completion marker the restart
+        gap-check reads."""
         if threads.CHECK:
             # runs on the completion worker when deferred, inline on
             # the crank thread when defer_completion is off
@@ -709,8 +728,8 @@ class LedgerManager:
                         rows = self._tx_history_rows(
                             seq, applicable, txs, txset_bytes, tx_bytes)
                 with self.perf.zone("ledger.close.txHistory.sql"):
-                    dbtx = self.db.transaction() if self.db is not None \
-                        else nullcontext()
+                    dbtx = self.db.tail_transaction() \
+                        if self.db is not None else nullcontext()
                     with dbtx:
                         if stores:
                             self._store_tx_history(seq, *rows)
@@ -1171,11 +1190,11 @@ class LedgerManager:
         self.db.execute(
             "INSERT OR REPLACE INTO txsethistory "
             "(ledgerseq, isgeneralized, txset) VALUES (?,?,?)", set_row)
-        self.db.executemany(
+        self.db.insert_rows(
             "INSERT OR REPLACE INTO txhistory "
             "(txid, ledgerseq, txindex, txbody, txresult, txmeta) "
             "VALUES (?,?,?,?,?,?)", tx_rows)
-        self.db.executemany(
+        self.db.insert_rows(
             "INSERT OR REPLACE INTO txfeehistory "
             "(txid, ledgerseq, txindex, txchanges) VALUES (?,?,?,?)",
             fee_rows)

@@ -565,6 +565,80 @@ def test_crash_point_matrix(tmp_path, matrix_control, point):
     assert state[3] == 0                # publish queue consistent
 
 
+@pytest.mark.parametrize("tail", ["discarded", "finished"])
+@pytest.mark.parametrize("point", ["ledger.close.crash.fees",
+                                   "ledger.close.crash.applyTx"])
+def test_crash_in_next_close_beside_previous_tail(tmp_path, matrix_control,
+                                                  point, tail):
+    """What the barrier at the close's first statement made impossible:
+    ledger 5 dies in `fees` / `applyTx` while ledger 4's tail has not
+    run (the crash loses it: LCL 4, marker 3, healed on restart) or has
+    finished (marker 4). Either way nothing of ledger 5 is on disk and
+    the resumed chain is the crash-free one."""
+    import threading
+    from stellar_core_tpu.ledger.ledger_manager import LedgerCloseData
+    from stellar_core_tpu.main.persistent_state import StateEntry
+
+    def close_no_join(app, seq):
+        lm = app.ledger_manager
+        lcl = lm.get_last_closed_ledger_header()
+        tx_set, _, _ = make_tx_set_from_transactions(
+            [_scheduled_tx(app, seq)], lcl, app.config.network_id())
+        lm.close_ledger(LedgerCloseData(seq, tx_set, StellarValue(
+            txSetHash=tx_set.get_contents_hash(), closeTime=1000 + seq)))
+
+    app = Application.create(VirtualClock(ClockMode.VIRTUAL_TIME),
+                             _matrix_cfg(tmp_path))
+    app.start()
+    lm = app.ledger_manager
+    _close_seq(app, 2)
+    _close_seq(app, 3)
+    hold = threading.Event()
+    if tail == "discarded":
+        # from ledger 4's COMMIT on the worker is busy, so the tail
+        # queues behind it and has not started when ledger 5 dies
+        lm.closed_hooks.append(
+            lambda header, _hash: lm._completion.submit(
+                header.ledgerSeq, hold.wait))
+    close_no_join(app, _CRASH_AT)
+    if tail == "finished":
+        lm.join_completion()
+    chaos.install(ChaosEngine(8, [FaultSpec(point, "crash")]))
+    try:
+        with pytest.raises(SimulatedCrash):
+            close_no_join(app, _CRASH_AT + 1)
+    finally:
+        chaos.uninstall()
+    lm.discard_pending_completion()     # as Simulation.crash_node does
+    hold.set()
+    lm.join_completion()
+    # what the dead process left in its files
+    db = app.database
+    assert db.query_one(
+        "SELECT MAX(ledgerseq) FROM ledgerheaders")[0] == _CRASH_AT
+    assert int(app.persistent_state.get(StateEntry.LAST_CLOSE_COMPLETED)) \
+        == (_CRASH_AT - 1 if tail == "discarded" else _CRASH_AT)
+
+    app2 = Application.create(VirtualClock(ClockMode.VIRTUAL_TIME),
+                              _matrix_cfg(tmp_path))
+    app2.start()
+    try:
+        assert app2.ledger_manager.get_last_closed_ledger_num() == _CRASH_AT
+        assert int(app2.persistent_state.get(
+            StateEntry.LAST_CLOSE_COMPLETED)) == _CRASH_AT
+        rows = app2.database.query_one(
+            "SELECT COUNT(*) FROM txhistory WHERE ledgerseq=?",
+            (_CRASH_AT,))[0]
+        assert rows == (0 if tail == "discarded" else 1)
+        for s in range(_CRASH_AT + 1, _TARGET + 1):
+            _close_seq(app2, s)
+        state = _chain_state(app2, _TARGET)
+        assert state[0] == matrix_control[0], "header chain diverged"
+        assert state[1:] == (matrix_control[1], _TARGET, 0)
+    finally:
+        app2.shutdown()
+
+
 @pytest.mark.parametrize("crash_point", ["ledger.close.crash.commit",
                                          "ledger.close.crash.queued"])
 def test_publish_queue_survives_crash_after_queueing(tmp_path,

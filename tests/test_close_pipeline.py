@@ -14,6 +14,8 @@ index sidecar, and the DNS cache TTL.
 
 import json
 import os
+import sqlite3
+import threading
 import time
 
 import pytest
@@ -152,33 +154,270 @@ def test_next_close_joins_previous_completion():
     assert order.index(("complete", 2)) < order.index(("complete", 3))
 
 
-def test_deferred_path_byte_identical_to_synchronous():
-    """Golden regression: header hashes AND emitted meta are
-    byte-identical between the deferred and inline completion
-    schedules."""
-    def run(defer):
-        metas = []
-        db = Database(":memory:")
-        db.initialize()
-        lm = lc.make_manager(db=db)
-        lm.defer_completion = defer
-        lm.meta_stream = metas.append
-        for _ in range(3):
-            _close_payment_ledger(lm)
-        lm.join_completion()
-        rows = db.query_all(
-            "SELECT ledgerseq, txindex, txbody, txresult, txmeta "
-            "FROM txhistory ORDER BY ledgerseq, txindex")
-        return (lm.get_last_closed_ledger_hash(),
-                [m.to_bytes() for m in metas],
-                [tuple(bytes(c) if isinstance(c, (bytes, memoryview))
-                       else c for c in r) for r in rows])
+_HISTORY_TABLES = {
+    "txhistory": "ledgerseq, txindex, txid, txbody, txresult, txmeta",
+    "txfeehistory": "ledgerseq, txindex, txid, txchanges",
+    "txsethistory": "ledgerseq, isgeneralized, txset",
+}
 
-    deferred = run(True)
-    inline = run(False)
+
+def _history_rows(db):
+    """Every row of the three tables the completion tail writes."""
+    return {table: [tuple(bytes(c) if isinstance(c, (bytes, memoryview))
+                          else c for c in r)
+                    for r in db.query_all(
+                        f"SELECT {cols} FROM {table} ORDER BY 1, 2")]
+            for table, cols in _HISTORY_TABLES.items()}
+
+
+def _run_three_closes(db, defer=True):
+    """(LCL hash, streamed meta bytes, history rows) of three closes."""
+    metas = []
+    db.initialize()
+    lm = lc.make_manager(db=db)
+    lm.defer_completion = defer
+    lm.meta_stream = metas.append
+    for _ in range(3):
+        _close_payment_ledger(lm)
+    lm.join_completion()
+    rows = _history_rows(db)
+    assert [len(r) for r in rows.values()] == [3, 3, 3]
+    return (lm.get_last_closed_ledger_hash(),
+            [m.to_bytes() for m in metas], rows)
+
+
+def test_deferred_path_byte_identical_to_synchronous():
+    """Golden regression: header hashes, emitted meta AND the history
+    tables' rows are byte-identical between the deferred and inline
+    completion schedules."""
+    deferred = _run_three_closes(Database(":memory:"), True)
+    inline = _run_three_closes(Database(":memory:"), False)
     assert deferred[0] == inline[0]
     assert deferred[1] == inline[1]
     assert deferred[2] == inline[2]
+
+
+# ------------------------------- the next close beside the previous tail --
+
+class _GatedTail:
+    """Holds ledger `seq`'s `_store_tx_history` (inside the tail's SQL
+    transaction) until `open()`, and keeps the order of what happened."""
+
+    def __init__(self, lm, seq):
+        self.order = []
+        self.held = threading.Event()       # the tail is inside its BEGIN
+        self.applied = threading.Event()    # close seq+1 is past applyTx
+        self._gate = threading.Event()
+        store, apply, header = (lm._store_tx_history,
+                                lm._apply_transactions, lm._store_header)
+
+        def gated_store(s, *a):
+            if s == seq:
+                self.held.set()
+                assert self._gate.wait(10)
+            self.order.append(("tail-sql", s))
+            store(s, *a)
+
+        def noted_apply(ltx, *a):
+            out = apply(ltx, *a)
+            self.order.append(("applied", ltx.get_header().ledgerSeq))
+            if ltx.get_header().ledgerSeq == seq + 1:
+                self.applied.set()
+            return out
+
+        def noted_header(h):
+            self.order.append(("seal-sql", h.ledgerSeq))
+            header(h)
+
+        lm._store_tx_history = gated_store
+        lm._apply_transactions = noted_apply
+        lm._store_header = noted_header
+
+    def open(self):
+        self.order.append(("gate-open",))
+        self._gate.set()
+
+    def open_after_apply(self, delay=0.2):
+        """Open the gate `delay` seconds after close seq+1 has left
+        `applyTx`: room for it to run into `seal`, and a wait at the
+        barrier that a loaded machine cannot shrink to nothing."""
+        def run():
+            assert self.applied.wait(10)
+            time.sleep(delay)
+            self.open()
+        opener = threading.Thread(target=run)
+        opener.start()
+        return opener
+
+
+def _file_db(tmp_path):
+    db = Database(str(tmp_path / "node.db"))
+    db.initialize()
+    return db
+
+
+def test_next_close_applies_beside_previous_tail(tmp_path):
+    """Ledger 3 runs prepare, fees and applyTx while ledger 2's tail is
+    in flight, and does not enter `seal` until that tail is durable."""
+    lm = lc.make_manager(db=_file_db(tmp_path))
+    gate = _GatedTail(lm, 2)
+    # a bare LedgerManager's zones are the process-wide registry's
+    waits0 = lm.perf.report().get("ledger.close.completeWait",
+                                  {"count": 0, "total_ms": 0.0})
+
+    opener = gate.open_after_apply()
+    _close_payment_ledger(lm)
+    assert gate.held.wait(10)
+    _close_payment_ledger(lm)
+    opener.join()
+    lm.join_completion()
+    at = gate.order.index
+    assert at(("applied", 3)) < at(("gate-open",)) < at(("tail-sql", 2)) \
+        < at(("seal-sql", 3)) < at(("tail-sql", 3))
+    waits = lm.perf.report()["ledger.close.completeWait"]
+    assert waits["count"] - waits0["count"] == 2
+    assert waits["total_ms"] - waits0["total_ms"] >= 100
+
+
+def test_closing_thread_reads_beside_open_tail_transaction(tmp_path):
+    """A file-backed database gives the tail a connection of its own:
+    the closing thread's prefetch and entry loads return while the
+    tail's transaction is open, and see no row of it before COMMIT."""
+    from stellar_core_tpu.xdr.ledger_entries import LedgerKey
+    db = _file_db(tmp_path)
+    assert db._tail_conn is not None
+    lm = lc.make_manager(db=db)
+    gate = _GatedTail(lm, 2)
+    _close_payment_ledger(lm)
+    assert gate.held.wait(10)
+    dest = SecretKey.pseudo_random_for_testing(1)
+    key = LedgerKey.account(lc.xpk(dest))
+    lm.root._cache.clear()
+    t0 = time.monotonic()
+    assert lm.root.prefetch([key]) == 1         # through SQL: cache is cold
+    assert lm.root._lookup(key.to_bytes()) is not None
+    assert db.query_one("SELECT COUNT(*) FROM ledgerheaders")[0] == 2
+    assert time.monotonic() - t0 < 5
+    assert lm._completion.pending() == 1        # ... and the tail still open
+    assert db._conn.execute(
+        "SELECT COUNT(*) FROM txsethistory").fetchone()[0] == 0
+    gate.open()
+    lm.join_completion()
+    assert db.query_one("SELECT COUNT(*) FROM txsethistory")[0] == 1
+
+
+def test_failed_tail_halts_next_close_before_it_commits(tmp_path):
+    """A tail that raises stops the next close at the moved barrier:
+    the close has applied in memory, and nothing of it is in the
+    database."""
+    db = _file_db(tmp_path)
+    lm = lc.make_manager(db=db)
+    store = lm._store_tx_history
+
+    def failing_store(seq, *a):
+        if seq == 2:
+            raise OSError("disk gone")
+        store(seq, *a)
+
+    lm._store_tx_history = failing_store
+    _close_payment_ledger(lm)
+    applied = []
+    apply = lm._apply_transactions
+    lm._apply_transactions = lambda *a: applied.append(1) or apply(*a)
+    accounts = db.query_one("SELECT COUNT(*) FROM accounts")[0]
+    with pytest.raises(RuntimeError, match="ledger 2"):
+        _close_payment_ledger(lm)
+    assert applied == [1]
+    assert lm.get_last_closed_ledger_num() == 2
+    assert db.query_one("SELECT MAX(ledgerseq) FROM ledgerheaders")[0] == 2
+    assert db.query_one("SELECT COUNT(*) FROM accounts")[0] == accounts
+    # the failed tail rolled back whole, and the error stays
+    assert db._conn.execute(
+        "SELECT COUNT(*) FROM txhistory").fetchone()[0] == 0
+    with pytest.raises(RuntimeError, match="ledger 2"):
+        lm.join_completion()
+
+
+def test_memory_database_keeps_one_connection_and_the_same_results(
+        tmp_path):
+    """`:memory:` has no second connection to open; what the closes
+    leave is the same either way."""
+    memory = Database(":memory:")
+    assert memory._tail_conn is None
+    on_file = Database(str(tmp_path / "node.db"))
+    assert on_file._tail_conn is not None
+    assert _run_three_closes(memory) == _run_three_closes(on_file)
+    # one connection: the tail's transaction is the shared session's
+    with memory.tail_transaction():
+        assert memory._tx_depth == 1
+    with on_file.tail_transaction():
+        assert on_file._tx_depth == 0
+        on_file.execute("INSERT OR REPLACE INTO storestate "
+                        "(statename, state) VALUES ('k', 'v')")
+        assert on_file._conn.execute(
+            "SELECT state FROM storestate WHERE statename='k'"
+        ).fetchone() is None
+    assert on_file.query_one(
+        "SELECT state FROM storestate WHERE statename='k'")[0] == "v"
+
+
+def test_insert_rows_is_executemany_in_fewer_statements(tmp_path,
+                                                        monkeypatch):
+    """The tail's bulk inserts: the rows `executemany` leaves (keys that
+    repeat inside a batch included), a row a mark of the meter, one
+    statement per `PACKED_INSERT_ROWS`."""
+    from stellar_core_tpu.db import database as dbmod
+    from stellar_core_tpu.util.metrics import MetricsRegistry
+    sql = ("INSERT OR REPLACE INTO txfeehistory "
+           "(txid, ledgerseq, txindex, txchanges) VALUES (?,?,?,?)")
+    rows = [(bytes([i % 251]) * 32, 7, i % 40, b"changes %d" % i)
+            for i in range(45)]
+    plain = Database(":memory:")
+    plain.initialize()
+    plain.executemany(sql, rows)
+    monkeypatch.setattr(dbmod, "PACKED_INSERT_ROWS", 16)
+    metrics = MetricsRegistry()
+    packed = Database(str(tmp_path / "node.db"), metrics=metrics)
+    packed.initialize()
+    statements = []
+    packed._tail_conn.set_trace_callback(statements.append)
+    meter = metrics.meter("database", "query", "exec")
+    marks = meter.count
+    with packed.tail_transaction():
+        packed.insert_rows(sql, rows)
+    assert meter.count - marks == 45
+    # the trace shows the statements with their values bound
+    assert [s.count("), (") + 1 for s in statements
+            if s.startswith("INSERT")] == [16, 16, 13]
+    assert _history_rows(packed) == _history_rows(plain)
+    assert len(_history_rows(packed)["txfeehistory"]) == 40
+
+
+def test_tail_transaction_counts_a_busy_file(tmp_path):
+    """Another writer on the file when the tail begins: the tail waits
+    for it and `database.tail.busy` says so."""
+    from stellar_core_tpu.util.metrics import MetricsRegistry
+    metrics = MetricsRegistry()
+    db = Database(str(tmp_path / "node.db"), metrics=metrics)
+    db.initialize()
+    busy = metrics.counter("database", "tail", "busy")
+    with db.tail_transaction():
+        pass
+    assert busy.count == 0
+    other = sqlite3.connect(db.path, check_same_thread=False)
+    other.isolation_level = None
+    other.execute("BEGIN IMMEDIATE")
+    release = threading.Timer(0.2, other.execute, ("COMMIT",))
+    release.start()
+    with db.tail_transaction():
+        db.execute("INSERT OR REPLACE INTO storestate "
+                   "(statename, state) VALUES ('k', 'v')")
+    release.join()
+    other.close()
+    assert busy.count == 1
+    assert db.query_one(
+        "SELECT state FROM storestate WHERE statename='k'")[0] == "v"
+    db.close()
 
 
 # ------------------------------------------- one encoding for both sinks --
@@ -328,6 +567,45 @@ def test_crash_mid_completion_restart(tmp_path):
             (new_seq,)) is not None
     finally:
         app2.shutdown()
+
+
+def test_manual_close_hides_every_tail(tmp_path):
+    """`manual_close` joins the tail before it returns, so the barrier
+    before `seal` never waits on a standalone node; closes that follow
+    each other directly make it wait, and say so."""
+    with Application.create(VirtualClock(ClockMode.VIRTUAL_TIME),
+                            _file_cfg(tmp_path)) as app:
+        app.start()
+        master = m1.master_account(app)
+        for _ in range(3):
+            m1.submit(app, master.tx([op_payment(master.muxed, 1)]))
+            app.manual_close()
+
+        def counts():
+            j = app.metrics.to_json()
+            return tuple(j[n]["count"] for n in (
+                "ledger.close.tail.hidden", "ledger.close.tail.waited",
+                "database.tail.busy"))
+
+        assert counts() == (3, 0, 0)
+        lm = app.ledger_manager
+        gate = _GatedTail(lm, lm.get_last_closed_ledger_num() + 1)
+        first = lm.get_last_closed_ledger_num() + 1
+        for seq in (first, first + 1):
+            if seq > first:
+                assert gate.held.wait(10)
+                opener = gate.open_after_apply()
+            lcl = lm.get_last_closed_ledger_header()
+            frame, _, _ = make_tx_set_from_transactions(
+                [], lcl, app.config.network_id())
+            lm.close_ledger(LedgerCloseData(seq, frame, StellarValue(
+                txSetHash=frame.get_contents_hash(),
+                closeTime=lcl.scpValue.closeTime + 1)))
+        opener.join()
+        lm.join_completion()
+        assert counts() == (4, 1, 0)
+        zone = app.perf.report()["ledger.close.completeWait"]
+        assert zone["count"] == 5 and zone["total_ms"] >= 100
 
 
 # ------------------------------------------------ HAS snapshot at queue --

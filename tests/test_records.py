@@ -209,6 +209,21 @@ def test_layer_metric_reads_names_the_program_prints(
     assert names or program or touched, f"{reader} reads nothing checkable"
 
 
+# what PR 31 publishes about the moved completion barrier: no reader of
+# the benchmark takes them, a traced line's zones and a node's `metrics`
+# route do
+@pytest.mark.parametrize("name", ["ledger.close.tail.hidden",
+                                  "ledger.close.tail.waited",
+                                  "database.tail.busy",
+                                  "ledger.close.completeWait"])
+def test_barrier_counters_are_published_and_documented(name, program_names):
+    assert name in program_names, (
+        f"stellar_core_tpu/ opens no zone or counter {name!r}")
+    for doc in ("docs/OBSERVABILITY.md", "docs/CLOSE_PIPELINE.md"):
+        with open(os.path.join(ROOT, doc), encoding="utf-8") as fh:
+            assert f"`{name}`" in fh.read(), f"{doc} does not name {name}"
+
+
 # ------------------------------------------------------------ documents --
 
 CITED = re.compile(r"`([^`\s]+)`")
