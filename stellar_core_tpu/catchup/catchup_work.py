@@ -18,6 +18,7 @@ import io
 import os
 import tempfile
 import time
+from collections import deque
 from typing import Dict, List, Optional
 
 import threading
@@ -357,6 +358,77 @@ class _AsyncResult:
         return self._res
 
 
+class _ChunkFeed:
+    """The verdicts of one dispatched batch, chunk by chunk: a daemon
+    thread takes each chunk from the verifier's collect callable as it
+    lands (`chunks()` of a split batch, ops/chunking.py; a callable
+    without it is one chunk) and the crank thread takes what has landed
+    without ever blocking on the device. Daemon for `_AsyncResult`'s
+    reason: a stalled batch dies with the process."""
+
+    def __init__(self, handle, n: int):
+        self._lock = threading.Lock()   # guards _landed and error
+        self._landed = deque()   # (lo, hi, verdicts or None, landed at)
+        self._first = threading.Event()
+        self._done = threading.Event()
+        self.error: Optional[BaseException] = None
+        if handle is not None:
+            threading.Thread(target=self._run, args=(handle, n),
+                             daemon=True, name="batch-resolve").start()
+
+    @classmethod
+    def ready(cls, verdicts) -> "_ChunkFeed":
+        """A synchronous verifier's result: already landed, no thread."""
+        feed = cls(None, 0)
+        feed._landed.append((0, len(verdicts), verdicts,
+                             time.perf_counter()))
+        feed._first.set()
+        feed._done.set()
+        return feed
+
+    def _run(self, handle, n: int) -> None:  # thread-domain: catchup-worker
+        from ..ops.chunking import chunks_of
+        from ..util import threads
+        if threads.CHECK:
+            threads.bind("catchup-worker")
+        try:
+            for lo, hi, verdicts in chunks_of(handle, n):
+                with self._lock:
+                    self._landed.append(
+                        (lo, hi, verdicts, time.perf_counter()))
+                self._first.set()
+        except BaseException as e:      # surfaced by take()
+            with self._lock:
+                self.error = e
+        finally:
+            self._done.set()
+            self._first.set()
+
+    def take(self, grace: float = 0.0) -> list:
+        """What has landed since the last call, in order; waits up to
+        `grace` seconds for the first chunk. Raises the collect's error
+        once everything that landed before it has been taken."""
+        if grace > 0:
+            self._first.wait(grace)
+        with self._lock:
+            out = list(self._landed)
+            self._landed.clear()
+            error = None
+            if not out and self.error is not None:
+                error, self.error = self.error, None
+        if error is not None:
+            raise error
+        return out
+
+    def exhausted(self) -> bool:
+        with self._lock:
+            return self._done.is_set() and not self._landed \
+                and self.error is None
+
+    def wait(self, timeout: Optional[float] = None) -> bool:
+        return self._done.wait(timeout)
+
+
 class DownloadVerifyTxResultsWork(BasicWork):
     """Download a checkpoint's archived tx results and verify each
     ledger's result set against the already-verified header chain
@@ -428,10 +500,17 @@ class ApplyCheckpointWork(BasicWork):
     """Replay one checkpoint's ledgers through closeLedger (reference:
     catchup/ApplyCheckpointWork.{h,cpp} — the north-star hot path).
 
-    With `batch_verifier` set, every checkpoint's signature tuples are
-    verified in ONE device batch before the apply loop; the per-signature
-    results seed a PrevalidatedVerifier so the sequential apply does hash
-    lookups instead of scalar verifies (SURVEY.md §3.3)."""
+    With `batch_verifier` set, every checkpoint's signature tuples
+    (resolved against the envelopes, the checkpoint's own SetOptions and
+    the node's ledger state: tx/signature_checker.py) go to the device
+    as one batch before the apply loop. A batch that fits the largest
+    bucket is one device call; a larger one runs as chunks of it
+    (ops/chunking.py), and because tuples are collected in ledger order
+    chunk k holds the earliest ledgers not yet covered: each landed
+    chunk's verdicts join the PrevalidatedVerifier at the next ledger's
+    start, so the sequential apply does hash lookups instead of scalar
+    verifies for whatever the device has finished (SURVEY.md §3.3), and
+    never waits for the rest."""
 
     def __init__(self, app, archive: HistoryArchive, checkpoint: int,
                  headers: Dict[int, LedgerHeaderHistoryEntry],
@@ -460,10 +539,11 @@ class ApplyCheckpointWork(BasicWork):
         self._txs_by_seq: Optional[Dict[int, TransactionHistoryEntry]] = None
         self._get: Optional[GetRemoteFileWork] = None
         self._next_seq: Optional[int] = None
-        self._pending_batch = None   # (tuples, resolver future)
-        # perf_counter at the dispatch of the batch, and the verifier's
-        # running number for it (None from a verifier that keeps none)
-        self._batch_t0 = 0.0
+        # (tuples, their table keys, _ChunkFeed) of the dispatched batch
+        # while any of its chunks is still to be adopted
+        self._pending_batch = None
+        # the verifier's running number for the batch (None from a
+        # verifier that keeps none)
         self._batch_id = None
         self._frame_sets: Dict[int, TxSetFrame] = {}
         self._prefetch_failed = False
@@ -580,12 +660,12 @@ class ApplyCheckpointWork(BasicWork):
             else State.WORK_SUCCESS
 
     def _batch_prevalidate(self) -> None:
-        """Dispatch one device batch for the whole checkpoint's
-        signatures (async — results are collected lazily at first apply,
-        so the device computes while earlier ledgers still apply)."""
+        """Resolve and dispatch the whole checkpoint's signatures as one
+        batch (async — verdicts are adopted chunk by chunk as they
+        land, so the device computes while earlier ledgers apply)."""
         network_id = self.app.config.network_id()
         frames = []
-        for the in self._txs_by_seq.values():
+        for _, the in sorted(self._txs_by_seq.items()):
             if not self._next_seq <= the.ledgerSeq <= self.last_ledger:
                 continue  # outside the replay range; never applied
             if the.ext.disc == 1:
@@ -596,22 +676,28 @@ class ApplyCheckpointWork(BasicWork):
             # hashes) instead of re-parsing the txset per ledger
             self._frame_sets[the.ledgerSeq] = frame_set
             frames.extend(t for t, _ in frame_set._frames_with_base_fee())
-        tuples = collect_signature_tuples(frames, network_id)
+        # frames are in ledger order (the history file's), so the
+        # tuples are, and chunk k of a split batch covers the earliest
+        # ledgers no earlier chunk does
+        tuples = collect_signature_tuples(
+            frames, network_id, ledger_state=self.app.ledger_manager.root,
+            perf=self.app.perf, metrics=self.app.metrics,
+            checkpoint=self.checkpoint)
         if not tuples:
             return
         try:
             if hasattr(self.batch_verifier, "verify_tuples_async"):
                 # collect device results on a daemon side thread: apply
-                # never stalls on the batch — ledgers applied before it
-                # lands verify through the sync fallback, later ones hit
-                # the table — and an abandoned/stalled batch can never
-                # block process shutdown
+                # never stalls on the batch — ledgers applied before a
+                # chunk lands verify through the sync fallback, later
+                # ones hit the table — and an abandoned/stalled batch
+                # can never block process shutdown
                 handle = self.batch_verifier.verify_tuples_async(tuples)
-                fut = _AsyncResult(handle)
+                feed = _ChunkFeed(handle, len(tuples))
             else:
                 # synchronous verifier: the cost was just paid inline;
-                # no thread, the result is simply ready
-                fut = _ReadyResult(
+                # the result is simply ready
+                feed = _ChunkFeed.ready(
                     self.batch_verifier.verify_tuples(tuples))
         except Exception:
             # device verifier down at dispatch: the sync fallback
@@ -620,40 +706,36 @@ class ApplyCheckpointWork(BasicWork):
                         "dispatch; native fallback", self.checkpoint,
                         exc_info=True)
             return
-        self._pending_batch = (tuples, fut)
-        self._batch_t0 = time.perf_counter()
         self._batch_id = getattr(self.batch_verifier, "last_batch_id", None)
-        # the table exists, empty, from the dispatch on: a check that
-        # apply makes before the batch has landed is a counted miss
-        # (and verified by the fallback, as before), so hits + misses
-        # are all the checks of this checkpoint's applies
+        # the table exists from the dispatch on and knows what is on its
+        # way: a check that apply makes before its chunk has landed is a
+        # counted pending miss, one for a tuple the resolver never made
+        # an unknown miss (both verified by the fallback, as before), so
+        # hits + misses are all the checks of this checkpoint's applies
         from ..tx.signature_checker import (PrevalidatedVerifier,
                                             default_verify)
         self.prevalidated = PrevalidatedVerifier(
             fallback=self.verify or default_verify)
+        self._pending_batch = (tuples, self.prevalidated.expect(tuples),
+                               feed)
         log.info("checkpoint %d: dispatched batch of %d signatures",
                  self.checkpoint, len(tuples))
 
     def _resolve_prevalidated(self, seq: int) -> None:
-        """Adopt the dispatched batch's results once available (`seq`
-        is the ledger about to apply, the first to use them).  The
-        first probe grants a short grace (`batch_grace` seconds) — worth
-        a bounded stall to catch a nearly-landed batch — after which the
-        probe is non-blocking and the sync fallback covers the in-flight
-        gap, so apply never waits on the device."""
+        """Adopt the chunks of the dispatched batch that have landed
+        (`seq` is the ledger about to apply, the first to use them).
+        The first probe grants a short grace (`batch_grace` seconds) —
+        worth a bounded stall to catch a nearly-landed first chunk —
+        after which the probe is non-blocking and the sync fallback
+        covers what is still in flight, so apply never waits on the
+        device. A chunk that failed drops only itself to the fallback."""
         if self._pending_batch is None:
             return
-        tuples, fut = self._pending_batch
+        tuples, keys, feed = self._pending_batch
+        grace = 0.0 if self._grace_spent else self.batch_grace
+        self._grace_spent = True
         try:
-            if self._grace_spent or self.batch_grace <= 0:
-                if not fut.done():
-                    return
-                results = fut.result()
-            else:
-                self._grace_spent = True
-                results = fut.result(timeout=self.batch_grace)
-                if results is _PENDING:
-                    return
+            landed = feed.take(grace)
         except Exception:
             # device verifier died after dispatch: drop the batch and
             # let the sync fallback verify everything
@@ -661,27 +743,34 @@ class ApplyCheckpointWork(BasicWork):
                         "collection; native fallback", self.checkpoint,
                         exc_info=True)
             self._pending_batch = None
-            # an empty table would only cost a key and a miss per
-            # check: publish what it was asked so far and go back to
-            # the plain verifier
+            # a table that will learn nothing more would only cost a
+            # key and a miss per check: publish what it was asked so
+            # far and go back to the plain verifier
             self._retire_prevalidated(drop=True)
             return
-        self._pending_batch = None
-        self.prevalidated.add_results(tuples, results)
-        # dispatch to adoption: the dispatch's own wall time
-        # (crypto.verify.dispatch.wall) plus the time the landed batch
-        # waited for apply to look
-        self.app.metrics.new_timer("catchup.batch.adoptLag").update(
-            time.perf_counter() - self._batch_t0)
-        if tracing.ENABLED:
-            rec = self.app.flight_recorder
-            if rec.active:
-                rec.instant("catchup.batch.adopted", {
-                    "checkpoint": self.checkpoint,
-                    "batch": self._batch_id, "seq": seq,
-                    "n": len(tuples)})
-        log.info("checkpoint %d: batch-verified %d signatures",
-                 self.checkpoint, len(tuples))
+        now = time.perf_counter()
+        for lo, hi, verdicts, landed_at in landed:
+            if verdicts is None:
+                log.warning("checkpoint %d: chunk [%d, %d) of the batch "
+                            "failed; native fallback for it",
+                            self.checkpoint, lo, hi)
+                continue
+            self.prevalidated.add_results(tuples[lo:hi], verdicts,
+                                          keys[lo:hi])
+            # landed to adopted: what the chunk waited for apply to look
+            self.app.metrics.new_timer("catchup.batch.adoptLag").update(
+                now - landed_at)
+            if tracing.ENABLED:
+                rec = self.app.flight_recorder
+                if rec.active:
+                    rec.instant("catchup.batch.adopted", {
+                        "checkpoint": self.checkpoint,
+                        "batch": self._batch_id, "seq": seq,
+                        "lo": lo, "n": hi - lo})
+            log.info("checkpoint %d: batch-verified signatures [%d, %d) "
+                     "before ledger %d", self.checkpoint, lo, hi, seq)
+        if feed.exhausted():
+            self._pending_batch = None
 
     def _retire_prevalidated(self, drop: bool = False) -> None:
         """Publish what the table was asked (crypto.prevalidated.hit /
@@ -709,7 +798,7 @@ class ApplyCheckpointWork(BasicWork):
         and only a settled batch shows in the supervisor's status. A
         work abandoned before its end publishes its table here."""
         if self._pending_batch is not None:
-            self._pending_batch[1].wait(timeout)
+            self._pending_batch[2].wait(timeout)
         if not self.is_done():
             self._retire_prevalidated(drop=True)
 

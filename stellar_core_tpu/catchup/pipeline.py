@@ -259,7 +259,11 @@ def _verify_checkpoint_bundle(task: _CheckpointTask, paths: Dict[str, str],
             frames[the.ledgerSeq] = frame
             sig_frames.extend(
                 t for t, _ in frame._frames_with_base_fee())
-        tuples = collect_signature_tuples(sig_frames, network_id)
+        # the shared resolver, without ledger state: this runs beside
+        # the apply that writes it, so candidates come from the envelopes
+        # and from the checkpoint's own SetOptions
+        tuples = collect_signature_tuples(sig_frames, network_id, perf=perf,
+                                          checkpoint=task.cp)
 
         results: Dict[int, TransactionHistoryResultEntry] = {}
         if "results" in paths:

@@ -901,7 +901,7 @@ class LedgerManager:
         from .parallel_apply import StageSnapshot
         targs = {"width": len(stage)} if tracing.ENABLED else None
         with self.perf.zone("ledger.close.applyTx.stage", targs=targs):
-            self._prewarm_stage_verify([txs[i] for i in stage])
+            self._prewarm_stage_verify([txs[i] for i in stage], verify)
             stage_keys = set()
             for i in stage:
                 stage_keys |= footprints[i].keys
@@ -989,15 +989,22 @@ class LedgerManager:
                 slots[i] = exc
         return job
 
-    def _prewarm_stage_verify(self, stage_txs) -> None:
-        """Batch the stage's hint-matching signatures through the
-        verify service so worker-side checks hit the process-wide
-        verify cache (the reference's per-cluster signature batching,
-        SOSP 2019 §6) — a miss just falls back to sync verify."""
+    def _prewarm_stage_verify(self, stage_txs, verify) -> None:
+        """Batch the stage's signatures (every signer candidate of its
+        envelopes) through the verify service so worker-side checks hit
+        the process-wide verify cache (the reference's per-cluster
+        signature batching, SOSP 2019 §6) — a miss just falls back to
+        sync verify. Under a `PrevalidatedVerifier` the workers look in
+        its table and not in that cache: the checkpoint's batch is the
+        prewarm, and a second one would verify every signature again
+        and queue its device calls behind the batch's own chunks."""
+        from ..tx.signature_checker import (PrevalidatedVerifier,
+                                            collect_signature_tuples)
+        if isinstance(verify, PrevalidatedVerifier):
+            return
         vs = self.verify_service
         if vs is None:
             return
-        from ..tx.signature_checker import collect_signature_tuples
         tuples = collect_signature_tuples(stage_txs)
         if not tuples:
             return

@@ -613,10 +613,14 @@ class CommandHandler:
 
     def _generate_load(self, params) -> dict:
         """reference: CommandHandler::generateLoad — synthesize load
-        (generateload?mode=create|pay|zipf&accounts=N&txs=N
-        [&exponent=F]). `zipf` is the hot-account skew mode (ISSUE 16's
-        Zipfian loadgen, ISSUE 20's matrix cell): rank-weighted
-        source/destination draws, reproducible per node."""
+        (generateload?mode=create|pay|zipf|multisig_setup|multisig
+        &accounts=N&txs=N[&exponent=F]). `zipf` is the hot-account skew
+        mode (ISSUE 16's Zipfian loadgen, ISSUE 20's matrix cell):
+        rank-weighted source/destination draws, reproducible per node.
+        `multisig_setup` installs the signers of the benchmark's four
+        signer classes (close a ledger after it), `multisig` sends
+        payments signed m-of-n, fee-bumped and with twenty signatures
+        as each source's class says."""
         from ..simulation.load_generator import LoadGenerator
         mode = params.get("mode", "create")
         if getattr(self, "_load_generator", None) is None:
@@ -637,6 +641,20 @@ class CommandHandler:
                     n, exponent=float(params.get("exponent", "1.0")))
             else:
                 submitted = lg.generate_payments(n)
+            return {"status": "ok", "mode": mode, "submitted": submitted}
+        if mode in ("multisig_setup", "multisig"):
+            if len(lg.accounts) < 2:
+                return {"exception": "run generateload?mode=create and "
+                        "close a ledger first"}
+            lg.sync_account_seqs()
+            if mode == "multisig_setup":
+                submitted = lg.setup_multisig()
+            elif getattr(lg, "_multisig", None) is None:
+                return {"exception": "run generateload?mode=multisig_setup "
+                        "and close a ledger first"}
+            else:
+                submitted = lg.generate_multisig(
+                    int(params.get("txs", "100")))
             return {"status": "ok", "mode": mode, "submitted": submitted}
         return {"exception": f"unknown load mode: {mode}"}
 

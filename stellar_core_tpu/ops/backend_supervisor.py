@@ -90,6 +90,7 @@ import threading
 import time
 from typing import List, Optional, Sequence, Tuple
 
+from . import chunking
 from .shard_math import shard_shares
 from ..util import chaos, tracing
 from ..util.logging import get_logger
@@ -328,9 +329,19 @@ class BackendSupervisor:
         least one device is CLOSED, straight to the native path when
         the mesh is empty (no device attempt, no failure latency).
         Always returns a zero-arg collect callable whose results are
-        identical to PubKeyUtils.verify_sig."""
+        identical to PubKeyUtils.verify_sig. A batch larger than the
+        largest bucket is split here (ops/chunking.py), so that every
+        chunk is a dispatch of its own: its own deadline, its own
+        breaker accounting, and a chunk that fails or overruns falls
+        back to the native path alone."""
         if not items:
             return lambda: []
+        if len(items) > chunking.MAX_BUCKET:
+            # the wrapped verifier numbers the batch: its chunks share
+            # the number the first chunk's dispatch took
+            return chunking.ChunkedCollect(
+                self._inner, items,
+                lambda part, chunk: self._dispatch(part, chunk=chunk))
         with self._lock:
             if not self._active_locked():
                 self._record_skip_locked()
@@ -348,10 +359,12 @@ class BackendSupervisor:
         for b in self._breakers:
             b.skips.inc()
 
-    def _dispatch(self, items, probe_device: Optional[int] = None):
+    def _dispatch(self, items, probe_device: Optional[int] = None,
+                  chunk: Optional[tuple] = None):
         """Dispatch to the active mesh (breakers permitting) and wrap
         the collect handle with the watchdog deadline. `probe_device`
-        pins the dispatch to one device — the canary-probe path."""
+        pins the dispatch to one device — the canary-probe path;
+        `chunk` marks a chunk of a split batch for the verifier."""
         probe = probe_device is not None
         with self._lock:
             if probe:
@@ -424,6 +437,9 @@ class BackendSupervisor:
             elif probe and hasattr(self._inner, "verify_tuples_async_on"):
                 inner_collect = self._inner.verify_tuples_async_on(
                     probe_device, items)
+            elif chunk is not None:
+                inner_collect = self._inner.verify_tuples_async(
+                    items, chunk=chunk)
             else:
                 inner_collect = self._inner.verify_tuples_async(items)
         except Exception as e:

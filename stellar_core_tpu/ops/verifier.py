@@ -50,7 +50,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as PSpec
 from jax import shard_map
 
-from . import ed25519_kernel
+from . import chunking, ed25519_kernel
 from .shard_math import shard_shares
 from ..util import chaos, tracing
 
@@ -205,7 +205,7 @@ class TpuBatchVerifier:
         self.last_batch_id = 0
         if metrics is None:
             self._m_batch = self._m_padding = self._m_wall = None
-            self._m_host = None
+            self._m_host = self._m_chunks = None
             return
         self._m_batch = metrics.new_histogram(
             "crypto.verify.dispatch.batch")
@@ -215,6 +215,10 @@ class TpuBatchVerifier:
         # entry of verify_tuples_async to the return of the enqueue:
         # what a dispatch costs the thread that makes it
         self._m_host = metrics.new_timer("crypto.verify.dispatch.host")
+        # chunks dispatched of batches larger than the largest bucket
+        # (ops/chunking.py); a batch that fits one bucket adds nothing
+        self._m_chunks = metrics.new_counter(
+            "crypto.verify.dispatch.chunks")
 
     def verify_batch(self, pubs: np.ndarray, sigs: np.ndarray,
                      msgs: Sequence[bytes]) -> np.ndarray:
@@ -233,13 +237,17 @@ class TpuBatchVerifier:
         return self._enqueue(self._pack(pubs, sigs, msgs, _active))
 
     def _pack(self, pubs: np.ndarray, sigs: np.ndarray,
-              msgs: Sequence[bytes], active=None) -> SimpleNamespace:
+              msgs: Sequence[bytes], active=None,
+              full: bool = False) -> SimpleNamespace:
         """Host half of a dispatch: the padded arrays of the batch's
-        bucket and the program that takes them."""
+        bucket and the program that takes them. `full` pads to the
+        largest bucket whatever `n` is: a chunk of a split batch runs
+        the one shape its siblings run."""
         n = len(msgs)
         pubs = np.asarray(pubs, dtype=np.uint8).reshape(n, 32)
         sigs = np.asarray(sigs, dtype=np.uint8).reshape(n, 64)
-        bucket = _bucket_size(n, self._min_bucket)
+        bucket = chunking.MAX_BUCKET if full \
+            else _bucket_size(n, self._min_bucket)
         if all(len(m) == 32 for m in msgs):
             # tx-hash hot path: ship M raw, SHA-512 + mod L on device —
             # no per-signature host work
@@ -285,15 +293,26 @@ class TpuBatchVerifier:
         return self.verify_tuples_async(items)()
 
     def verify_tuples_async(
-            self, items: Sequence[Tuple[bytes, bytes, bytes]]):
+            self, items: Sequence[Tuple[bytes, bytes, bytes]],
+            chunk: Optional[tuple] = None):
         """Non-blocking verify_tuples: dispatches host prep + transfer +
         device compute and returns a zero-arg callable yielding the
         List[bool]. Used to overlap checkpoint N+1's signature batch with
         checkpoint N's sequential apply in catchup. The crypto.batchVerify
         perf zone wraps dispatch and (separately) collection, so the
-        accounting survives the async split."""
+        accounting survives the async split.
+
+        A batch larger than the largest bucket runs as chunks of it
+        (ops/chunking.py): the callable returned is then a
+        `ChunkedCollect`, whose `chunks()` also hands out each chunk's
+        verdicts as it lands. `chunk` = (k, of, batch) marks a call as
+        chunk `k` of `of` of the split batch numbered `batch` (None:
+        number it here); whoever splits the batch passes it."""
         if not items:
             return lambda: []
+        if chunk is None and len(items) > chunking.MAX_BUCKET:
+            return chunking.ChunkedCollect(self, items,
+                                           self.verify_tuples_async)
         if chaos.ENABLED:
             # device-verifier fault seam: an injected io_error raises
             # BEFORE any dispatch — callers must fall back to the
@@ -304,28 +323,38 @@ class TpuBatchVerifier:
         t_in = _time.perf_counter()
         from ..util.perf import default_registry
         registry = self.perf or default_registry
-        if len(items) < self._device_min_batch:
+        if chunk is None and len(items) < self._device_min_batch:
             # small-batch CPU bypass: the fixed device dispatch cost
             # loses to the native verifier below the cutoff, so tiny
             # flushes (the verify service's deadline stragglers) stay
-            # on host — same strict accept/reject either way
+            # on host — same strict accept/reject either way (the
+            # remainder of a split batch is no such flush)
             from ..crypto.keys import verify_sig_uncached
             targs = {"n": len(items)} if tracing.ENABLED else None
             with registry.zone("crypto.batchVerify.native", targs=targs):
                 res = [verify_sig_uncached(p, s, m) for p, s, m in items]
             return lambda: res
-        self.last_batch_id = batch = self.last_batch_id + 1
-        # one dict for every span of this batch, on both threads;
-        # `bucket` is known once the batch is packed
+        if chunk is None or chunk[2] is None:
+            self.last_batch_id = batch = self.last_batch_id + 1
+        else:
+            batch = chunk[2]
+        # one dict for every span of this batch (of this chunk of it),
+        # on both threads; `bucket` is known once it is packed
         targs = {"batch": batch, "n": len(items)} \
             if tracing.ENABLED else None
+        if chunk is not None:
+            if targs is not None:
+                targs["chunk"], targs["of"] = chunk[0], chunk[1]
+            if self._m_chunks is not None:
+                self._m_chunks.inc()
         with registry.zone("crypto.batchVerify", targs=targs):
             with registry.zone("crypto.batchVerify.pack", targs=targs):
                 pubs = np.frombuffer(b"".join(p for p, _, _ in items),
                                      dtype=np.uint8).reshape(-1, 32)
                 sigs = np.frombuffer(b"".join(s for _, s, _ in items),
                                      dtype=np.uint8).reshape(-1, 64)
-                packed = self._pack(pubs, sigs, [m for _, _, m in items])
+                packed = self._pack(pubs, sigs, [m for _, _, m in items],
+                                    full=chunk is not None)
                 if targs is not None:
                     targs["bucket"] = packed.bucket
             with registry.zone("crypto.batchVerify.enqueue", targs=targs):
@@ -493,16 +522,20 @@ class ShardedBatchVerifier(TpuBatchVerifier):
 
     # -------------------------------------------------------- dispatch --
     def _pack(self, pubs: np.ndarray, sigs: np.ndarray,
-              msgs: Sequence[bytes], active=None) -> SimpleNamespace:
+              msgs: Sequence[bytes], active=None,
+              full: bool = False) -> SimpleNamespace:
         """Mesh dispatch, host half: padded per-shard buckets over the
         active devices (`active` pins an explicit set, None uses the
-        live mesh) and the program of that set."""
+        live mesh) and the program of that set. `full`: the largest
+        bucket (a chunk of a split batch), rounded up to a multiple of
+        the active devices."""
         n = len(msgs)
         active = tuple(active) if active is not None else self._active
         nact = len(active)
         pubs = np.asarray(pubs, dtype=np.uint8).reshape(n, 32)
         sigs = np.asarray(sigs, dtype=np.uint8).reshape(n, 64)
-        bucket = _bucket_size(n, self._min_bucket_for(nact))
+        bucket = -(-chunking.MAX_BUCKET // nact) * nact if full \
+            else _bucket_size(n, self._min_bucket_for(nact))
         rows = bucket // nact
         counts = shard_shares(n, nact)
 

@@ -88,7 +88,12 @@ class LoadGenerator:
     # ------------------------------------------------------------ building --
     def _sign_and_submit(self, source: GeneratedAccount,
                          ops: List[Operation], fee: Optional[int] = None,
-                         ext=None) -> AddResult:
+                         ext=None, signers: Optional[List[SecretKey]] = None,
+                         fee_payer: Optional[GeneratedAccount] = None
+                         ) -> AddResult:
+        """Build, sign and submit one transaction of `source`: signed
+        by `signers` (default: the source's master key), and wrapped in
+        a fee bump that `fee_payer` signs and pays when one is given."""
         source.seq += 1
         tx = Transaction(
             sourceAccount=source.muxed,
@@ -101,16 +106,39 @@ class LoadGenerator:
             EnvelopeType.ENVELOPE_TYPE_TX,
             TransactionV1Envelope(tx=tx, signatures=[]))
         frame = make_frame(env, self.network_id)
-        sig = source.key.sign(frame.contents_hash())
-        frame.signatures.append(DecoratedSignature(
-            hint=source.key.public_key().hint(), signature=sig))
+        for key in signers or [source.key]:
+            frame.signatures.append(DecoratedSignature(
+                hint=key.public_key().hint(),
+                signature=key.sign(frame.contents_hash())))
         env.value.signatures = frame.signatures
+        if fee_payer is not None:
+            frame = self._fee_bump(frame, fee_payer)
         res = self.app.herder.recv_transaction(frame)
         self.submitted += 1
         if res != AddResult.ADD_STATUS_PENDING:
             self.failed += 1
             source.seq -= 1
         return res
+
+    def _fee_bump(self, inner, payer: GeneratedAccount):
+        """`inner` wrapped in a fee bump that `payer` signs: twice the
+        inner bid, for the bump counts as one operation more."""
+        from ..xdr.transaction import (FeeBumpTransaction,
+                                       FeeBumpTransactionEnvelope,
+                                       _FeeBumpInnerTx)
+        fb = FeeBumpTransaction(
+            feeSource=payer.muxed, fee=2 * inner.tx.fee,
+            innerTx=_FeeBumpInnerTx(EnvelopeType.ENVELOPE_TYPE_TX,
+                                    inner.envelope.value),
+            ext=_TxExt(0))
+        env = FeeBumpTransactionEnvelope(tx=fb, signatures=[])
+        frame = make_frame(TransactionEnvelope(
+            EnvelopeType.ENVELOPE_TYPE_TX_FEE_BUMP, env), self.network_id)
+        env.signatures = [DecoratedSignature(
+            hint=payer.key.public_key().hint(),
+            signature=payer.key.sign(frame.contents_hash()))]
+        frame.signatures = env.signatures
+        return frame
 
     # --------------------------------------------------------------- modes --
     def generate_accounts(self, n: int,
@@ -194,6 +222,83 @@ class LoadGenerator:
             src = self.accounts[order[min(si, len(order) - 1)]]
             dst = self.accounts[order[min(di, len(order) - 1)]]
             if self._sign_and_submit(src, [self._payment_op(dst, amount)]) \
+                    == AddResult.ADD_STATUS_PENDING:
+                ok += 1
+        return ok
+
+    # ---------------------------------------------------------- multisig --
+    # (class, its share in tenths, signers beside the master key, the
+    # threshold = signatures an envelope carries, fee-bumped): the four
+    # classes of the benchmark's `multisig-dense` deployment
+    # (benchmark/configs/multisig-dense.json). The shares here are a
+    # stress mix of this generator's own, not that deployment's and not
+    # pubnet's: most accounts multi-signer, so that a handful of
+    # generated accounts holds every class
+    MULTISIG_CLASSES = (("single", 2, 0, 1, False),
+                        ("2of3", 3, 2, 2, False),
+                        ("3of5-bumped", 2, 4, 3, True),
+                        ("limit20", 3, 19, 20, False))
+
+    def setup_multisig(self) -> int:
+        """MULTISIG mode, step one: draw every generated account's class
+        once from the node-seeded RNG, in the fixed shares of
+        MULTISIG_CLASSES, and have every multi-signer account install
+        its signers (weight 1 each) and its three thresholds by one
+        SetOptions transaction of its own. Close a ledger before
+        `generate_multisig`. Returns the transactions admitted."""
+        from ..xdr.ledger_entries import Signer
+        from ..xdr.transaction import SetOptionsOp
+        from ..xdr.types import SignerKey, SignerKeyType
+        assert self.accounts, "run generate_accounts first"
+        classes = [c for c in self.MULTISIG_CLASSES
+                   for _ in range(c[1] * len(self.accounts) // 10)]
+        classes += [self.MULTISIG_CLASSES[0]] * \
+            (len(self.accounts) - len(classes))
+        self._rng.shuffle(classes)
+        self._multisig: Dict[int, tuple] = {}
+        ok = 0
+        for i, (acct, cls) in enumerate(zip(self.accounts, classes)):
+            _, _, extra, threshold, bumped = cls
+            keys = [SecretKey.from_seed(sha256(
+                b"loadgen-signer-%d-%d-%d" % (i, j,
+                                              self.app.config.PEER_PORT)))
+                for j in range(extra)]
+            self._multisig[i] = ([acct.key] + keys, threshold, bumped)
+            ops = []
+            for j, key in enumerate(keys):
+                last = j == len(keys) - 1
+                level = threshold if last else None
+                ops.append(Operation(sourceAccount=None, body=_OperationBody(
+                    OperationType.SET_OPTIONS, SetOptionsOp(
+                        inflationDest=None, clearFlags=None, setFlags=None,
+                        masterWeight=None, lowThreshold=level,
+                        medThreshold=level, highThreshold=level,
+                        homeDomain=None,
+                        signer=Signer(key=SignerKey(
+                            SignerKeyType.SIGNER_KEY_TYPE_ED25519,
+                            key.public_key().raw), weight=1)))))
+            if ops and self._sign_and_submit(acct, ops) == \
+                    AddResult.ADD_STATUS_PENDING:
+                ok += 1
+        return ok
+
+    def generate_multisig(self, n: int, amount: int = 10000) -> int:
+        """MULTISIG mode: PAY mode's payments, each signed as its
+        source's class says: m of the account's n keys (which ones is
+        drawn from the node-seeded RNG), and for a bumped class wrapped
+        in a fee bump that the root signs and pays."""
+        assert getattr(self, "_multisig", None), "run setup_multisig first"
+        order = self._account_order()
+        ok = 0
+        for i in range(n):
+            at = order[i % len(order)]
+            src = self.accounts[at]
+            dst = self.accounts[order[(i + 1) % len(order)]]
+            keys, threshold, bumped = self._multisig[at]
+            if self._sign_and_submit(
+                    src, [self._payment_op(dst, amount)],
+                    signers=self._rng.sample(keys, threshold),
+                    fee_payer=self.root if bumped else None) \
                     == AddResult.ADD_STATUS_PENDING:
                 ok += 1
         return ok

@@ -19,10 +19,14 @@ import hashlib
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from ..crypto.keys import PubKeyUtils
+from ..util import tracing
 from ..xdr.types import SignerKey, SignerKeyType
 from ..xdr.transaction import DecoratedSignature
 
 VerifyFn = Callable[[bytes, bytes, bytes], bool]  # (pub, sig, msg) -> ok
+
+
+_UNKNOWN = object()      # a tuple the table was never told of
 
 
 def default_verify(pub: bytes, sig: bytes, msg: bytes) -> bool:
@@ -30,46 +34,80 @@ def default_verify(pub: bytes, sig: bytes, msg: bytes) -> bool:
 
 
 class PrevalidatedVerifier:
-    """Lookup table of (pub, sig, msg) -> bool filled by one TPU batch
-    verify; falls back to the sync path on miss (stragglers keep exact
+    """Lookup table of (pub, sig, msg) -> bool filled by TPU batch
+    verifies; falls back to the sync path on miss (stragglers keep exact
     semantics, SURVEY.md §7 'latency vs batch').
 
-    `hits` and `misses` are plain attributes (the call is per
+    A table filled chunk by chunk is told first what was dispatched
+    (`expect`), so a miss is one of two kinds: `misses_pending` (the
+    tuple is on its way: its chunk has not been adopted yet, which is
+    what apply outran) and `misses_unknown` (no one made this tuple:
+    the resolver missed a candidate).
+
+    `hits` and the misses are plain attributes (the call is per
     signature); the owner calls `publish` when it retires the table."""
 
     def __init__(self, fallback: VerifyFn = default_verify):
-        self._results: Dict[bytes, bool] = {}
+        # key -> verdict, or None while the tuple's chunk is in flight
+        self._results: Dict[bytes, Optional[bool]] = {}
         self._fallback = fallback
         self.hits = 0
-        self.misses = 0
+        self.misses_pending = 0
+        self.misses_unknown = 0
+
+    @property
+    def misses(self) -> int:
+        return self.misses_pending + self.misses_unknown
 
     @staticmethod
     def _key(pub: bytes, sig: bytes, msg: bytes) -> bytes:
         return hashlib.blake2b(pub + sig + msg, digest_size=32).digest()
 
+    def expect(self, tuples: Sequence[Tuple[bytes, bytes, bytes]]
+               ) -> List[bytes]:
+        """Note `tuples` as dispatched and not yet answered; returns
+        their keys, which `add_results` takes back as `keys` so each
+        tuple is hashed once."""
+        key = self._key
+        keys = [key(p, s, m) for p, s, m in tuples]
+        results = self._results
+        for k in keys:
+            results.setdefault(k, None)
+        return keys
+
     def add_results(self, tuples: Sequence[Tuple[bytes, bytes, bytes]],
-                    results: Sequence[bool]) -> None:
-        for (p, s, m), ok in zip(tuples, results):
-            self._results[self._key(p, s, m)] = bool(ok)
+                    results: Sequence[bool],
+                    keys: Optional[Sequence[bytes]] = None) -> None:
+        if keys is None:
+            keys = [self._key(p, s, m) for p, s, m in tuples]
+        for k, ok in zip(keys, results):
+            self._results[k] = bool(ok)
 
     def __call__(self, pub: bytes, sig: bytes, msg: bytes) -> bool:
-        r = self._results.get(self._key(pub, sig, msg))
-        if r is not None:
+        r = self._results.get(self._key(pub, sig, msg), _UNKNOWN)
+        if r is _UNKNOWN:
+            self.misses_unknown += 1
+        elif r is None:
+            self.misses_pending += 1
+        else:
             self.hits += 1
             return r
-        self.misses += 1
         return self._fallback(pub, sig, msg)
 
     def publish(self, metrics) -> None:
-        """Add `hits` and `misses` to the counters
+        """Add `hits` and the misses to the counters
         `crypto.prevalidated.hit` / `.miss` of `metrics`: the checks
         the batch answered, and those it was asked and had to hand to
-        the fallback. The owner calls it once, when it retires the
-        table."""
+        the fallback; `.miss.pending` and `.miss.unknown` split the
+        latter. The owner calls it once, when it retires the table."""
         if metrics is None:
             return
         metrics.new_counter("crypto.prevalidated.hit").inc(self.hits)
         metrics.new_counter("crypto.prevalidated.miss").inc(self.misses)
+        metrics.new_counter("crypto.prevalidated.miss.pending").inc(
+            self.misses_pending)
+        metrics.new_counter("crypto.prevalidated.miss.unknown").inc(
+            self.misses_unknown)
 
 
 def signed_payload_hint(pubkey_raw: bytes, payload: bytes) -> bytes:
@@ -173,25 +211,159 @@ class SignatureChecker:
         return all(self.used)
 
 
-def collect_signature_tuples(frames, network_id=None):
-    """(pub, sig, msg) candidates for a batch verify: each decorated
-    signature paired with the tx's hint-matching source key, and — when
-    `network_id` is provided — every Soroban address-credential
-    auth-entry signature with its deterministic auth payload (BASELINE.md
-    config #4: contract-heavy ledgers). Signatures from extra signers
-    miss the cache and fall back to the sync path, preserving exact
-    semantics (SURVEY.md §7 'latency vs batch'). Shared by the herder's
-    txset validation and catchup's checkpoint prevalidation (SURVEY.md
-    §3.2/§3.3 collection points)."""
-    tuples = []
+def collect_signature_tuples(frames, network_id=None, ledger_state=None,
+                             perf=None, metrics=None, checkpoint=None):
+    """(pub, sig, msg) candidates for a batch verify, by signer
+    resolution: each decorated signature is paired with EVERY
+    hint-matching ed25519 key that could be asked to verify it at
+    apply. The candidate keys of an envelope are
+
+    - the keys it names: the transaction's source, its operations'
+      sources and, for a fee bump, the fee source (outer signatures
+      over the outer hash) and the inner source and operation sources
+      (inner signatures over the inner hash);
+    - those accounts' ed25519 signers in `ledger_state` (a ledger
+      root: one `prefetch` of every named account, then cache reads —
+      one bulk read a call, never one an account); callers that pass
+      none get the envelope's and the operations' keys only;
+    - signer keys that `SetOptions` operations of `frames` add to
+      those accounts, anywhere in `frames`: a checkpoint tells the
+      resolver the signers it installs and rotates in itself.
+
+    A tuple is a fact about three byte strings, so a candidate too many
+    costs one device lane and a candidate missed is a counted miss of
+    the `PrevalidatedVerifier` that falls back to the sync path: exact
+    semantics either way (SURVEY.md §7 'latency vs batch'). With
+    `network_id`, every Soroban address-credential auth-entry signature
+    rides along with its deterministic auth payload (BASELINE.md config
+    #4). Tuples come out in the order of `frames`, no key twice for one
+    signature. Shared by the herder's txset validation,
+    the close's stage prewarm and catchup's checkpoint prevalidation
+    (SURVEY.md §3.2/§3.3 collection points).
+
+    `perf` opens the zone `crypto.collectTuples` round the collection
+    (args `checkpoint`, `n`, `frames`) and `metrics` counts
+    `crypto.collect.signatures` (decorated signatures seen) and
+    `crypto.collect.candidates` (tuples made): once a call, so the
+    per-transaction callers pass neither."""
+    if perf is None:
+        return _collect(frames, network_id, ledger_state, metrics)
+    targs = {"checkpoint": checkpoint, "frames": len(frames)} \
+        if tracing.ENABLED else None
+    with perf.zone("crypto.collectTuples", targs=targs):
+        tuples = _collect(frames, network_id, ledger_state, metrics)
+        if targs is not None:
+            targs["n"] = len(tuples)
+    return tuples
+
+
+def _ed25519_raw(muxed) -> bytes:
+    """The 32-byte account key of a MuxedAccount."""
+    return bytes(muxed.account_id().value)
+
+
+def _named_accounts(frame) -> List[bytes]:
+    """Raw keys of the accounts whose signers `frame`'s own signatures
+    may be checked against: the source and every operation source."""
+    named = [bytes(frame.source_id.value)]
+    for op in frame.tx.operations:
+        if op.sourceAccount is not None:
+            raw = _ed25519_raw(op.sourceAccount)
+            if raw not in named:
+                named.append(raw)
+    return named
+
+
+def _signer_adds(frames) -> Dict[bytes, List[bytes]]:
+    """{account raw key: ed25519 signer keys that a SetOptions operation
+    of `frames` adds to it}."""
+    from ..xdr.transaction import OperationType
+    added: Dict[bytes, List[bytes]] = {}
     for frame in frames:
-        src_raw = bytes(frame.source_id.value)  # 32-byte ed25519 key
-        h = frame.contents_hash()
-        for ds in frame.signatures:
-            if bytes(ds.hint) == src_raw[-4:]:
-                tuples.append((src_raw, bytes(ds.signature), h))
-        if network_id is not None:
+        for op in frame.tx.operations:
+            if op.body.disc != OperationType.SET_OPTIONS:
+                continue
+            signer = op.body.value.signer
+            if signer is None or not signer.weight or signer.key.disc \
+                    != SignerKeyType.SIGNER_KEY_TYPE_ED25519:
+                continue
+            acct = bytes(frame.source_id.value) \
+                if op.sourceAccount is None \
+                else _ed25519_raw(op.sourceAccount)
+            keys = added.setdefault(acct, [])
+            key = bytes(signer.key.value)
+            if key not in keys:
+                keys.append(key)
+    return added
+
+
+def _state_signers(ledger_state, accounts) -> Dict[bytes, List[bytes]]:
+    """{account raw key: its ed25519 signers in `ledger_state`}: one
+    bulk prefetch, then reads of the root's cache."""
+    from ..xdr.ledger_entries import LedgerKey
+    from ..xdr.types import PublicKey
+    kbs = {raw: LedgerKey.account(PublicKey.ed25519(raw)).to_bytes()
+           for raw in accounts}
+    ledger_state.prefetch(kbs.values())
+    out: Dict[bytes, List[bytes]] = {}
+    for raw, kb in kbs.items():
+        le = ledger_state.get_entry(kb)
+        if le is None:
+            continue
+        keys = [bytes(s.key.value) for s in le.data.value.signers
+                if s.key.disc == SignerKeyType.SIGNER_KEY_TYPE_ED25519]
+        if keys:
+            out[raw] = keys
+    return out
+
+
+def _collect(frames, network_id, ledger_state, metrics) -> list:
+    parts = []          # (signatures, hash, named accounts) per envelope
+    for frame in frames:
+        if frame.is_fee_bump():
+            parts.append((frame.signatures, frame.contents_hash(),
+                          [bytes(frame.fee_source_id.value)], None))
+            frame = frame.inner
+        parts.append((frame.signatures, frame.contents_hash(),
+                      _named_accounts(frame), frame))
+    added = _signer_adds(frames)
+    in_state = {} if ledger_state is None else _state_signers(
+        ledger_state, {a for _, _, named, _ in parts for a in named})
+    by_hint: Dict[bytes, Dict[bytes, List[bytes]]] = {}
+
+    def hints_of(acct: bytes) -> Dict[bytes, List[bytes]]:
+        """{hint: candidate keys} of one account, made once."""
+        table = by_hint.get(acct)
+        if table is None:
+            table = by_hint[acct] = {}
+            for key in (acct, *in_state.get(acct, ()),
+                        *added.get(acct, ())):
+                keys = table.setdefault(key[-4:], [])
+                if key not in keys:
+                    keys.append(key)
+        return table
+
+    tuples = []
+    seen_signatures = 0
+    for signatures, h, named, frame in parts:
+        seen_signatures += len(signatures)
+        tables = [hints_of(a) for a in named]
+        for ds in signatures:
+            hint, sig = bytes(ds.hint), bytes(ds.signature)
+            keys = tables[0].get(hint, ())
+            if len(tables) > 1:
+                keys = list(keys)
+                for t in tables[1:]:
+                    keys.extend(k for k in t.get(hint, ())
+                                if k not in keys)
+            for key in keys:
+                tuples.append((key, sig, h))
+        if network_id is not None and frame is not None:
             tuples.extend(_soroban_auth_tuples(frame, network_id))
+    if metrics is not None:
+        metrics.new_counter("crypto.collect.signatures").inc(
+            seen_signatures)
+        metrics.new_counter("crypto.collect.candidates").inc(len(tuples))
     return tuples
 
 

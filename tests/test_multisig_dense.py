@@ -1,0 +1,632 @@
+"""Signature-dense replay (ISSUE 32): signer resolution at collection,
+chunking over one warm bucket, adoption chunk by chunk, and the load
+generator's multisig mode.
+
+The reference for authorisation is `benchmark/reference/multisig_model.py`
+(plain Python over the pure-Python oracle, nothing of the program). The
+device path runs on the CPU at buckets the suite already compiles: the
+largest bucket is patched to 16 lanes."""
+
+import hashlib
+
+import pytest
+
+from stellar_core_tpu.catchup import CatchupConfiguration, CatchupWork
+from stellar_core_tpu.crypto.keys import (SecretKey, clear_verify_cache,
+                                          verify_sig_uncached)
+from stellar_core_tpu.history import make_tmpdir_archive
+from stellar_core_tpu.ledger.ledger_txn import LedgerTxn
+from stellar_core_tpu.main import Application, get_test_config
+from stellar_core_tpu.ops import chunking
+from stellar_core_tpu.simulation.load_generator import LoadGenerator
+from stellar_core_tpu.tx.signature_checker import (
+    PrevalidatedVerifier, collect_signature_tuples)
+from stellar_core_tpu.util.metrics import MetricsRegistry
+from stellar_core_tpu.util.timer import ClockMode, VirtualClock
+from stellar_core_tpu.work import State
+from stellar_core_tpu.xdr.ledger_entries import Signer
+from stellar_core_tpu.xdr.results import (TransactionResultCode,
+                                          TransactionResultPair)
+from stellar_core_tpu.xdr.types import (PublicKey, SignerKey,
+                                        SignerKeyType)
+
+from benchmark.reference import ed25519_oracle, multisig_model as model
+from test_fee_bump import bump
+from txtest_utils import (TestAccount, TestLedger, op_payment,
+                          op_set_options, sign_frame)
+
+XLM = 10_000_000
+# two seeds whose public keys end in the same four bytes (found by a
+# search over sha256(b"hint-pair-%d")): one hint, two candidate keys
+HINT_PAIR = (53547, 91290)
+
+
+def _key(tag: str) -> SecretKey:
+    return SecretKey.from_seed(hashlib.sha256(tag.encode()).digest())
+
+
+def _signer_op(key_raw: bytes, weight: int, thresholds=None):
+    low = med = high = None
+    if thresholds is not None:
+        low, med, high = thresholds
+    return op_set_options(
+        inflationDest=None, clearFlags=None, setFlags=None,
+        masterWeight=None, lowThreshold=low, medThreshold=med,
+        highThreshold=high, homeDomain=None,
+        signer=Signer(key=SignerKey(SignerKeyType.SIGNER_KEY_TYPE_ED25519,
+                                    key_raw), weight=weight))
+
+
+def _install(ledger, acct, keys, threshold):
+    """One SetOptions transaction: `keys` at weight 1 each, all three
+    thresholds `threshold`; applied to the ledger."""
+    ops = [_signer_op(k.public_key().raw, 1) for k in keys[:-1]]
+    ops.append(_signer_op(keys[-1].public_key().raw, 1,
+                          (threshold,) * 3))
+    assert acct.apply(ops)
+
+
+def _tx(acct, ops, signers, ahead=1):
+    """The transaction of `acct` that is `ahead` sequence numbers on,
+    signed by exactly `signers`."""
+    frame = acct.tx(ops, seq=acct.seq + ahead)
+    del frame.signatures[:]
+    for sk in signers:
+        sign_frame(frame, sk)
+    return frame
+
+
+def _flip(frame, i):
+    """Flip one bit of signature `i`."""
+    ds = frame.signatures[i]
+    sig = bytes(ds.signature)
+    ds.signature = bytes([sig[0] ^ 1]) + sig[1:]
+
+
+def _model_accounts(ledger, raws) -> dict:
+    out = {}
+    for raw in raws:
+        acc = ledger.account(PublicKey.ed25519(raw))
+        out[raw] = model.Account(
+            raw, master_weight=acc.thresholds[0],
+            signers=[(bytes(s.key.value), s.weight) for s in acc.signers],
+            thresholds=tuple(acc.thresholds[1:4]))
+    return out
+
+
+def _describe(frame) -> dict:
+    """`frame` in the model's plain terms."""
+    def sigs(f):
+        return [(bytes(d.hint), bytes(d.signature)) for d in f.signatures]
+    inner = frame.inner if frame.is_fee_bump() else frame
+    doc = {"hash": inner.contents_hash(), "signatures": sigs(inner),
+           "source": bytes(inner.source_id.value),
+           "ops": [(None if op.sourceAccount is None
+                    else bytes(op.sourceAccount.account_id().value),
+                    model.MEDIUM) for op in inner.tx.operations]}
+    if frame.is_fee_bump():
+        doc["outer"] = {"hash": frame.contents_hash(),
+                        "signatures": sigs(frame),
+                        "fee_source": bytes(frame.fee_source_id.value)}
+    return doc
+
+
+def _accounts_of(doc) -> set:
+    named = {doc["source"]} | {s for s, _ in doc["ops"] if s}
+    if "outer" in doc:
+        named.add(doc["outer"]["fee_source"])
+    return named
+
+
+@pytest.fixture(scope="module")
+def dense():
+    """One in-memory ledger with an account of every class, the
+    envelopes to resolve, and the frames given to the resolver."""
+    ledger = TestLedger()
+    root = ledger.root_account
+
+    def account(tag):
+        a = TestAccount(ledger, _key("acct-" + tag))
+        assert root.create(a, 1000 * XLM)
+        a.sync_seq()
+        return a
+
+    single, two, three, limit, payer, other, rotated, shared = (
+        account(t) for t in ("single", "2of3", "3of5", "limit20", "sponsor",
+                             "other", "rotated", "shared-hint"))
+    k2 = [_key(f"2of3-{i}") for i in range(2)]
+    k3 = [_key(f"3of5-{i}") for i in range(4)]
+    k20 = [_key(f"limit20-{i}") for i in range(19)]
+    kr = [_key(f"rotated-{i}") for i in range(3)]
+    kh = [SecretKey.from_seed(hashlib.sha256(
+        b"hint-pair-%d" % i).digest()) for i in HINT_PAIR]
+    assert kh[0].public_key().hint() == kh[1].public_key().hint()
+    _install(ledger, two, k2, 2)
+    _install(ledger, three, k3, 3)
+    _install(ledger, limit, k20, 20)
+    _install(ledger, rotated, kr[:2], 2)
+    _install(ledger, shared, kh, 1)
+
+    pay = [op_payment(single.muxed, XLM)]
+    stranger = _key("stranger")
+    good = {
+        "single": _tx(single, [op_payment(two.muxed, XLM)], [single.key]),
+        "2of3": _tx(two, pay, [k2[1], two.key]),
+        "3of5-bumped": bump(_tx(three, pay, [k3[3], three.key, k3[0]]),
+                            payer, 400),
+        "limit20": _tx(limit, pay, [limit.key] + k20),
+        # an operation with a source of its own: both must sign
+        "op-source": _tx(single, [op_payment(two.muxed, XLM),
+                                  op_payment(single.muxed, XLM,
+                                             source=other.muxed)],
+                         [single.key, other.key]),
+        # the second key of the hint pair: the checker tries the first
+        "shared-hint": _tx(shared, pay, [kh[1]]),
+    }
+    bad = {
+        "limit20-one-flipped": _tx(limit, pay, [limit.key] + k20),
+        "2of3-one-bad": _tx(two, pay, [two.key, k2[0]]),
+        "extra-non-signer": _tx(single, [op_payment(two.muxed, XLM)],
+                                [single.key, stranger]),
+        "bump-bad-outer": bump(_tx(three, pay, [three.key] + k3[:2]),
+                               payer, 400),
+    }
+    _flip(bad["limit20-one-flipped"], 7)
+    _flip(bad["2of3-one-bad"], 1)
+    _flip(bad["bump-bad-outer"], 0)
+    # the rotation: remove signer 1, add signer 2, then pay with the new
+    # set; the resolver sees both frames and the state BEFORE either
+    rotation = _tx(rotated, [_signer_op(kr[1].public_key().raw, 0),
+                             _signer_op(kr[2].public_key().raw, 1)],
+                   [rotated.key, kr[1]])
+    after = _tx(rotated, pay, [rotated.key, kr[2]], ahead=2)
+    stale = _tx(rotated, pay, [rotated.key, kr[1]], ahead=2)  # rotated out
+    frames = list(good.values()) + list(bad.values()) + [rotation, after,
+                                                         stale]
+    metrics = MetricsRegistry()
+    tuples = collect_signature_tuples(frames, None, ledger_state=ledger.root,
+                                      metrics=metrics)
+    return {"ledger": ledger, "good": good, "bad": bad,
+            "rotation": (rotation, after, stale), "frames": frames,
+            "tuples": tuples, "metrics": metrics}
+
+
+def _table(tuples) -> PrevalidatedVerifier:
+    pv = PrevalidatedVerifier()
+    keys = pv.expect(tuples)
+    pv.add_results(tuples, [verify_sig_uncached(*t) for t in tuples], keys)
+    return pv
+
+
+def _check(ledger, frame, pv):
+    """(check_valid's answer, its code and inner code by name)."""
+    with LedgerTxn(ledger.root) as ltx:
+        ok = frame.check_valid(ltx, verify=pv)
+    res = frame.result.result
+    code = TransactionResultCode(res.disc).name
+    inner = None
+    if code.startswith("txFEE_BUMP_INNER"):
+        inner = TransactionResultCode(res.value.result.result.disc).name
+    return ok, code, inner
+
+
+def _same_verdict(got, want):
+    """Authorised or not as the model says, and a refusal with the
+    model's codes (an authorised envelope's code is made at apply)."""
+    assert got[0] == want[0]
+    if not want[0]:
+        assert got[1:] == want[1:]
+
+
+# ------------------------------------------------- (a) signer resolution --
+
+@pytest.mark.parametrize("name", [
+    "single", "2of3", "3of5-bumped", "limit20", "op-source", "shared-hint",
+    "limit20-one-flipped", "2of3-one-bad", "extra-non-signer",
+    "bump-bad-outer"])
+def test_resolver_covers_what_the_model_can_ask(dense, name):
+    """Every tuple the reference's checks can ask for was made, the
+    sequential checker finds each of its questions in a table filled
+    from the resolver's tuples, and decides as the model does."""
+    frame = {**dense["good"], **dense["bad"]}[name]
+    doc = _describe(frame)
+    accounts = _model_accounts(dense["ledger"], _accounts_of(doc))
+    assert model.candidate_tuples(accounts, doc) <= set(dense["tuples"])
+    pv = _table(dense["tuples"])
+    ok, code, inner = _check(dense["ledger"], frame, pv)
+    assert pv.misses_unknown == 0 and pv.misses_pending == 0
+    assert pv.hits > 0
+    _same_verdict((ok, code, inner), model.envelope_verdict(accounts, doc))
+    assert ok == (name in dense["good"])
+
+
+def test_resolver_learns_a_rotated_signer_from_the_frames(dense):
+    """The new signer is in no ledger state when the tuples are
+    collected: only the rotation's SetOptions can tell the resolver."""
+    ledger = dense["ledger"]
+    rotation, after, stale = dense["rotation"]
+    pv = _table(dense["tuples"])
+    assert ledger.apply_tx(rotation), rotation.result
+    for frame, want in ((after, True), (stale, False)):
+        doc = _describe(frame)
+        accounts = _model_accounts(ledger, _accounts_of(doc))
+        assert model.candidate_tuples(accounts, doc) <= set(dense["tuples"])
+        ok, code, inner = _check(ledger, frame, pv)
+        _same_verdict((ok, code, inner), model.envelope_verdict(accounts, doc))
+        assert ok is want
+    assert pv.misses_unknown == 0
+
+
+def test_resolver_without_state_misses_only_state_signers(dense):
+    """Callers that pass no ledger state get the keys the envelopes
+    name and the signers the frames' own SetOptions add; what is left
+    is a counted unknown miss, verified by the fallback: same answer."""
+    tuples = collect_signature_tuples(dense["frames"])
+    assert set(tuples) < set(dense["tuples"])
+    pv = _table(tuples)
+    frame = dense["good"]["limit20"]
+    ok, code, _ = _check(dense["ledger"], frame, pv)
+    assert ok and code == "txSUCCESS"
+    # asked twice: at the source's low and at the payment's medium
+    assert pv.misses_unknown == 2 * 19 and pv.hits == 2
+    # a single-signer payment resolves from its envelope alone
+    pv = _table(tuples)
+    assert _check(dense["ledger"], dense["good"]["single"], pv)[0]
+    assert pv.misses_unknown == 0
+
+
+def test_resolver_counts_signatures_and_candidates(dense):
+    seen = dense["metrics"].to_json()
+    decorated = sum(len(f.signatures) + (len(f.inner.signatures)
+                                         if f.is_fee_bump() else 0)
+                    for f in dense["frames"])
+    assert seen["crypto.collect.signatures"]["count"] == decorated
+    assert seen["crypto.collect.candidates"]["count"] == \
+        len(dense["tuples"])
+    # the hint pair gives one signature two candidates; the stranger's
+    # signature matches no candidate
+    assert len(dense["tuples"]) == decorated + 1 - 1
+
+
+def test_model_matches_the_oracle_on_a_plain_signature():
+    sk = hashlib.sha256(b"model").digest()
+    pub = ed25519_oracle.secret_to_public(sk)
+    msg = hashlib.sha256(b"msg").digest()
+    sig = ed25519_oracle.sign(sk, msg)
+    acct = {pub: model.Account(pub)}
+    doc = {"hash": msg, "signatures": [(pub[-4:], sig)], "source": pub,
+           "ops": [(None, model.MEDIUM)]}
+    assert model.envelope_verdict(acct, doc) == (True, "txSUCCESS", None)
+    doc["signatures"] = [(pub[-4:], sig[:-1] + bytes([sig[-1] ^ 1]))]
+    assert model.envelope_verdict(acct, doc) == (False, "txBAD_AUTH", None)
+
+
+# ------------------------------------------------------------ (b) chunks --
+
+def _tuples(n: int, bad: set) -> tuple:
+    sk = _key("chunks")
+    pub = sk.public_key().raw
+    items, want = [], []
+    for i in range(n):
+        msg = hashlib.sha256(b"chunk-msg-%d" % i).digest()
+        sig = sk.sign(msg)
+        if i in bad:
+            sig = sig[:5] + bytes([sig[5] ^ 4]) + sig[6:]
+        items.append((pub, sig, msg))
+        want.append(ed25519_oracle.verify(pub, sig, msg))
+    return items, want
+
+
+@pytest.fixture
+def bucket16(monkeypatch):
+    monkeypatch.setattr(chunking, "MAX_BUCKET", 16)
+
+
+def test_a_batch_beyond_the_bucket_runs_as_chunks_of_it(bucket16):
+    """50 tuples at a largest bucket of 16: four calls of the one shape,
+    bit-flipped tuples on both sides of every boundary, verdicts equal
+    to the oracle's in order, at most two chunks in flight."""
+    from stellar_core_tpu.ops.verifier import TpuBatchVerifier
+    metrics = MetricsRegistry()
+    verifier = TpuBatchVerifier(metrics=metrics)
+    items, want = _tuples(50, {15, 16, 31, 32, 47, 48})
+    assert want.count(False) == 6
+    handle = verifier.verify_tuples_async(items)
+    assert isinstance(handle, chunking.ChunkedCollect)
+    landed = [(lo, hi, list(v)) for lo, hi, v in handle.chunks()]
+    assert [(lo, hi) for lo, hi, _ in landed] == \
+        [(0, 16), (16, 32), (32, 48), (48, 50)]
+    assert [v for _, _, vs in landed for v in vs] == want
+    assert handle() == want                      # the contract as before
+    assert handle.max_in_flight == 2
+    seen = metrics.to_json()
+    assert seen["crypto.verify.dispatch.chunks"]["count"] == 4
+    assert seen["crypto.verify.dispatch.batch"]["count"] == 4
+    assert seen["crypto.verify.dispatch.batch"]["sum"] == 50
+    # every chunk, the remainder too, is padded into the one bucket
+    assert seen["crypto.verify.dispatch.padding"]["sum"] == 4 * 16 - 50
+    assert seen["crypto.verify.dispatch.wall"]["count"] == 4
+    # a batch that fits the bucket is one call, as before
+    plain = verifier.verify_tuples_async(items[:16])
+    assert not hasattr(plain, "chunks") and list(plain()) == want[:16]
+    assert metrics.to_json()["crypto.verify.dispatch.chunks"]["count"] == 4
+
+
+def test_a_failed_chunk_falls_back_alone(bucket16):
+    """Under the supervisor every chunk is a dispatch of its own: the
+    one that fails is answered by the native path, the others by the
+    device, and the verdicts stay the oracle's in order."""
+    from stellar_core_tpu.ops.backend_supervisor import BackendSupervisor
+    from stellar_core_tpu.ops.verifier import TpuBatchVerifier
+
+    class FailsChunkOne(TpuBatchVerifier):
+        device_calls = 0
+
+        def verify_tuples_async(self, items, chunk=None):
+            if chunk is not None and chunk[0] == 1:
+                raise OSError("device lost under chunk 1")
+            self.device_calls += 1
+            return super().verify_tuples_async(items, chunk)
+
+    inner = FailsChunkOne(metrics=MetricsRegistry())
+    sup = BackendSupervisor(inner, dispatch_deadline_ms=60000.0)
+    try:
+        items, want = _tuples(50, {15, 16, 31, 32, 47, 48})
+        handle = sup.verify_tuples_async(items)
+        assert isinstance(handle, chunking.ChunkedCollect)
+        assert handle() == want
+        assert handle.max_in_flight == 2
+        # the chunks share the number the wrapped verifier gave the first
+        assert handle.batch == inner.last_batch_id == 1
+        status = sup.status()
+        assert status["dispatches"] == 4 and inner.device_calls == 3
+        assert status["failures"]["transient"] == 1
+        assert status["state"] == "CLOSED" and not status["quarantined"]
+    finally:
+        sup.shutdown()
+
+
+def test_chunks_yield_none_for_a_chunk_that_raises(bucket16):
+    """Without a supervisor a failed chunk is handed out as None and
+    the whole-batch call raises; the other chunks still land."""
+    def dispatch(part, chunk):
+        if chunk[0] == 1:
+            raise RuntimeError("boom")
+        return lambda: [True] * len(part)
+    handle = chunking.ChunkedCollect(None, list(range(40)), dispatch)
+    got = [(lo, hi, v) for lo, hi, v in handle.chunks()]
+    assert got == [(0, 16, [True] * 16), (16, 32, None),
+                   (32, 40, [True] * 8)]
+    with pytest.raises(RuntimeError):
+        handle()
+
+
+# ------------------------------------- (c) replay of a tiny dense archive --
+
+ACCOUNTS = 20
+
+
+def _close_to(app, seq):
+    while app.ledger_manager.get_last_closed_ledger_num() < seq:
+        app.manual_close()
+
+
+@pytest.fixture(scope="module")
+def archive(tmp_path_factory):
+    """A native-verifier publisher: 20 accounts in the four classes,
+    their signers installed in ledger 4, multisig payments in ledgers
+    5..7 of checkpoint 63 and in ledgers 64..65 of checkpoint 127."""
+    root = str(tmp_path_factory.mktemp("dense") / "archive")
+    cfg = get_test_config()
+    cfg.MAX_TX_SET_SIZE = 1000
+    cfg.TESTING_UPGRADE_MAX_TX_SET_SIZE = 1000
+    cfg.HISTORY = {"test": {
+        "get": f"cp {root}/{{0}} {{1}}",
+        "put": f"mkdir -p $(dirname {root}/{{1}}) && cp {{0}} {root}/{{1}}"}}
+    clear_verify_cache()
+    app = Application.create(VirtualClock(ClockMode.VIRTUAL_TIME), cfg)
+    app.start()
+    try:
+        app.manual_close()                       # the tx-set size upgrade
+        lg = LoadGenerator(app, seed=32)
+        assert lg.generate_accounts(ACCOUNTS) == ACCOUNTS
+        app.manual_close()
+        lg.sync_account_seqs()
+        assert lg.setup_multisig() == ACCOUNTS - 4     # 4 are `single`
+        app.manual_close()
+        for _ in range(3):
+            assert lg.generate_multisig(ACCOUNTS) == ACCOUNTS
+            app.manual_close()
+        _close_to(app, 63)
+        for _ in range(2):
+            assert lg.generate_multisig(ACCOUNTS) == ACCOUNTS
+            app.manual_close()
+        _close_to(app, 127)
+        app.ledger_manager.join_completion()
+        assert app.history_manager.published_count == 2
+        hashes = {int(seq): bytes(h) for seq, h in app.database.query_all(
+            "SELECT ledgerseq, ledgerhash FROM ledgerheaders")}
+        yield {"archive": make_tmpdir_archive("test", root),
+               "passphrase": cfg.NETWORK_PASSPHRASE, "hashes": hashes,
+               "failed": lg.failed}
+    finally:
+        app.shutdown()
+
+
+class _Recording:
+    """Pass-through that keeps every tuple the device was given and
+    hands the chunks on."""
+
+    def __init__(self, inner, settle=False):
+        self._inner = inner
+        self._settle = settle
+        self.dispatched = []
+
+    def __getattr__(self, name):
+        return getattr(self._inner, name)
+
+    def verify_tuples_async(self, items):
+        self.dispatched.extend(items)
+        handle = self._inner.verify_tuples_async(items)
+        if not self._settle:
+            return handle
+        verdicts = handle()     # landed, all of it, before apply looks
+        return lambda: verdicts
+
+
+def _replaying_node(passphrase):
+    cfg = get_test_config()
+    cfg.NETWORK_PASSPHRASE = passphrase
+    cfg.SIGNATURE_VERIFY_BACKEND = "tpu"
+    cfg.VERIFY_DISPATCH_DEADLINE_MS = 60000.0
+    clear_verify_cache()
+    app = Application.create(VirtualClock(ClockMode.VIRTUAL_TIME), cfg)
+    app.start()
+    return app
+
+
+def _catch_up(app, archive, to_ledger, wait_for_device, settle=False):
+    verifier = _Recording(app.batch_verifier, settle)
+    work = CatchupWork(app, archive,
+                       CatchupConfiguration(to_ledger=to_ledger),
+                       batch_verifier=verifier, batch_grace=60.0)
+    app.work_scheduler.schedule(work)
+    clock = app.clock
+    waited = set()
+    while not work.is_done():
+        if wait_for_device:
+            # hold apply back until every chunk has landed, so that
+            # what the table cannot answer is the resolver's fault
+            for cp in work.applied_checkpoints:
+                if cp._pending_batch is not None and id(cp) not in waited:
+                    assert cp._pending_batch[2].wait(300)
+                    waited.add(id(cp))
+        if clock.crank(False) == 0:
+            clock.crank(True)
+    work.drain(300.0)
+    return work, verifier
+
+
+def _table_counts(work) -> tuple:
+    tables = [cp.prevalidated for cp in work.applied_checkpoints]
+    return (sum(t.hits for t in tables),
+            sum(t.misses_pending for t in tables),
+            sum(t.misses_unknown for t in tables))
+
+
+def test_tiny_dense_archive_replays_through_chunks(archive, bucket16):
+    """Checkpoint 63 into a fresh node: the header chain is the native
+    publisher's, every tuple went to the device exactly once, every
+    chunk was adopted and the table answered every check apply made
+    (nothing unknown: the resolver made every tuple from the envelopes
+    and the checkpoint's own SetOptions). Then checkpoint 127 over the
+    node's own state: its signers come from the ledger."""
+    assert archive["failed"] == 0
+    app = _replaying_node(archive["passphrase"])
+    try:
+        work, verifier = _catch_up(app, archive["archive"], 63, True)
+        assert work.get_state() == State.WORK_SUCCESS
+        lm = app.ledger_manager
+        assert lm.get_last_closed_ledger_num() == 63
+        assert lm.get_last_closed_ledger_hash() == archive["hashes"][63]
+        seen = app.metrics.to_json()
+        n = len(verifier.dispatched)
+        assert n > 3 * 16 and len(set(verifier.dispatched)) == n
+        assert seen["crypto.collect.candidates"]["count"] == n
+        assert seen["crypto.verify.dispatch.batch"]["sum"] == n
+        chunks = -(-n // 16)
+        assert seen["crypto.verify.dispatch.chunks"]["count"] == chunks
+        assert seen["catchup.batch.adoptLag"]["count"] == chunks
+        hits, pending, unknown = _table_counts(work)
+        assert unknown == 0 and pending == 0 and hits > n // 2
+        assert seen["crypto.prevalidated.miss.unknown"]["count"] == 0
+        assert seen["crypto.prevalidated.hit"]["count"] == hits
+        status = app.batch_verifier.status()
+        assert status["state"] == "CLOSED" and not status["quarantined"]
+        assert not any(status["failures"].values())
+        # staged closes under the table prewarm nothing: the device saw
+        # the checkpoint's chunks and not one flush of the verify service
+        zones = app.perf.report()
+        assert zones["ledger.close.applyTx.stage"]["count"] > 0
+        assert "crypto.verifyService.flush" not in zones
+        assert status["dispatches"] == chunks
+
+        # the second checkpoint: no SetOptions in it, a state with signers
+        before = seen["crypto.collect.candidates"]["count"]
+        # (its payments are in its first ledger, which applies in the
+        # crank that dispatches: the batch is settled at dispatch)
+        work, verifier = _catch_up(app, archive["archive"], 0, False, True)
+        assert work.get_state() == State.WORK_SUCCESS
+        assert lm.get_last_closed_ledger_num() == 127
+        assert lm.get_last_closed_ledger_hash() == archive["hashes"][127]
+        hits, pending, unknown = _table_counts(work)
+        assert unknown == 0 and pending == 0
+        # 2 ledgers x (4 + 6*2 + 4*4 + 6*20) signatures
+        assert hits >= 2 * 152
+        assert app.metrics.to_json()[
+            "crypto.collect.candidates"]["count"] - before == \
+            len(verifier.dispatched) >= 2 * 152
+    finally:
+        app.shutdown()
+
+
+def test_apply_never_waits_for_a_chunk(archive, bucket16):
+    """The same replay with apply left to run ahead of the device:
+    the chain is the same, and what the table could not answer yet is
+    counted as pending, never as unknown."""
+    app = _replaying_node(archive["passphrase"])
+    try:
+        work, verifier = _catch_up(app, archive["archive"], 63, False)
+        assert work.get_state() == State.WORK_SUCCESS
+        assert app.ledger_manager.get_last_closed_ledger_hash() == \
+            archive["hashes"][63]
+        hits, pending, unknown = _table_counts(work)
+        assert unknown == 0 and hits + pending > 0
+        seen = app.metrics.to_json()
+        assert seen["crypto.prevalidated.miss"]["count"] == pending
+        assert seen["crypto.prevalidated.miss.pending"]["count"] == pending
+        assert len(set(verifier.dispatched)) == len(verifier.dispatched)
+    finally:
+        app.shutdown()
+
+
+# ----------------------------------------- the load generator's new mode --
+
+def test_standalone_node_closes_a_ledger_of_multisig_load():
+    """`generateload` modes multisig_setup and multisig on a live
+    standalone node: a ledger of m-of-n, fee-bumped and 20-signature
+    payments closes with every transaction a success."""
+    cfg = get_test_config()
+    cfg.MAX_TX_SET_SIZE = 1000
+    cfg.TESTING_UPGRADE_MAX_TX_SET_SIZE = 1000
+    app = Application.create(VirtualClock(ClockMode.VIRTUAL_TIME), cfg)
+    app.start()
+    try:
+        app.manual_close()
+        handle = app.command_handler.handle
+        out = handle("generateload", {"mode": "create", "accounts": "10"})
+        assert out["submitted"] == 10
+        app.manual_close()
+        assert "exception" in handle("generateload", {"mode": "multisig"})
+        out = handle("generateload", {"mode": "multisig_setup"})
+        assert out["status"] == "ok" and out["submitted"] == 8
+        app.manual_close()
+        out = handle("generateload", {"mode": "multisig", "txs": "10"})
+        assert out["status"] == "ok" and out["submitted"] == 10
+        app.manual_close()
+        seq = app.ledger_manager.get_last_closed_ledger_num()
+        row = app.database.query_one(
+            "SELECT COUNT(*) FROM txhistory WHERE ledgerseq=?", (seq,))
+        assert row[0] == 10
+        codes = set()
+        for (blob,) in app.database.query_all(
+                "SELECT txresult FROM txhistory WHERE ledgerseq=?", (seq,)):
+            pair = TransactionResultPair.from_bytes(bytes(blob))
+            codes.add(TransactionResultCode(pair.result.result.disc).name)
+        assert codes == {"txSUCCESS", "txFEE_BUMP_INNER_SUCCESS"}
+        lg = app.command_handler._load_generator
+        classes = [len(keys) for keys, _, _ in lg._multisig.values()]
+        assert sorted(classes) == [1, 1, 3, 3, 3, 5, 5, 20, 20, 20]
+        assert lg.failed == 0
+    finally:
+        app.shutdown()

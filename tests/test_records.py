@@ -224,6 +224,39 @@ def test_barrier_counters_are_published_and_documented(name, program_names):
             assert f"`{name}`" in fh.read(), f"{doc} does not name {name}"
 
 
+# what ISSUE 32 publishes about signer resolution, chunks and their
+# adoption: the readers `*.dense.py` take some (checked above, reader by
+# reader); all of them are opened by the program and in the doc
+@pytest.mark.parametrize("name", [
+    "crypto.collectTuples", "crypto.collect.candidates",
+    "crypto.collect.signatures", "crypto.verify.dispatch.chunks",
+    "crypto.prevalidated.miss.pending", "crypto.prevalidated.miss.unknown",
+    "catchup.batch.adoptLag"])
+def test_dense_replay_names_are_published_and_documented(name,
+                                                         program_names):
+    assert name in program_names, (
+        f"stellar_core_tpu/ opens no zone or counter {name!r}")
+    with open(os.path.join(ROOT, "docs/OBSERVABILITY.md"),
+              encoding="utf-8") as fh:
+        assert f"`{name}`" in fh.read()
+
+
+def test_dense_readers_that_borrow_a_reading_name_a_reader_that_exists():
+    """A `*.dense.py` reader that makes its reading with another
+    reader's code names that reader's file."""
+    borrowed = 0
+    for reader in _reader_files():
+        if not reader.endswith(".dense.py"):
+            continue
+        for node in ast.walk(_parse(os.path.join(READERS, reader))):
+            if isinstance(node, ast.Call) \
+                    and _dotted(node.func) == "cell.spec.layer_reader":
+                assert os.path.exists(os.path.join(
+                    READERS, _string(node.args[0]) + ".py")), reader
+                borrowed += 1
+    assert borrowed == 13
+
+
 # ------------------------------------------------------------ documents --
 
 CITED = re.compile(r"`([^`\s]+)`")
