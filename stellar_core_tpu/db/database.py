@@ -186,17 +186,20 @@ class SchemaMixin:
         self._close_barriers.append(fn)
 
     def _completion_barrier(self, sql: str) -> None:
-        barriers = self._close_barriers
-        if not barriers:
-            return
-        if not any(t in sql for t in _CLOSE_COMPLETION_TABLES):
-            return
+        if self._close_barriers and \
+                any(t in sql for t in _CLOSE_COMPLETION_TABLES):
+            self.join_close_barriers()
+
+    def join_close_barriers(self) -> None:
+        """Join the close-completion tail as a reader of what it writes:
+        before any statement on its tables, and before a read of its
+        LAST_CLOSE_COMPLETED marker (PersistentState.get)."""
         # a thread already inside its own transaction must not block on
         # the worker (which may need this connection's lock): callers
         # that read completion tables transactionally join beforehand
         if self._tx_owner is threading.current_thread():
             return
-        for fn in barriers:
+        for fn in self._close_barriers or ():
             fn()
 
     def tail_transaction(self):

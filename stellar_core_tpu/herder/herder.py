@@ -406,14 +406,23 @@ class Herder:
                 closeTime=close_time,
                 upgrades=[u.to_bytes() for u in upgrade_steps],
                 ext=_StellarValueExt(StellarValueType.STELLAR_VALUE_BASIC))
+            # returns when the ledger is committed, as the SCP-driven
+            # path does: its completion tail is queued on the worker and
+            # runs beside the admission of the next ledger. The next
+            # close joins it before `seal`; whoever reads what the tail
+            # writes joins through `join_completion` below
             self.externalize_value(next_seq, value, applicable)
-            # manual/standalone close is a synchronous contract: the
-            # caller (admin `manualclose`, tests) reads close artifacts
-            # the moment this returns, so join the deferred completion
-            # tail. The SCP-driven path keeps the pipeline — the next
-            # close's own barrier gates it instead.
-            with self.perf.zone("herder.joinCompletion", targs=targs):
-                self.ledger_manager.join_completion()
+
+    def join_completion(self) -> None:
+        """The readers' join of the close-completion tail: history rows
+        and marker, close meta, the tx-status feed, a checkpoint's
+        publish (docs/CLOSE_PIPELINE.md, "A manual close is no
+        reader"). Re-raises a failed tail, as the next close's barrier
+        does."""
+        targs = {"seq": self.ledger_manager.get_last_closed_ledger_num()} \
+            if tracing.ENABLED else None
+        with self.perf.zone("herder.joinCompletion", targs=targs):
+            self.ledger_manager.join_completion()
 
     def _propose_upgrades(self, lcl_header, close_time: int):
         """Vote upgrades against current ledger state (the Soroban

@@ -64,7 +64,7 @@ class HistoryManager:
         # the completion worker or a publish timer; serialize drains
         self._publish_lock = threading.Lock()
         self._publish_timers: List[object] = []
-        self.published_count = 0
+        self._published = 0
         # durable queue (reference: the publishqueue table) — a crash
         # between queue and publish must not lose the checkpoint, and
         # the re-queued publish must record the queue-time HAS
@@ -122,8 +122,17 @@ class HistoryManager:
     def has_any_writable_archive(self) -> bool:
         return any(a.has_put() for a in self.archives)
 
+    # A checkpoint's publish rides its ledger's completion tail: whoever
+    # asks what has been published joins the tail first (a no-op from
+    # the tail itself, and when nothing is in flight)
     def publish_queue_length(self) -> int:
+        self.app.herder.join_completion()
         return len(self._publish_queue)
+
+    @property
+    def published_count(self) -> int:
+        self.app.herder.join_completion()
+        return self._published
 
     def publish_delay(self) -> float:
         return self.app.config.PUBLISH_TO_ARCHIVE_DELAY
@@ -186,7 +195,7 @@ class HistoryManager:
                     db.execute(
                         "DELETE FROM publishqueue WHERE ledgerseq=?",
                         (item.seq,))
-                self.published_count += 1
+                self._published += 1
                 n += 1
         if on_done is not None and n:
             on_done(True)

@@ -271,6 +271,7 @@ class LedgerManager:
         with dbtx:
             self._store_header(self.root.get_header())
             self._persist_local_has(self.root.get_header())
+            self._persist_lcl_hash()
             if self.persistent_state is not None:
                 from ..main.persistent_state import StateEntry
                 self.persistent_state.set(
@@ -368,6 +369,17 @@ class LedgerManager:
         self.persistent_state.set(
             StateEntry.HISTORY_ARCHIVE_STATE, has.to_json())
 
+    def _persist_lcl_hash(self) -> None:
+        """reference: kLastClosedLedger, stored in closeLedger's commit.
+        Inside the close's transaction, never after it: a write of the
+        crank thread between two closes would wait out the previous
+        ledger's tail transaction on the other connection."""
+        if self.persistent_state is None:
+            return
+        from ..main.persistent_state import StateEntry
+        self.persistent_state.set(
+            StateEntry.LAST_CLOSED_LEDGER, self._lcl_hash.hex())
+
     def _assume_bucket_state(self, header) -> bool:
         """Rebuild the bucket list from the persisted HAS + shared
         bucket dir (reference: BucketManager::assumeState, SURVEY §3.4)."""
@@ -441,6 +453,10 @@ class LedgerManager:
         every already-closed ledger's tx-history/meta/publish tail has
         run (and surfaces the first completion failure)."""
         self._completion.join(reraise=reraise)
+
+    def completion_pending(self) -> bool:
+        """Whether a closed ledger's completion tail has yet to run."""
+        return self._completion.pending() > 0
 
     def discard_pending_completion(self) -> None:
         """Simulated process kill (Simulation.crash_node): drop the
@@ -594,6 +610,7 @@ class LedgerManager:
                         self._lcl_hash = ledger_header_hash(closed)
                         self._store_header(closed)
                         self._persist_local_has(closed)
+                        self._persist_lcl_hash()
             # the checkpoint's durable publishqueue row rides the close
             # transaction (HAS snapshotted at queue time, see
             # HistoryManager.snapshot_checkpoint): a crash on either

@@ -459,9 +459,6 @@ class Application:
                     if self.config.USE_CONFIG_FOR_GENESIS else 0
                 self.ledger_manager.start_new_ledger(
                     self.config.network_id(), genesis_protocol)
-                self.persistent_state.set(
-                    StateEntry.LAST_CLOSED_LEDGER,
-                    self.ledger_manager.get_last_closed_ledger_hash().hex())
             # boot snapshot: the read tier answers from the LCL before the
             # first close of this process ever lands
             self.snapshots.on_ledger_closed(
@@ -533,9 +530,6 @@ class Application:
         if not self.config.MANUAL_CLOSE:
             raise RuntimeError("manualclose requires MANUAL_CLOSE=true")
         self.herder.trigger_next_ledger()
-        self.persistent_state.set(
-            StateEntry.LAST_CLOSED_LEDGER,
-            self.ledger_manager.get_last_closed_ledger_hash().hex())
 
     def crank(self, block: bool = False) -> int:
         n = self.clock.crank(block)

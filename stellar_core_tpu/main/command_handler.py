@@ -310,6 +310,8 @@ class CommandHandler:
         return out
 
     def _manual_close(self, params) -> dict:
+        # answers when the ledger is committed; its completion tail
+        # (history rows, meta, publish) is joined by whoever reads it
         self.app.manual_close()
         return {"status": "Manually triggered a ledger close with sequence "
                           f"number {self.app.ledger_manager.get_last_closed_ledger_num()}"}
@@ -605,6 +607,8 @@ class CommandHandler:
         """snapshotinfo — the read tier's serving state: newest
         snapshot seq, open snapshot count, pool/shed/hedge tallies."""
         snaps = self.app.snapshots.stats()
+        # `tx_status_entries` counts what the completion tail has fed
+        self.app.herder.join_completion()
         return {"snapshot": snaps,
                 "pinned_buckets":
                     len(self.app.snapshots.pinned_bucket_hashes()),

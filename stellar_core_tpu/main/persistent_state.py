@@ -31,6 +31,10 @@ class PersistentState:
         self._db = db
 
     def get(self, entry: StateEntry, suffix: str = "") -> Optional[str]:
+        if entry is StateEntry.LAST_CLOSE_COMPLETED:
+            # the close-completion tail writes it: its readers join the
+            # tail, as those of the history tables do
+            self._db.join_close_barriers()
         row = self._db.query_one(
             "SELECT state FROM storestate WHERE statename = ?",
             (entry.value + suffix,))

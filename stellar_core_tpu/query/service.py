@@ -311,6 +311,13 @@ class QueryService:
     def _perform(self, req: _Request) -> dict:
         if req.kind == "txstatus":
             rec = self._tx_status.lookup(req.payload)
+            if rec is None and \
+                    self._app.ledger_manager.completion_pending():
+                # the feed rides the close's completion tail: a status
+                # asked for a just-committed ledger joins it, and only
+                # then is a miss a miss
+                self._app.herder.join_completion()
+                rec = self._tx_status.lookup(req.payload)
             if rec is None:
                 return {"found": False, "ledger_seq": None}
             result_xdr, seq = rec

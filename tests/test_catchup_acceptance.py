@@ -68,6 +68,7 @@ def _publish_with_upgrade(tmp_path, n_ledgers=130):
     hdr = app.ledger_manager.get_last_closed_ledger_header()
     assert hdr.ledgerVersion == END_PROTO, \
         "publisher never crossed the protocol upgrade"
+    app.herder.join_completion()    # who reads the archive joins the tail
     return app, make_tmpdir_archive("test", archive_root), archive_root
 
 

@@ -639,6 +639,86 @@ def test_crash_in_next_close_beside_previous_tail(tmp_path, matrix_control,
         app2.shutdown()
 
 
+@pytest.mark.parametrize("died", ["ledger.close.crash.queued",
+                                  "tail pending", "tail at its marker"])
+def test_crash_between_manual_close_commit_and_tail_is_reported(
+        tmp_path, caplog, died):
+    """A manual close returns at the commit (PR 34), so the process can
+    die with ledger 4 durable and its tail not run: before the tail was
+    queued (the crash point after COMMIT), while it waits on the worker,
+    or inside it before the marker. The restart gap-check finds
+    LAST_CLOSE_COMPLETED behind the LCL, says so, drops the partial
+    tail, heals the marker, and the node keeps closing."""
+    import logging
+    import threading
+    from stellar_core_tpu.main.persistent_state import StateEntry
+
+    def manual_close(app, seq):
+        assert app.herder.recv_transaction(_scheduled_tx(app, seq)).name \
+            == "ADD_STATUS_PENDING"
+        app.manual_close()
+
+    app = Application.create(VirtualClock(ClockMode.VIRTUAL_TIME),
+                             _matrix_cfg(tmp_path))
+    app.start()
+    lm = app.ledger_manager
+    manual_close(app, 2)
+    manual_close(app, 3)
+    app.herder.join_completion()
+    hold = threading.Event()
+    if died == "tail pending":
+        # the worker is busy from ledger 4's COMMIT on: its tail queues
+        lm.closed_hooks.append(
+            lambda header, _hash: lm._completion.submit(
+                header.ledgerSeq, hold.wait))
+    point = died if died.startswith("ledger.") \
+        else "ledger.close.crash.complete.meta"
+    chaos.install(ChaosEngine(8, [FaultSpec(point, "crash")]))
+    try:
+        if died == "ledger.close.crash.queued":
+            with pytest.raises(SimulatedCrash):
+                manual_close(app, _CRASH_AT)
+        else:
+            manual_close(app, _CRASH_AT)    # returns: ledger 4 committed
+            if died == "tail at its marker":
+                with pytest.raises(RuntimeError) as failure:
+                    app.herder.join_completion()
+                assert isinstance(failure.value.__cause__, SimulatedCrash)
+    finally:
+        chaos.uninstall()
+    assert lm.get_last_closed_ledger_num() == _CRASH_AT
+    lcl_hash = lm.get_last_closed_ledger_hash()
+    lm.discard_pending_completion()     # as Simulation.crash_node does
+    hold.set()
+    lm.join_completion(reraise=False)
+    assert app.database._conn.execute(
+        "SELECT state FROM storestate WHERE statename=?",
+        (StateEntry.LAST_CLOSE_COMPLETED.value,)).fetchone()[0] \
+        == str(_CRASH_AT - 1)
+
+    with caplog.at_level(logging.WARNING):
+        app2 = Application.create(VirtualClock(ClockMode.VIRTUAL_TIME),
+                                  _matrix_cfg(tmp_path))
+        app2.start()
+    try:
+        assert any(f"crash mid-completion: ledgers {_CRASH_AT}..{_CRASH_AT}"
+                   in r.getMessage() for r in caplog.records)
+        lm2 = app2.ledger_manager
+        assert lm2.get_last_closed_ledger_num() == _CRASH_AT
+        assert lm2.get_last_closed_ledger_hash() == lcl_hash
+        assert int(app2.persistent_state.get(
+            StateEntry.LAST_CLOSE_COMPLETED)) == _CRASH_AT
+        assert app2.database.query_one(
+            "SELECT COUNT(*) FROM txhistory WHERE ledgerseq=?",
+            (_CRASH_AT,))[0] == 0
+        manual_close(app2, _CRASH_AT + 1)
+        assert app2.database.query_one(
+            "SELECT COUNT(*) FROM txhistory WHERE ledgerseq=?",
+            (_CRASH_AT + 1,))[0] == 1
+    finally:
+        app2.shutdown()
+
+
 @pytest.mark.parametrize("crash_point", ["ledger.close.crash.commit",
                                          "ledger.close.crash.queued"])
 def test_publish_queue_survives_crash_after_queueing(tmp_path,

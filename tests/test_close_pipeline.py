@@ -569,25 +569,36 @@ def test_crash_mid_completion_restart(tmp_path):
         app2.shutdown()
 
 
-def test_manual_close_hides_every_tail(tmp_path):
-    """`manual_close` joins the tail before it returns, so the barrier
-    before `seal` never waits on a standalone node; closes that follow
-    each other directly make it wait, and say so."""
+def _tail_counts(app):
+    j = app.metrics.to_json()
+    return tuple(j[n]["count"] for n in (
+        "ledger.close.tail.hidden", "ledger.close.tail.waited",
+        "database.tail.busy"))
+
+
+def test_manual_closes_run_their_tails_beside_the_next_submission(tmp_path):
+    """`manual_close` returns at the commit (until PR 34 it joined its
+    own tail, so the barrier before `seal` found an empty queue at every
+    close). Twenty closes with submissions between them: no close opens
+    `herder.joinCompletion`, the barrier counts each close once, the
+    tail's transaction never finds the file locked (the close writes
+    LAST_CLOSED_LEDGER inside its own transaction, not after it), and a
+    reader of the tables finds every row. Closes that follow each other
+    directly make the barrier wait, and say so."""
     with Application.create(VirtualClock(ClockMode.VIRTUAL_TIME),
                             _file_cfg(tmp_path)) as app:
         app.start()
         master = m1.master_account(app)
-        for _ in range(3):
-            m1.submit(app, master.tx([op_payment(master.muxed, 1)]))
+        for _ in range(20):
+            for _ in range(3):
+                m1.submit(app, master.tx([op_payment(master.muxed, 1)]))
             app.manual_close()
-
-        def counts():
-            j = app.metrics.to_json()
-            return tuple(j[n]["count"] for n in (
-                "ledger.close.tail.hidden", "ledger.close.tail.waited",
-                "database.tail.busy"))
-
-        assert counts() == (3, 0, 0)
+        hidden, waited, busy = _tail_counts(app)
+        assert hidden + waited == 20 and busy == 0
+        assert "herder.joinCompletion" not in app.perf.report()
+        assert app.database.query_one(
+            "SELECT COUNT(*), COUNT(DISTINCT ledgerseq) FROM txhistory") \
+            == (60, 20)
         lm = app.ledger_manager
         gate = _GatedTail(lm, lm.get_last_closed_ledger_num() + 1)
         first = lm.get_last_closed_ledger_num() + 1
@@ -603,9 +614,234 @@ def test_manual_close_hides_every_tail(tmp_path):
                 closeTime=lcl.scpValue.closeTime + 1)))
         opener.join()
         lm.join_completion()
-        assert counts() == (4, 1, 0)
+        assert _tail_counts(app) == (hidden + 1, waited + 1, 0)
         zone = app.perf.report()["ledger.close.completeWait"]
-        assert zone["count"] == 5 and zone["total_ms"] >= 100
+        assert zone["count"] == 22 and zone["total_ms"] >= 100
+
+
+# ----------------- a manual close returns at the commit; readers join --
+
+class _HeldWorker:
+    """Holds the completion worker at the head of every tail, before
+    any of it has run, until `release()` (or `release_after`)."""
+
+    def __init__(self, lm):
+        self._gate = threading.Event()
+        complete = lm._complete_close
+
+        def held(*a, **kw):
+            assert self._gate.wait(10)
+            complete(*a, **kw)
+        lm._complete_close = held
+
+    def release(self):
+        self._gate.set()
+
+    def release_after(self, seconds=0.15):
+        """From another thread, while the caller is inside a reader's
+        join: the reader can return only through the join."""
+        threading.Timer(seconds, self._gate.set).start()
+
+
+def _standalone(tmp_path, **settings):
+    cfg = _file_cfg(tmp_path)
+    for key, value in settings.items():
+        setattr(cfg, key, value)
+    app = Application.create(VirtualClock(ClockMode.VIRTUAL_TIME), cfg)
+    app.start()
+    return app
+
+
+def _submit_payment(app):
+    master = m1.master_account(app)
+    frame = master.tx([op_payment(master.muxed, 1)])
+    assert m1.submit(app, frame)["status"] == "PENDING"
+    return frame
+
+
+@pytest.mark.parametrize("how", ["in-process", "route"])
+def test_manual_close_returns_at_the_commit_with_its_tail_pending(
+        tmp_path, how):
+    """Header, entries, bucket list, local HAS and LAST_CLOSED_LEDGER
+    are committed when the call (or the admin route, which does the
+    same) returns; of the tail nothing has run, and nothing joined it."""
+    from stellar_core_tpu.main.persistent_state import StateEntry
+    app = _standalone(tmp_path)
+    try:
+        lm, db = app.ledger_manager, app.database
+        held = _HeldWorker(lm)
+        frame = _submit_payment(app)
+        if how == "route":
+            out = app.command_handler.handle("manualclose")
+            assert "sequence number 2" in out["status"], out
+        else:
+            app.manual_close()
+        assert lm.get_last_closed_ledger_num() == 2
+        assert lm.completion_pending()
+        assert "herder.joinCompletion" not in app.perf.report()
+        # past the facade's barrier, on the raw connection
+        raw = db._conn.execute
+        assert raw("SELECT COUNT(*) FROM ledgerheaders "
+                   "WHERE ledgerseq=2").fetchone()[0] == 1
+        assert raw("SELECT state FROM storestate WHERE statename=?",
+                   (StateEntry.LAST_CLOSED_LEDGER.value,)).fetchone()[0] \
+            == lm.get_last_closed_ledger_hash().hex()
+        assert raw("SELECT state FROM storestate WHERE statename=?",
+                   (StateEntry.LAST_CLOSE_COMPLETED.value,)
+                   ).fetchone()[0] == "1"
+        assert raw("SELECT COUNT(*) FROM txhistory").fetchone()[0] == 0
+        assert app.tx_status.lookup(frame.full_hash()) is None
+        # the committed state serves the next admission beside the tail
+        _submit_payment(app)
+        held.release()
+        app.herder.join_completion()
+        assert not lm.completion_pending()
+        assert app.perf.report()["herder.joinCompletion"]["count"] == 1
+        assert raw("SELECT COUNT(*) FROM txhistory").fetchone()[0] == 1
+    finally:
+        app.shutdown()
+
+
+def _read_table(table):
+    def read(app, frame, seq):
+        return app.database.query_one(
+            f"SELECT COUNT(*) FROM {table} WHERE ledgerseq=?", (seq,))[0]
+    return read
+
+
+def _read_marker(app, frame, seq):
+    from stellar_core_tpu.main.persistent_state import StateEntry
+    return int(app.persistent_state.get(
+        StateEntry.LAST_CLOSE_COMPLETED)) == seq
+
+
+def _read_meta_stream(app, frame, seq):
+    app.herder.join_completion()        # the stream's consumer joins
+    return [m.value.ledgerHeader.header.ledgerSeq
+            for m in app.test_metas] == [seq]
+
+
+def _read_debug_segment(app, frame, seq):
+    from test_debug_meta_segment import SEG63, records_of
+    app.herder.join_completion()        # who lists the segments joins
+    return len(records_of(
+        os.path.join(app.ledger_manager.meta_debug_dir, SEG63)))
+
+
+def _read_tx_status(app, frame, seq):
+    out = app.command_handler.handle(
+        "txstatus", {"hash": frame.full_hash().hex(),
+                     "deadline_ms": "5000"})
+    return out["found"] and out["ledger_seq"] == seq
+
+
+def _read_snapshot_info(app, frame, seq):
+    return app.command_handler.handle("snapshotinfo")["tx_status_entries"]
+
+
+def _read_maintenance(app, frame, seq):
+    # deletes history below the LCL's floor: through the tables' barrier
+    app.maintainer.perform_maintenance(10)
+    return app.database._conn.execute(
+        "SELECT COUNT(*) FROM txhistory WHERE ledgerseq=?",
+        (seq,)).fetchone()[0]
+
+
+@pytest.mark.parametrize("reader", [
+    pytest.param(_read_table("txhistory"), id="txhistory"),
+    pytest.param(_read_table("txsethistory"), id="txsethistory"),
+    pytest.param(_read_table("txfeehistory"), id="txfeehistory"),
+    pytest.param(_read_marker, id="marker"),
+    pytest.param(_read_meta_stream, id="meta-stream"),
+    pytest.param(_read_debug_segment, id="debug-segment"),
+    pytest.param(_read_tx_status, id="txstatus-route"),
+    pytest.param(_read_snapshot_info, id="snapshotinfo-route"),
+    pytest.param(_read_maintenance, id="maintenance"),
+])
+def test_reader_of_a_pending_tail_sees_it_finished(tmp_path, reader):
+    """Each reader of what the tail writes, asked while the worker is
+    held before ledger 2's tail: it returns only once the tail has run,
+    and sees what a synchronous close would have shown it."""
+    app = _standalone(tmp_path, METADATA_DEBUG_LEDGERS=64)
+    try:
+        lm = app.ledger_manager
+        app.test_metas = []
+        lm.meta_stream = app.test_metas.append
+        held = _HeldWorker(lm)
+        frame = _submit_payment(app)
+        app.manual_close()
+        assert lm.completion_pending()
+        held.release_after()
+        t0 = time.monotonic()
+        assert reader(app, frame, 2) == 1
+        assert time.monotonic() - t0 >= 0.1     # it stood at the join
+        assert not lm.completion_pending()
+    finally:
+        app.shutdown()
+
+
+def test_reader_of_a_pending_publish_sees_the_checkpoint(tmp_path):
+    """A checkpoint's publish rides ledger 63's tail: `manual_close`
+    returns before it, and who asks the history manager what has been
+    published (or what is still queued) joins first."""
+    cfg, root = _archive_cfg(tmp_path)
+    with Application.create(VirtualClock(ClockMode.VIRTUAL_TIME),
+                            cfg) as app:
+        app.start()
+        lm, hm = app.ledger_manager, app.history_manager
+        while lm.get_last_closed_ledger_num() < 62:
+            app.manual_close()
+        app.herder.join_completion()
+        held = _HeldWorker(lm)
+        app.manual_close()
+        assert lm.get_last_closed_ledger_num() == 63
+        assert lm.completion_pending() and hm._published == 0
+        assert not os.path.exists(
+            os.path.join(root, ".well-known/stellar-history.json"))
+        held.release_after()
+        assert hm.published_count == 1
+        assert hm.publish_queue_length() == 0
+        with open(os.path.join(
+                root, ".well-known/stellar-history.json")) as f:
+            assert json.load(f)["currentLedger"] == 63
+
+
+@pytest.mark.parametrize("how", ["in-process", "route"])
+def test_failed_tail_raises_at_the_next_manual_close(tmp_path, how):
+    """The close whose tail fails has returned; the failure is sticky
+    and halts the next `manual_close` at its barrier with nothing of
+    that ledger written, as it halts an SCP-driven close."""
+    app = _standalone(tmp_path)
+    try:
+        lm, db = app.ledger_manager, app.database
+        store = lm._store_tx_history
+
+        def failing_store(seq, *a):
+            if seq == 2:
+                raise OSError("disk gone")
+            store(seq, *a)
+        lm._store_tx_history = failing_store
+        _submit_payment(app)
+        app.manual_close()                  # ledger 2: returns
+        assert lm.get_last_closed_ledger_num() == 2
+        _submit_payment(app)
+        accounts = db.query_one("SELECT COUNT(*) FROM accounts")[0]
+        if how == "route":
+            out = app.command_handler.handle("manualclose")
+            assert "ledger 2" in out["exception"], out
+        else:
+            with pytest.raises(RuntimeError, match="ledger 2"):
+                app.manual_close()
+        assert lm.get_last_closed_ledger_num() == 2
+        assert db.query_one(
+            "SELECT MAX(ledgerseq) FROM ledgerheaders")[0] == 2
+        assert db.query_one("SELECT COUNT(*) FROM accounts")[0] == accounts
+        assert db._conn.execute(
+            "SELECT COUNT(*) FROM txhistory").fetchone()[0] == 0
+        with pytest.raises(RuntimeError, match="ledger 2"):
+            app.herder.join_completion()    # and every reader's join
+    finally:
+        app.shutdown()
 
 
 # ------------------------------------------------ HAS snapshot at queue --

@@ -672,8 +672,10 @@ def test_precaution_delay_meta(tmp_path):
         app.start()
         app.manual_close()          # ledger 2: held back
         import io
+        app.herder.join_completion()    # the stream's consumer joins
         assert path.read_bytes() == b""
         app.manual_close()          # ledger 3 closes; ledger 2 emits
+        app.herder.join_completion()
         bio = io.BytesIO(path.read_bytes())
         seqs = []
         while True:

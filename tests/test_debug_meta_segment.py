@@ -183,7 +183,7 @@ def test_segment_is_caught_up_after_a_restart(tmp_path, torn):
     assert got == {"segment.caughtUp": 1}
 
 
-# (c) the contract `manual_close` and the benchmark's window rest on
+# (c) the contract the readers' join and the benchmark's window rest on
 def test_join_completion_returns_once_the_gz_is_on_disk(tmp_path,
                                                         monkeypatch):
     lm = closing_manager(tmp_path)
@@ -313,11 +313,13 @@ def test_failed_compress_job_surfaces_and_keeps_the_raw_file(tmp_path,
     while lm.get_last_closed_ledger_num() < 62:
         app.manual_close()      # nothing joins the compressor yet
     assert sorted(os.listdir(meta_dir)) == [SEG63]
-    with pytest.raises(RuntimeError) as failure:
-        app.manual_close()
+    app.manual_close()          # 63 commits; its tail's rotation fails
     assert lm.get_last_closed_ledger_num() == 63
+    with pytest.raises(RuntimeError) as failure:
+        app.herder.join_completion()
     with pytest.raises(RuntimeError):
-        lm.join_completion()
+        app.manual_close()      # sticky: the next close halts at its barrier
+    assert lm.get_last_closed_ledger_num() == 63
     cause = failure.value.__cause__
     while cause is not None and not isinstance(cause, OSError):
         cause = cause.__cause__
@@ -333,6 +335,7 @@ def test_failed_compress_job_surfaces_and_keeps_the_raw_file(tmp_path,
     assert lm.get_last_closed_ledger_num() == 63
     while lm.get_last_closed_ledger_num() < 127:
         app.manual_close()
+    app.herder.join_completion()        # who lists the segments joins
     assert sorted(os.listdir(meta_dir)) == [SEG63, SEG127 + ".gz"]
     assert len(records_of(meta_dir / (SEG127 + ".gz"))) == 64
     assert counts(app.metrics)["segment.streamed"] == 1

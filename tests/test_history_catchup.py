@@ -158,6 +158,9 @@ def make_publishing_app(tmp_path, n_ledgers=130):
             d = dests[seq % len(dests)]
             m1.submit(app, d.tx([op_payment(master.muxed, 1000)]))
         app.manual_close()
+    # a checkpoint's publish rides its ledger's tail: whoever reads the
+    # archive joins first
+    app.herder.join_completion()
     return app, make_tmpdir_archive("test", archive_root), archive_root
 
 

@@ -108,6 +108,9 @@ def _publisher(tmp_path):
         app.manual_close()
     while app.ledger_manager.get_last_closed_ledger_num() < CHECKPOINT:
         app.manual_close()
+    # the checkpoint's publish rides its ledger's tail: who reads the
+    # archive joins first
+    app.herder.join_completion()
     return app, make_tmpdir_archive("test", root)
 
 
@@ -325,6 +328,8 @@ def test_spans_under_a_close_carry_its_seq(runs):
     assert set(seqs) == want
     assert seqs["ledger.close.meta.compress"] == [CHECKPOINT]
     assert seqs["herder.trimInvalid"] == seqs["herder.triggerNextLedger"]
+    # no close joins its own tail: the one join is the archive reader's
+    assert seqs["herder.joinCompletion"] == [CHECKPOINT]
 
 
 # --------------------------------------------------- the registry itself --

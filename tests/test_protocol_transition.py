@@ -108,6 +108,7 @@ def published(tmp_path):
     # run out to a published checkpoint (frequency 64: ledger 63)
     while app.ledger_manager.get_last_closed_ledger_num() < 63:
         app.manual_close()
+    assert app.history_manager.published_count == 1   # joins the tail
     archive = make_tmpdir_archive("test", archive_root)
     return app, archive, failed_hash, failed_at, ok_hash, mid
 

@@ -215,7 +215,8 @@ def test_layer_metric_reads_names_the_program_prints(
 @pytest.mark.parametrize("name", ["ledger.close.tail.hidden",
                                   "ledger.close.tail.waited",
                                   "database.tail.busy",
-                                  "ledger.close.completeWait"])
+                                  "ledger.close.completeWait",
+                                  "herder.joinCompletion"])
 def test_barrier_counters_are_published_and_documented(name, program_names):
     assert name in program_names, (
         f"stellar_core_tpu/ opens no zone or counter {name!r}")
@@ -241,20 +242,63 @@ def test_dense_replay_names_are_published_and_documented(name,
         assert f"`{name}`" in fh.read()
 
 
-def test_dense_readers_that_borrow_a_reading_name_a_reader_that_exists():
-    """A `*.dense.py` reader that makes its reading with another
-    reader's code names that reader's file."""
-    borrowed = 0
+def _borrowed_readers(suffix):
+    """(reader file, the reader whose code makes its reading) of every
+    `*<suffix>` reader that calls `cell.spec.layer_reader`."""
     for reader in _reader_files():
-        if not reader.endswith(".dense.py"):
+        if not reader.endswith(suffix):
             continue
         for node in ast.walk(_parse(os.path.join(READERS, reader))):
             if isinstance(node, ast.Call) \
                     and _dotted(node.func) == "cell.spec.layer_reader":
-                assert os.path.exists(os.path.join(
-                    READERS, _string(node.args[0]) + ".py")), reader
-                borrowed += 1
-    assert borrowed == 13
+                yield reader, _string(node.args[0])
+
+
+@pytest.mark.parametrize("suffix,count", [(".dense.py", 13),
+                                          (".live.py", 1)])
+def test_readers_that_borrow_a_reading_name_a_reader_that_exists(suffix,
+                                                                 count):
+    """A `*.dense.py` or `*.live.py` reader that makes its reading with
+    another reader's code names that reader's file."""
+    borrowed = list(_borrowed_readers(suffix))
+    for reader, lender in borrowed:
+        assert os.path.exists(os.path.join(READERS, lender + ".py")), reader
+    assert len(borrowed) == count
+
+
+def test_complete_wait_live_reads_the_barrier_zone_through_catchups_reader(
+        program_names):
+    """ISSUE 34's one new metric: `complete_wait_ms.live` is
+    `complete_wait_ms.catchup`'s reading, and that reader looks up the
+    zone the close opens round its join of the previous tail; the zone
+    the readers' join opens is the one `herder_self_ms.live` subtracts."""
+    assert dict(_borrowed_readers(".live.py")) == {
+        "complete_wait_ms.live.py": "complete_wait_ms.catchup"}
+    lender = _parse(os.path.join(READERS, "complete_wait_ms.catchup.py"))
+    looked_up = {_string(n.args[0]) for n in ast.walk(lender)
+                 if isinstance(n, ast.Call)
+                 and _dotted(n.func) == "cell.zones.get"}
+    assert looked_up == {"ledger.close.completeWait"}
+    assert {"ledger.close.completeWait",
+            "herder.joinCompletion"} <= program_names
+    # opened by the readers' join, and by nothing on the close path
+    herder = _parse(os.path.join(PACKAGE, "herder", "herder.py"))
+    opened_in = {
+        fn.name for fn in ast.walk(herder)
+        if isinstance(fn, ast.FunctionDef)
+        for n in ast.walk(fn)
+        if isinstance(n, ast.Call)
+        and _opened_by_call(n) == "herder.joinCompletion"}
+    assert opened_in == {"join_completion"}
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
+        import json
+        entry = [m for m in json.load(fh)["per_layer"]
+                 if m["name"] == "complete_wait_ms.live"]
+    assert entry == [{
+        "name": "complete_wait_ms.live", "unit": "ms", "better": "lower",
+        "source": "program_span", "layer": "ledger (close, apply)",
+        "moves": "close_ms_p90",
+        "workloads": ["standalone-pay1000.closed"]}]
 
 
 # ------------------------------------------------------------ documents --

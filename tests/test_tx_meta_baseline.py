@@ -41,8 +41,10 @@ def _collect_app():
     return app, metas
 
 
-def _meta_hashes(metas):
-    """Per-tx sha256 of the XDR TransactionMeta, in apply order."""
+def _meta_hashes(app, metas):
+    """Per-tx sha256 of the XDR TransactionMeta, in apply order. The
+    stream is fed by the closes' completion tails: its consumer joins."""
+    app.herder.join_completion()
     out = []
     for meta in metas:
         v = meta.value
@@ -95,7 +97,7 @@ def test_classic_scenario_meta_is_stable():
         app.manual_close()
         _submit_ok(app, master.tx([op_payment(a.muxed, 42, asset=usd)]))
         app.manual_close()
-        _check("classic-v1", _meta_hashes(metas))
+        _check("classic-v1", _meta_hashes(app, metas))
     finally:
         app.shutdown()
 
@@ -118,7 +120,7 @@ def test_soroban_scenario_meta_is_stable(build, golden):
         r = m1.submit(app, frame)
         assert r["status"] == "PENDING", r
         app.manual_close()
-        _check(golden, _meta_hashes(metas))
+        _check(golden, _meta_hashes(app, metas))
     finally:
         app.shutdown()
 
@@ -154,6 +156,6 @@ def test_dex_scenario_meta_is_stable():
         # the crossing really happened
         row = app.database.query_one("SELECT COUNT(*) FROM offers", ())
         assert row[0] <= 1
-        _check("dex-v1", _meta_hashes(metas))
+        _check("dex-v1", _meta_hashes(app, metas))
     finally:
         app.shutdown()
