@@ -1,0 +1,11 @@
+"""Share of its roofline the `verify_kernel_msg32` program reaches (%) at
+the work this cell gives it: four runs a replay of the one shape, up to
+four enqueued at once. No kernel is new: the same
+`kernel_costs/verify_kernel_msg32.py`.
+
+The reading is `verify_kernel_msg32_roofline`'s, made by that reader, in the cell
+`multisig-range.range-replay`."""
+
+
+def read(cell):
+    return cell.spec.layer_reader("verify_kernel_msg32_roofline")(cell)

@@ -30,7 +30,7 @@ from ..history.archive import (CHECKPOINT_FREQUENCY, HAS_PATH,
                                file_path, first_ledger_in_checkpoint,
                                note_archive_failure, read_gz)
 from ..ledger.ledger_manager import LedgerCloseData, ledger_header_hash
-from ..tx.signature_checker import collect_signature_tuples
+from ..tx.signature_checker import collect_signature_tuples, signer_adds
 from ..util import chaos, tracing
 from ..util.logging import get_logger
 from ..util.xdr_stream import read_record
@@ -372,6 +372,7 @@ class _ChunkFeed:
         self._first = threading.Event()
         self._done = threading.Event()
         self.error: Optional[BaseException] = None
+        self.last_landed = 0.0   # perf_counter of the newest chunk
         if handle is not None:
             threading.Thread(target=self._run, args=(handle, n),
                              daemon=True, name="batch-resolve").start()
@@ -380,8 +381,8 @@ class _ChunkFeed:
     def ready(cls, verdicts) -> "_ChunkFeed":
         """A synchronous verifier's result: already landed, no thread."""
         feed = cls(None, 0)
-        feed._landed.append((0, len(verdicts), verdicts,
-                             time.perf_counter()))
+        feed.last_landed = time.perf_counter()
+        feed._landed.append((0, len(verdicts), verdicts, feed.last_landed))
         feed._first.set()
         feed._done.set()
         return feed
@@ -394,8 +395,9 @@ class _ChunkFeed:
         try:
             for lo, hi, verdicts in chunks_of(handle, n):
                 with self._lock:
+                    self.last_landed = time.perf_counter()
                     self._landed.append(
-                        (lo, hi, verdicts, time.perf_counter()))
+                        (lo, hi, verdicts, self.last_landed))
                 self._first.set()
         except BaseException as e:      # surfaced by take()
             with self._lock:
@@ -427,6 +429,13 @@ class _ChunkFeed:
 
     def wait(self, timeout: Optional[float] = None) -> bool:
         return self._done.wait(timeout)
+
+    def lead(self, now: float) -> float:
+        """Seconds before `now` that the last chunk landed; 0 while one
+        is still to land."""
+        if not self._done.is_set():
+            return 0.0
+        return max(0.0, now - self.last_landed)
 
 
 class DownloadVerifyTxResultsWork(BasicWork):
@@ -501,16 +510,23 @@ class ApplyCheckpointWork(BasicWork):
     catchup/ApplyCheckpointWork.{h,cpp} — the north-star hot path).
 
     With `batch_verifier` set, every checkpoint's signature tuples
-    (resolved against the envelopes, the checkpoint's own SetOptions and
-    the node's ledger state: tx/signature_checker.py) go to the device
-    as one batch before the apply loop. A batch that fits the largest
+    (resolved against the envelopes, the checkpoint's own SetOptions,
+    the node's ledger state and, for a checkpoint collected while the
+    one before it applies, the signer keys that one's operations add:
+    tx/signature_checker.py) go to the device as one batch before the
+    apply loop. A batch that fits the largest
     bucket is one device call; a larger one runs as chunks of it
     (ops/chunking.py), and because tuples are collected in ledger order
     chunk k holds the earliest ledgers not yet covered: each landed
     chunk's verdicts join the PrevalidatedVerifier at the next ledger's
     start, so the sequential apply does hash lookups instead of scalar
     verifies for whatever the device has finished (SURVEY.md §3.3), and
-    never waits for the rest."""
+    never waits for the rest.
+
+    When the work ends it keeps its counts and gives the rest back
+    (`_end`): a catchup holds every checkpoint's work for its whole
+    life, and over 157 checkpoints that must not be 157 checkpoints of
+    parsed history and verdicts."""
 
     def __init__(self, app, archive: HistoryArchive, checkpoint: int,
                  headers: Dict[int, LedgerHeaderHistoryEntry],
@@ -546,6 +562,13 @@ class ApplyCheckpointWork(BasicWork):
         # verifier that keeps none)
         self._batch_id = None
         self._frame_sets: Dict[int, TxSetFrame] = {}
+        # {account: signer keys} that this checkpoint's SetOptions
+        # operations add: what the next checkpoint is resolved against
+        # while this one still applies (the works before this one have
+        # ended by then, so what they add is in the node's state)
+        self._adds: Dict[bytes, List[bytes]] = {}
+        # parsed and dispatched from the work before, ahead of its turn
+        self._ahead = False
         self._prefetch_failed = False
         # seconds the FIRST result probe may wait (see
         # _resolve_prevalidated); deterministic tests raise it
@@ -557,20 +580,25 @@ class ApplyCheckpointWork(BasicWork):
                             f"transactions-{self.checkpoint:08x}.xdr.gz")
 
 
-    def advance_prefetch(self, swallow_errors: bool = False) -> bool:
+    def advance_prefetch(self, swallow_errors: bool = False,
+                         carried=None) -> bool:
         """Crank the download/parse/batch-dispatch stages without applying.
         Called by the PREVIOUS checkpoint's apply loop (swallow_errors=True
         there: a corrupt prefetched file must fail THIS work when its own
         on_run reaches it, not the caller mid-apply) so that this
         checkpoint's archive download and device signature batch overlap
         the sequential apply (the batch is dispatched async; its results
-        are collected lazily at first use). Returns True when prefetched
-        through the batch dispatch."""
+        are collected lazily at first use). That caller passes `carried`,
+        the signer keys its own checkpoint adds (never None from it): the
+        ledgers that install them have not applied, so the node's state
+        cannot tell the resolver. Returns True when prefetched through
+        the batch dispatch."""
         if swallow_errors:
             if self._prefetch_failed:
                 return True      # don't redo the doomed parse every crank
             try:
-                return self.advance_prefetch(swallow_errors=False)
+                return self.advance_prefetch(swallow_errors=False,
+                                             carried=carried)
             except Exception as e:       # noqa: BLE001 — re-raised by owner
                 # reset the partial parse so on_run re-attempts (once) and
                 # the failure is attributed to this checkpoint's own work
@@ -596,24 +624,38 @@ class ApplyCheckpointWork(BasicWork):
         if self._get.get_state() != State.WORK_SUCCESS:
             return True  # failure surfaces when on_run reaches this work
         if self._txs_by_seq is None:
-            targs = {"checkpoint": self.checkpoint} \
-                if tracing.ENABLED else None
-            with self.app.perf.zone("catchup.prefetch", targs=targs):
-                self._txs_by_seq = {}
-                bio = io.BytesIO(read_gz(self._local()))
-                while True:
-                    rec = read_record(bio)
-                    if rec is None:
-                        break
-                    the = TransactionHistoryEntry.from_bytes(rec)
-                    self._txs_by_seq[the.ledgerSeq] = the
-                self._next_seq = max(
-                    self.app.ledger_manager
-                    .get_last_closed_ledger_num() + 1,
-                    first_ledger_in_checkpoint(self.checkpoint))
-                if self.batch_verifier is not None:
-                    self._batch_prevalidate()
+            if carried is None:
+                self._parse_and_dispatch(None)
+            else:
+                # what the checkpoint before this one stands still for
+                self._ahead = True
+                targs = {"checkpoint": self.checkpoint,
+                         "lcl": self.app.ledger_manager
+                         .get_last_closed_ledger_num()} \
+                    if tracing.ENABLED else None
+                with self.app.perf.zone("catchup.prefetch.ahead",
+                                        targs=targs):
+                    self._parse_and_dispatch(carried)
         return True
+
+    def _parse_and_dispatch(self, carried) -> None:
+        targs = {"checkpoint": self.checkpoint} \
+            if tracing.ENABLED else None
+        with self.app.perf.zone("catchup.prefetch", targs=targs):
+            self._txs_by_seq = {}
+            bio = io.BytesIO(read_gz(self._local()))
+            while True:
+                rec = read_record(bio)
+                if rec is None:
+                    break
+                the = TransactionHistoryEntry.from_bytes(rec)
+                self._txs_by_seq[the.ledgerSeq] = the
+            self._next_seq = max(
+                self.app.ledger_manager
+                .get_last_closed_ledger_num() + 1,
+                first_ledger_in_checkpoint(self.checkpoint))
+            if self.batch_verifier is not None:
+                self._batch_prevalidate(carried)
 
     def on_run(self) -> State:
         lm = self.app.ledger_manager
@@ -645,7 +687,8 @@ class ApplyCheckpointWork(BasicWork):
         # reference: ApplyCheckpointWork applies ledger-at-a-time);
         # meanwhile push the next checkpoint's download + device batch
         if self.next_work is not None:
-            self.next_work.advance_prefetch(swallow_errors=True)
+            self.next_work.advance_prefetch(swallow_errors=True,
+                                            carried=self._adds)
         if self._next_seq > self.last_ledger:
             return State.WORK_SUCCESS
         seq = self._next_seq
@@ -659,10 +702,15 @@ class ApplyCheckpointWork(BasicWork):
         return State.WORK_RUNNING if self._next_seq <= self.last_ledger \
             else State.WORK_SUCCESS
 
-    def _batch_prevalidate(self) -> None:
+    def _batch_prevalidate(self, carried=None) -> None:
         """Resolve and dispatch the whole checkpoint's signatures as one
         batch (async — verdicts are adopted chunk by chunk as they
-        land, so the device computes while earlier ledgers apply)."""
+        land, so the device computes while earlier ledgers apply).
+        `carried`: the signer keys that the checkpoint still applying
+        adds, where this one is collected ahead of its turn. Nothing is
+        ever delayed to wait for state: a candidate too many is a lane,
+        a signer removed since a stale lane, a candidate missed a
+        counted native fallback."""
         network_id = self.app.config.network_id()
         frames = []
         for _, the in sorted(self._txs_by_seq.items()):
@@ -679,10 +727,11 @@ class ApplyCheckpointWork(BasicWork):
         # frames are in ledger order (the history file's), so the
         # tuples are, and chunk k of a split batch covers the earliest
         # ledgers no earlier chunk does
+        self._adds = signer_adds(frames)
         tuples = collect_signature_tuples(
             frames, network_id, ledger_state=self.app.ledger_manager.root,
             perf=self.app.perf, metrics=self.app.metrics,
-            checkpoint=self.checkpoint)
+            checkpoint=self.checkpoint, carried=carried, added=self._adds)
         if not tuples:
             return
         try:
@@ -732,8 +781,16 @@ class ApplyCheckpointWork(BasicWork):
         if self._pending_batch is None:
             return
         tuples, keys, feed = self._pending_batch
-        grace = 0.0 if self._grace_spent else self.batch_grace
-        self._grace_spent = True
+        grace = 0.0
+        if not self._grace_spent:
+            grace = self.batch_grace
+            self._grace_spent = True
+            if self._ahead:
+                # a checkpoint dispatched while the one before applied:
+                # how long its verdicts were all back before its first
+                # ledger wanted them (0: apply got here first)
+                self.app.metrics.new_timer("catchup.batch.lead").update(
+                    feed.lead(time.perf_counter()))
         try:
             landed = feed.take(grace)
         except Exception:
@@ -774,22 +831,38 @@ class ApplyCheckpointWork(BasicWork):
 
     def _retire_prevalidated(self, drop: bool = False) -> None:
         """Publish what the table was asked (crypto.prevalidated.hit /
-        .miss). Called once per table: when this work ends (the hooks
-        below; the table stays readable), or with `drop` where the
-        table goes before the work ends."""
+        .miss). Called once per table: when this work ends (`_end`; the
+        table's counts stay readable, its verdict map goes), or with
+        `drop` where the table goes before the work ends."""
         if self.prevalidated is not None:
             self.prevalidated.publish(self.app.metrics)
             if drop:
                 self.prevalidated = None
 
-    def on_success(self) -> None:
+    def _end(self) -> None:
+        """The work is over: publish the table's counters, then give
+        back everything a finished checkpoint does not need. What stays
+        is the table's counts and, for `drain`, the feed of a batch that
+        replay outran."""
         self._retire_prevalidated()
+        if self.prevalidated is not None:
+            self.prevalidated.release()
+        self._txs_by_seq = None
+        self._frame_sets = {}
+        self._adds = {}
+        if self.results_work is not None:
+            self.results_work.results_by_seq = {}
+        if self._pending_batch is not None:
+            self._pending_batch = (None, None, self._pending_batch[2])
+
+    def on_success(self) -> None:
+        self._end()
 
     def on_failure_raise(self) -> None:
-        self._retire_prevalidated()
+        self._end()
 
     def on_abort(self) -> None:
-        self._retire_prevalidated()
+        self._end()
 
     def drain(self, timeout: float) -> None:
         """Wait (bounded) for a dispatched batch that replay outran:

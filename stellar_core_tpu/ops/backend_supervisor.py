@@ -267,6 +267,11 @@ class BackendSupervisor:
             for c in FAILURE_CLASSES}
         self._probe_timer_metric = metrics.timer(
             "crypto", "verify_backend", "probe")
+        # call to landed of every watched collect: what the deadline is
+        # held against (a chunk dispatched behind others waits for their
+        # runs too)
+        self._collect_wait_timer = metrics.new_timer(
+            "crypto.verify.dispatch.collectWait")
         # the per-device breaker array: decorrelated seeded jitter
         # streams per device (and per node via jitter_seed), per-device
         # dispatch/skip counters on the shared registry
@@ -479,8 +484,11 @@ class BackendSupervisor:
                 w = _CollectWorker()
             box = {}
             done = threading.Event()
+            t0 = time.perf_counter()
             w.jobs.put((inner_collect, box, done))
-            if not done.wait(self._deadline_s):
+            landed = done.wait(self._deadline_s)
+            self._collect_wait_timer.update(time.perf_counter() - t0)
+            if not landed:
                 # the worker thread is stuck inside the hung collect;
                 # the sentinel behind it lets the thread exit once the
                 # handle finally releases

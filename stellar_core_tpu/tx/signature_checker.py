@@ -94,6 +94,13 @@ class PrevalidatedVerifier:
             return r
         return self._fallback(pub, sig, msg)
 
+    def release(self) -> None:
+        """Drop the verdict map; `hits` and the misses stay readable.
+        For the owner whose checks are over (a checkpoint's work at its
+        end): a catchup over many checkpoints keeps counts and not a
+        map a checkpoint."""
+        self._results = {}
+
     def publish(self, metrics) -> None:
         """Add `hits` and the misses to the counters
         `crypto.prevalidated.hit` / `.miss` of `metrics`: the checks
@@ -212,7 +219,8 @@ class SignatureChecker:
 
 
 def collect_signature_tuples(frames, network_id=None, ledger_state=None,
-                             perf=None, metrics=None, checkpoint=None):
+                             perf=None, metrics=None, checkpoint=None,
+                             carried=None, added=None):
     """(pub, sig, msg) candidates for a batch verify, by signer
     resolution: each decorated signature is paired with EVERY
     hint-matching ed25519 key that could be asked to verify it at
@@ -228,7 +236,13 @@ def collect_signature_tuples(frames, network_id=None, ledger_state=None,
       none get the envelope's and the operations' keys only;
     - signer keys that `SetOptions` operations of `frames` add to
       those accounts, anywhere in `frames`: a checkpoint tells the
-      resolver the signers it installs and rotates in itself.
+      resolver the signers it installs and rotates in itself (`added`:
+      `signer_adds(frames)` from a caller that has made it already);
+    - `carried`, {account: signer keys} that operations parsed and not
+      yet applied add (`signer_adds` of their frames): a checkpoint
+      collected while the one before it still applies is resolved
+      against what is in flight, not only against a state that its
+      signers have not reached (catchup/catchup_work.py).
 
     A tuple is a fact about three byte strings, so a candidate too many
     costs one device lane and a candidate missed is a counted miss of
@@ -243,15 +257,18 @@ def collect_signature_tuples(frames, network_id=None, ledger_state=None,
 
     `perf` opens the zone `crypto.collectTuples` round the collection
     (args `checkpoint`, `n`, `frames`) and `metrics` counts
-    `crypto.collect.signatures` (decorated signatures seen) and
-    `crypto.collect.candidates` (tuples made): once a call, so the
-    per-transaction callers pass neither."""
+    `crypto.collect.signatures` (decorated signatures seen),
+    `crypto.collect.candidates` (tuples made) and
+    `crypto.collect.carried` (those of them whose key `carried` alone
+    gave): once a call, so the per-transaction callers pass neither."""
     if perf is None:
-        return _collect(frames, network_id, ledger_state, metrics)
+        return _collect(frames, network_id, ledger_state, metrics, carried,
+                        added)
     targs = {"checkpoint": checkpoint, "frames": len(frames)} \
         if tracing.ENABLED else None
     with perf.zone("crypto.collectTuples", targs=targs):
-        tuples = _collect(frames, network_id, ledger_state, metrics)
+        tuples = _collect(frames, network_id, ledger_state, metrics,
+                          carried, added)
         if targs is not None:
             targs["n"] = len(tuples)
     return tuples
@@ -274,7 +291,7 @@ def _named_accounts(frame) -> List[bytes]:
     return named
 
 
-def _signer_adds(frames) -> Dict[bytes, List[bytes]]:
+def signer_adds(frames) -> Dict[bytes, List[bytes]]:
     """{account raw key: ed25519 signer keys that a SetOptions operation
     of `frames` adds to it}."""
     from ..xdr.transaction import OperationType
@@ -317,7 +334,8 @@ def _state_signers(ledger_state, accounts) -> Dict[bytes, List[bytes]]:
     return out
 
 
-def _collect(frames, network_id, ledger_state, metrics) -> list:
+def _collect(frames, network_id, ledger_state, metrics, carried=None,
+             added=None) -> list:
     parts = []          # (signatures, hash, named accounts) per envelope
     for frame in frames:
         if frame.is_fee_bump():
@@ -326,7 +344,10 @@ def _collect(frames, network_id, ledger_state, metrics) -> list:
             frame = frame.inner
         parts.append((frame.signatures, frame.contents_hash(),
                       _named_accounts(frame), frame))
-    added = _signer_adds(frames)
+    if added is None:
+        added = signer_adds(frames)
+    carried = carried or {}
+    carried_alone = set()   # keys that only `carried` gave an account
     in_state = {} if ledger_state is None else _state_signers(
         ledger_state, {a for _, _, named, _ in parts for a in named})
     by_hint: Dict[bytes, Dict[bytes, List[bytes]]] = {}
@@ -336,15 +357,17 @@ def _collect(frames, network_id, ledger_state, metrics) -> list:
         table = by_hint.get(acct)
         if table is None:
             table = by_hint[acct] = {}
-            for key in (acct, *in_state.get(acct, ()),
-                        *added.get(acct, ())):
+            own = (acct, *in_state.get(acct, ()), *added.get(acct, ()))
+            for n, key in enumerate((*own, *carried.get(acct, ()))):
                 keys = table.setdefault(key[-4:], [])
                 if key not in keys:
                     keys.append(key)
+                    if n >= len(own):
+                        carried_alone.add(key)
         return table
 
     tuples = []
-    seen_signatures = 0
+    seen_signatures = from_carry = 0
     for signatures, h, named, frame in parts:
         seen_signatures += len(signatures)
         tables = [hints_of(a) for a in named]
@@ -358,12 +381,15 @@ def _collect(frames, network_id, ledger_state, metrics) -> list:
                                 if k not in keys)
             for key in keys:
                 tuples.append((key, sig, h))
+            if carried_alone:
+                from_carry += sum(1 for k in keys if k in carried_alone)
         if network_id is not None and frame is not None:
             tuples.extend(_soroban_auth_tuples(frame, network_id))
     if metrics is not None:
         metrics.new_counter("crypto.collect.signatures").inc(
             seen_signatures)
         metrics.new_counter("crypto.collect.candidates").inc(len(tuples))
+        metrics.new_counter("crypto.collect.carried").inc(from_carry)
     return tuples
 
 

@@ -48,10 +48,16 @@ def test_no_cache_path_under_tests_and_one_place_sets_it():
     """`jax_compilation_cache_dir` is updated in util/jax_cache.py and
     nowhere else, and no path under tests/ serves as a cache."""
     offenders = []
+    # the directories .gitignore names hold what building, testing and
+    # builders' sessions leave behind (copies of the tree among them):
+    # not the checkout's own files
+    with open(os.path.join(REPO, ".gitignore")) as f:
+        ignored = {line.strip().rstrip("/") for line in f
+                   if line.strip().endswith("/")}
+    assert {"_archive", "_proof", "_scratch", "chiprun_out"} <= ignored
     for root, dirs, files in os.walk(REPO):
         dirs[:] = [d for d in dirs if not d.startswith(".")
-                   and d not in ("__pycache__", "chiprun_out",
-                                 "_archive")]
+                   and d not in ignored]
         for name in files:
             if not name.endswith(".py"):
                 continue

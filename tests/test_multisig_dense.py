@@ -7,7 +7,12 @@ The reference for authorisation is `benchmark/reference/multisig_model.py`
 device path runs on the CPU at buckets the suite already compiles: the
 largest bucket is patched to 16 lanes."""
 
+import gc
+import gzip
 import hashlib
+import os
+import shutil
+import weakref
 
 import pytest
 
@@ -20,7 +25,7 @@ from stellar_core_tpu.main import Application, get_test_config
 from stellar_core_tpu.ops import chunking
 from stellar_core_tpu.simulation.load_generator import LoadGenerator
 from stellar_core_tpu.tx.signature_checker import (
-    PrevalidatedVerifier, collect_signature_tuples)
+    PrevalidatedVerifier, collect_signature_tuples, signer_adds)
 from stellar_core_tpu.util.metrics import MetricsRegistry
 from stellar_core_tpu.util.timer import ClockMode, VirtualClock
 from stellar_core_tpu.work import State
@@ -275,6 +280,60 @@ def test_resolver_without_state_misses_only_state_signers(dense):
     assert pv.misses_unknown == 0
 
 
+def test_resolver_takes_signers_from_what_is_in_flight(dense):
+    """The next checkpoint's tuples are collected while the rotation is
+    parsed and not applied: the state still holds the old signer, the
+    frames hold no SetOptions, and only the carried adds name the new
+    one. The rotated-in key is a hit; the rotated-out key is a stale
+    lane (a true fact about three byte strings) that changes neither
+    the verdict nor the result code, by the model."""
+    ledger = dense["ledger"]
+    acct = TestAccount(ledger, _key("acct-in-flight"))
+    assert ledger.root_account.create(acct, 1000 * XLM)
+    acct.sync_seq()
+    ks = [_key(f"in-flight-{i}") for i in range(3)]
+    _install(ledger, acct, ks[:2], 2)
+    rotation = _tx(acct, [_signer_op(ks[1].public_key().raw, 0),
+                          _signer_op(ks[2].public_key().raw, 1)],
+                   [acct.key, ks[1]])
+    pay = [op_payment(ledger.root_account.muxed, XLM)]
+    after = _tx(acct, pay, [acct.key, ks[2]], ahead=2)
+    stale = _tx(acct, pay, [acct.key, ks[1]], ahead=2)     # rotated out
+    carried = signer_adds([rotation])
+    assert carried == {acct.key.public_key().raw: [ks[2].public_key().raw]}
+    metrics = MetricsRegistry()
+    tuples = collect_signature_tuples([after, stale], None,
+                                      ledger_state=ledger.root,
+                                      metrics=metrics, carried=carried)
+    seen = metrics.to_json()
+    # one candidate from the carry alone: the new key under `after`
+    assert seen["crypto.collect.carried"]["count"] == 1
+    assert seen["crypto.collect.candidates"]["count"] == len(tuples) == 4
+    without = collect_signature_tuples([after, stale], None,
+                                       ledger_state=ledger.root)
+    assert set(tuples) - set(without) == {
+        (ks[2].public_key().raw, bytes(after.signatures[1].signature),
+         after.contents_hash())}
+    assert tuples[:1] + tuples[2:] == without      # the others, in order
+    stale_lane = (ks[1].public_key().raw,
+                  bytes(stale.signatures[1].signature), stale.contents_hash())
+    assert stale_lane in tuples and verify_sig_uncached(*stale_lane)
+    assert ledger.apply_tx(rotation), rotation.result
+    pv = _table(tuples)
+    for frame, want in ((after, True), (stale, False)):
+        doc = _describe(frame)
+        accounts = _model_accounts(ledger, _accounts_of(doc))
+        assert model.candidate_tuples(accounts, doc) <= set(tuples)
+        ok, code, inner = _check(ledger, frame, pv)
+        _same_verdict((ok, code, inner), model.envelope_verdict(accounts, doc))
+        assert ok is want
+    assert pv.misses_unknown == 0 and pv.hits > 0
+    # the resolver of before: the new key's check is unknown to the table
+    pv = _table(without)
+    assert _check(ledger, after, pv)[0]
+    assert pv.misses_unknown > 0
+
+
 def test_resolver_counts_signatures_and_candidates(dense):
     seen = dense["metrics"].to_json()
     decorated = sum(len(f.signatures) + (len(f.inner.signatures)
@@ -411,11 +470,34 @@ def _close_to(app, seq):
         app.manual_close()
 
 
+def _rotate(lg) -> dict:
+    """The first `2of3` account of the load generator drops both of its
+    extra signers and takes two new keys, and signs with the new set
+    from now on. Returns the account's raw key, the keys rotated out and
+    those rotated in."""
+    from stellar_core_tpu.herder.tx_queue import AddResult
+    at = next(i for i, (keys, _, _) in sorted(lg._multisig.items())
+              if len(keys) == 3)
+    keys, threshold, bumped = lg._multisig[at]
+    new = [_key(f"rotated-in-{j}") for j in range(2)]
+    ops = [_signer_op(k.public_key().raw, 0) for k in keys[1:]] + \
+        [_signer_op(k.public_key().raw, 1) for k in new]
+    assert lg._sign_and_submit(lg.accounts[at], ops, signers=keys[:2]) \
+        == AddResult.ADD_STATUS_PENDING
+    lg._multisig[at] = ([keys[0]] + new, threshold, bumped)
+    return {"account": lg.accounts[at].key.public_key().raw,
+            "out": [k.public_key().raw for k in keys[1:]],
+            "in": [k.public_key().raw for k in new]}
+
+
 @pytest.fixture(scope="module")
 def archive(tmp_path_factory):
     """A native-verifier publisher: 20 accounts in the four classes,
     their signers installed in ledger 4, multisig payments in ledgers
-    5..7 of checkpoint 63 and in ledgers 64..65 of checkpoint 127."""
+    5..7 of checkpoint 63 and in ledgers 64..65 of checkpoint 127; in
+    ledger 8 one `2of3` account replaces both of its extra signers, so
+    each of its payments in checkpoint 127 carries a signature of a key
+    that only an operation of checkpoint 63 names."""
     root = str(tmp_path_factory.mktemp("dense") / "archive")
     cfg = get_test_config()
     cfg.MAX_TX_SET_SIZE = 1000
@@ -437,6 +519,8 @@ def archive(tmp_path_factory):
         for _ in range(3):
             assert lg.generate_multisig(ACCOUNTS) == ACCOUNTS
             app.manual_close()
+        rotated = _rotate(lg)
+        app.manual_close()
         _close_to(app, 63)
         for _ in range(2):
             assert lg.generate_multisig(ACCOUNTS) == ACCOUNTS
@@ -446,9 +530,9 @@ def archive(tmp_path_factory):
         assert app.history_manager.published_count == 2
         hashes = {int(seq): bytes(h) for seq, h in app.database.query_all(
             "SELECT ledgerseq, ledgerhash FROM ledgerheaders")}
-        yield {"archive": make_tmpdir_archive("test", root),
+        yield {"archive": make_tmpdir_archive("test", root), "root": root,
                "passphrase": cfg.NETWORK_PASSPHRASE, "hashes": hashes,
-               "failed": lg.failed}
+               "failed": lg.failed, "rotated": rotated}
     finally:
         app.shutdown()
 
@@ -457,16 +541,19 @@ class _Recording:
     """Pass-through that keeps every tuple the device was given and
     hands the chunks on."""
 
-    def __init__(self, inner, settle=False):
+    def __init__(self, inner, settle=False, lcl=None):
         self._inner = inner
         self._settle = settle
+        self._lcl = lcl
         self.dispatched = []
+        self.batches = []       # (tuples, the node's LCL at the dispatch)
 
     def __getattr__(self, name):
         return getattr(self._inner, name)
 
     def verify_tuples_async(self, items):
         self.dispatched.extend(items)
+        self.batches.append((len(items), self._lcl and self._lcl()))
         handle = self._inner.verify_tuples_async(items)
         if not self._settle:
             return handle
@@ -485,8 +572,11 @@ def _replaying_node(passphrase):
     return app
 
 
-def _catch_up(app, archive, to_ledger, wait_for_device, settle=False):
-    verifier = _Recording(app.batch_verifier, settle)
+def _catch_up(app, archive, to_ledger, wait_for_device, settle=False,
+              each_crank=None):
+    verifier = _Recording(
+        app.batch_verifier, settle,
+        app.ledger_manager.get_last_closed_ledger_num)
     work = CatchupWork(app, archive,
                        CatchupConfiguration(to_ledger=to_ledger),
                        batch_verifier=verifier, batch_grace=60.0)
@@ -501,6 +591,8 @@ def _catch_up(app, archive, to_ledger, wait_for_device, settle=False):
                 if cp._pending_batch is not None and id(cp) not in waited:
                     assert cp._pending_batch[2].wait(300)
                     waited.add(id(cp))
+        if each_crank is not None:
+            each_crank(work)
         if clock.crank(False) == 0:
             clock.crank(True)
     work.drain(300.0)
@@ -520,7 +612,11 @@ def test_tiny_dense_archive_replays_through_chunks(archive, bucket16):
     chunk was adopted and the table answered every check apply made
     (nothing unknown: the resolver made every tuple from the envelopes
     and the checkpoint's own SetOptions). Then checkpoint 127 over the
-    node's own state: its signers come from the ledger."""
+    node's own state: its signers come from the ledger, which is true
+    only because this is a second `CatchupWork`, started when every
+    ledger of checkpoint 63 has applied. One catchup over both
+    checkpoints collects 127's tuples while 63 still applies:
+    `test_one_catchup_over_both_checkpoints` beside this one."""
     assert archive["failed"] == 0
     app = _replaying_node(archive["passphrase"])
     try:
@@ -586,6 +682,159 @@ def test_apply_never_waits_for_a_chunk(archive, bucket16):
         assert seen["crypto.prevalidated.miss"]["count"] == pending
         assert seen["crypto.prevalidated.miss.pending"]["count"] == pending
         assert len(set(verifier.dispatched)) == len(verifier.dispatched)
+    finally:
+        app.shutdown()
+
+
+# --------------------------- (d) one catchup over more than one checkpoint --
+
+def _chain_of(app) -> dict:
+    app.ledger_manager.join_completion()
+    return {int(seq): bytes(h) for seq, h in app.database.query_all(
+        "SELECT ledgerseq, ledgerhash FROM ledgerheaders")}
+
+
+def test_one_catchup_over_both_checkpoints(archive, bucket16):
+    """Genesis to 127 in one `CatchupWork`: checkpoint 127's tuples are
+    collected and dispatched while checkpoint 63 applies, against a
+    state that holds none of its signers, and the table still answers
+    every check of both checkpoints (apply held until the chunks land):
+    the signers come from what checkpoint 63's operations add."""
+    app = _replaying_node(archive["passphrase"])
+    try:
+        work, verifier = _catch_up(app, archive["archive"], 0, True)
+        assert work.get_state() == State.WORK_SUCCESS
+        chain = _chain_of(app)
+        assert set(archive["hashes"]) == set(range(1, 128))
+        assert chain == archive["hashes"]
+        first, second = work.applied_checkpoints
+        for table in (first.prevalidated, second.prevalidated):
+            assert table.misses_unknown == 0 and table.misses_pending == 0
+            assert table.hits > 0
+        # 2 ledgers x (4 + 6*2 + 4*4 + 6*20) signatures
+        assert second.prevalidated.hits >= 2 * 152
+        seen = app.metrics.to_json()
+        assert seen["crypto.prevalidated.miss.unknown"]["count"] == 0
+        assert seen["crypto.collect.carried"]["count"] > 0
+        n = len(verifier.dispatched)
+        assert len(set(verifier.dispatched)) == n
+        assert seen["crypto.collect.candidates"]["count"] == n
+        # two batches; the second left while the first still applied
+        assert len(verifier.batches) == 2
+        assert verifier.batches[0][0] + verifier.batches[1][0] == n
+        assert verifier.batches[1][1] < 63
+        rot = archive["rotated"]
+        late = {p for p, _, _ in verifier.dispatched[verifier.batches[0][0]:]}
+        assert set(rot["in"]) & late and not set(rot["out"]) & late
+        # the new names: once a prefetched checkpoint
+        assert app.perf.report()["catchup.prefetch.ahead"]["count"] == 1
+        assert seen["catchup.batch.lead"]["count"] == 1
+        assert seen["crypto.verify.dispatch.collectWait"]["count"] == \
+            app.batch_verifier.status()["dispatches"]
+        status = app.batch_verifier.status()
+        assert status["state"] == "CLOSED" and not status["quarantined"]
+        assert not any(status["failures"].values())
+    finally:
+        app.shutdown()
+
+
+def test_range_catchup_with_apply_left_to_run_ahead(archive, bucket16):
+    """The same catchup with nothing held back: what the table could
+    not answer yet is pending, on either checkpoint, and never unknown."""
+    app = _replaying_node(archive["passphrase"])
+    try:
+        work, verifier = _catch_up(app, archive["archive"], 0, False)
+        assert work.get_state() == State.WORK_SUCCESS
+        assert app.ledger_manager.get_last_closed_ledger_hash() == \
+            archive["hashes"][127]
+        hits, pending, unknown = _table_counts(work)
+        assert unknown == 0 and hits + pending > 0
+        seen = app.metrics.to_json()
+        assert seen["crypto.prevalidated.miss.unknown"]["count"] == 0
+        assert seen["crypto.prevalidated.miss.pending"]["count"] == pending
+        assert seen["crypto.prevalidated.hit"]["count"] == hits
+        assert len(set(verifier.dispatched)) == len(verifier.dispatched)
+    finally:
+        app.shutdown()
+
+
+def test_a_finished_checkpoint_gives_back_all_but_its_counts(archive,
+                                                             bucket16):
+    """Inside the running catchup, once checkpoint 63's work has ended:
+    its parsed entries and frame sets are collectable, its table holds
+    counts and no verdict, and `drain` still settles."""
+    app = _replaying_node(archive["passphrase"])
+    held = {}
+
+    def look(work):
+        if not work.applied_checkpoints:
+            return
+        first = work.applied_checkpoints[0]
+        if "entry" not in held and first._txs_by_seq and first._frame_sets:
+            held["entry"] = weakref.ref(first._txs_by_seq[5])
+            # (not the newest: the ledger manager's completion worker
+            # holds the close it ran last)
+            held["frames"] = weakref.ref(first._frame_sets[5])
+        if first.is_done() and not work.is_done() and "after" not in held:
+            gc.collect()
+            held["after"] = {
+                "dead": [r() is None for r in (held["entry"],
+                                               held["frames"])],
+                "counts": (first.prevalidated.hits,
+                           first.prevalidated.misses_pending,
+                           first.prevalidated.misses_unknown),
+                "map": len(first.prevalidated._results),
+                "rest": (first._txs_by_seq, first._frame_sets,
+                         first._adds, first._pending_batch,
+                         first.results_work.results_by_seq),
+                "lcl": app.ledger_manager.get_last_closed_ledger_num()}
+
+    try:
+        work, _ = _catch_up(app, archive["archive"], 0, True,
+                            each_crank=look)
+        assert work.get_state() == State.WORK_SUCCESS
+        after = held["after"]
+        assert 63 <= after["lcl"] < 127
+        assert after["dead"] == [True, True]
+        assert after["map"] == 0 and after["counts"][0] > 0
+        assert after["counts"][1:] == (0, 0)
+        assert after["rest"] == (None, {}, {}, None, {})
+        # the counts are what the counters took
+        assert app.metrics.to_json()["crypto.prevalidated.hit"]["count"] \
+            == sum(cp.prevalidated.hits for cp in work.applied_checkpoints)
+        work.drain(5.0)
+        second = work.applied_checkpoints[1]
+        assert second._txs_by_seq is None and not second.prevalidated._results
+    finally:
+        app.shutdown()
+
+
+def test_a_corrupt_second_file_fails_its_own_checkpoint(archive, bucket16,
+                                                        tmp_path):
+    """The transactions file of checkpoint 127 is cut short: its
+    prefetch, cranked from checkpoint 63's apply with the carried adds,
+    swallows the error, checkpoint 63 replays to its end, and the
+    failure is checkpoint 127's own."""
+    root = str(tmp_path / "archive")
+    shutil.copytree(archive["root"], root)
+    path = os.path.join(root, "transactions", "00", "00", "00",
+                        "transactions-0000007f.xdr.gz")
+    with gzip.open(path, "rb") as f:
+        raw = f.read()
+    with gzip.open(path, "wb") as f:
+        f.write(raw[:len(raw) // 2])
+    app = _replaying_node(archive["passphrase"])
+    try:
+        work, _ = _catch_up(app, make_tmpdir_archive("test", root), 0, True)
+        assert work.get_state() == State.WORK_FAILURE
+        first, second = work.applied_checkpoints
+        assert first.get_state() == State.WORK_SUCCESS
+        assert second.get_state() == State.WORK_FAILURE
+        assert second._prefetch_failed
+        lm = app.ledger_manager
+        assert lm.get_last_closed_ledger_num() == 63
+        assert lm.get_last_closed_ledger_hash() == archive["hashes"][63]
+        assert first.prevalidated.misses_unknown == 0
     finally:
         app.shutdown()
 

@@ -242,6 +242,30 @@ def test_dense_replay_names_are_published_and_documented(name,
         assert f"`{name}`" in fh.read()
 
 
+# what ISSUE 35 publishes about a catchup over more than one checkpoint:
+# three are read by `*.range.py` readers of their own (checked above),
+# the fourth decides `correct` in the range cell's driver
+RANGE_NAMES = {
+    "catchup.prefetch.ahead": "prefetch_ahead_ms.range.py",
+    "catchup.batch.lead": "batch_lead_ms.range.py",
+    "crypto.verify.dispatch.collectWait": "collect_wait_ms.range.py",
+    "crypto.collect.carried": "../generators/range_replay.py",
+}
+
+
+@pytest.mark.parametrize("name", sorted(RANGE_NAMES))
+def test_range_replay_names_are_published_documented_and_read(
+        name, program_names):
+    assert name in program_names, (
+        f"stellar_core_tpu/ opens no zone, timer or counter {name!r}")
+    for doc in ("docs/OBSERVABILITY.md", "PERF.md"):
+        with open(os.path.join(ROOT, doc), encoding="utf-8") as fh:
+            assert f"`{name}`" in fh.read(), f"{doc} does not name {name}"
+    with open(os.path.join(READERS, RANGE_NAMES[name]),
+              encoding="utf-8") as fh:
+        assert f'"{name}"' in fh.read()
+
+
 def _borrowed_readers(suffix):
     """(reader file, the reader whose code makes its reading) of every
     `*<suffix>` reader that calls `cell.spec.layer_reader`."""
@@ -255,11 +279,12 @@ def _borrowed_readers(suffix):
 
 
 @pytest.mark.parametrize("suffix,count", [(".dense.py", 13),
-                                          (".live.py", 1)])
+                                          (".live.py", 1),
+                                          (".range.py", 16)])
 def test_readers_that_borrow_a_reading_name_a_reader_that_exists(suffix,
                                                                  count):
-    """A `*.dense.py` or `*.live.py` reader that makes its reading with
-    another reader's code names that reader's file."""
+    """A `*.dense.py`, `*.live.py` or `*.range.py` reader that makes its
+    reading with another reader's code names that reader's file."""
     borrowed = list(_borrowed_readers(suffix))
     for reader, lender in borrowed:
         assert os.path.exists(os.path.join(READERS, lender + ".py")), reader
