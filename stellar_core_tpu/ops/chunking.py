@@ -4,17 +4,24 @@ Shared by the device verifier (`ops/verifier.py`) and the backend
 supervisor, and jax-free like `shard_math.py`, because the supervisor
 must stay importable without the device stack.
 
-One XLA program exists per bucket size, and the largest one this
-repository has ever compiled and run on a chip is 65,536 lanes (225 s
-cold, 2.95 s a run; a described v5e 2x2 refused 524,288). A batch
+One XLA program exists per bucket size, and a lane is not the same
+price on every rung: on a TPU v5e a full run reads 9.4 us a lane at
+2,048 lanes, 9.8 at 4,096, 10.5 at 8,192, 20.6 at 16,384, 38.8 at
+32,768 and 44.8 at 65,536 (PERF.md section 7). The ladder ends at the
+cheapest rung, the larger where two are within 5 %: 4,096. A batch
 beyond it runs as ceil(n / MAX_BUCKET) calls of that one program, in
 the order given, the remainder padded into the same bucket (one shape,
 no second compile), with at most MAX_CHUNKS_IN_FLIGHT of them
-dispatched and not yet collected: the device is never idle between
-chunks, and a chunk's dispatch-to-collect stays near two runs, under a
-deadline that is set for one. MAX_BUCKET is a constant, not a config
-field: which rung of the ladder is cheapest a lane is a measurement
-still to be made (ROADMAP Queue 1 item 6(a)).
+dispatched and not yet collected. Three, because the thread that
+collects a chunk and dispatches the next shares the interpreter with
+apply and comes back 25-70 ms after a chunk lands, where a run is 40
+ms: with two in flight the device stands idle between the runs of a
+batch (PERF.md section 6). A chunk's dispatch-to-collect stays near
+three runs, 0.12 s, under a deadline that is set in seconds. A small
+chunk is also an early one: a checkpoint's first verdicts are back
+after one run, before apply has reached the ledgers they cover. Both
+are constants, not config fields: they follow from the chip's ladder,
+not from a deployment.
 """
 
 from __future__ import annotations
@@ -27,8 +34,8 @@ from ..util.logging import get_logger
 
 log = get_logger("Herder")
 
-MAX_BUCKET = 65536
-MAX_CHUNKS_IN_FLIGHT = 2
+MAX_BUCKET = 4096
+MAX_CHUNKS_IN_FLIGHT = 3
 
 
 def chunk_bounds(n: int, size: int) -> List[Tuple[int, int]]:

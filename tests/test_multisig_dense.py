@@ -12,6 +12,7 @@ import gzip
 import hashlib
 import os
 import shutil
+import time
 import weakref
 
 import pytest
@@ -384,7 +385,8 @@ def bucket16(monkeypatch):
 def test_a_batch_beyond_the_bucket_runs_as_chunks_of_it(bucket16):
     """50 tuples at a largest bucket of 16: four calls of the one shape,
     bit-flipped tuples on both sides of every boundary, verdicts equal
-    to the oracle's in order, at most two chunks in flight."""
+    to the oracle's in order, no more chunks in flight than the constant
+    allows."""
     from stellar_core_tpu.ops.verifier import TpuBatchVerifier
     metrics = MetricsRegistry()
     verifier = TpuBatchVerifier(metrics=metrics)
@@ -397,7 +399,7 @@ def test_a_batch_beyond_the_bucket_runs_as_chunks_of_it(bucket16):
         [(0, 16), (16, 32), (32, 48), (48, 50)]
     assert [v for _, _, vs in landed for v in vs] == want
     assert handle() == want                      # the contract as before
-    assert handle.max_in_flight == 2
+    assert handle.max_in_flight == chunking.MAX_CHUNKS_IN_FLIGHT
     seen = metrics.to_json()
     assert seen["crypto.verify.dispatch.chunks"]["count"] == 4
     assert seen["crypto.verify.dispatch.batch"]["count"] == 4
@@ -434,7 +436,7 @@ def test_a_failed_chunk_falls_back_alone(bucket16):
         handle = sup.verify_tuples_async(items)
         assert isinstance(handle, chunking.ChunkedCollect)
         assert handle() == want
-        assert handle.max_in_flight == 2
+        assert handle.max_in_flight == chunking.MAX_CHUNKS_IN_FLIGHT
         # the chunks share the number the wrapped verifier gave the first
         assert handle.batch == inner.last_batch_id == 1
         status = sup.status()
@@ -682,6 +684,87 @@ def test_apply_never_waits_for_a_chunk(archive, bucket16):
         assert seen["crypto.prevalidated.miss"]["count"] == pending
         assert seen["crypto.prevalidated.miss.pending"]["count"] == pending
         assert len(set(verifier.dispatched)) == len(verifier.dispatched)
+    finally:
+        app.shutdown()
+
+
+class _KeepsUp:
+    """A device that keeps up: chunks of the largest bucket, as many in
+    flight as the constant allows, run one after the other at `lane_s`
+    seconds a lane of the full bucket, answered with the native
+    verifier's verdicts."""
+
+    def __init__(self, lane_s: float):
+        self.lane_s = lane_s
+        self.last_batch_id = 0
+        self.chunks = 0
+        self._free_at = 0.0      # when the last run dispatched ends
+
+    def verify_tuples_async(self, items):
+        self.last_batch_id += 1
+
+        def dispatch(part, chunk):
+            self.chunks += 1
+            due = max(time.perf_counter(), self._free_at) \
+                + chunking.MAX_BUCKET * self.lane_s
+            self._free_at = due
+
+            def collect():
+                time.sleep(max(0.0, due - time.perf_counter()))
+                return [verify_sig_uncached(p, s, m) for p, s, m in part]
+            return collect
+        return chunking.ChunkedCollect(self, items, dispatch)
+
+
+def test_the_cold_checkpoint_is_not_cold_where_the_device_keeps_up(
+        archive, monkeypatch):
+    """Checkpoint 63 into a fresh node with the grace a node has (50
+    ms), a device that runs a 64-lane chunk in 10 ms and apply paced at
+    a ledger every 50 ms or slower (152 signatures a payment ledger
+    against 320 lanes): the first chunk is in the table before the
+    first payment ledger (5) applies, and from the second payment
+    ledger on apply outruns nothing."""
+    monkeypatch.setattr(chunking, "MAX_BUCKET", 64)
+    app = _replaying_node(archive["passphrase"])
+    try:
+        app.flight_recorder.start()
+        device = _KeepsUp(0.010 / 64)
+        work = CatchupWork(app, archive["archive"],
+                           CatchupConfiguration(to_ledger=63),
+                           batch_verifier=device)
+        app.work_scheduler.schedule(work)
+        lm = app.ledger_manager
+        pending_at = {}          # LCL -> pending misses counted so far
+        while not work.is_done():
+            tables = [cp.prevalidated for cp in work.applied_checkpoints
+                      if cp.prevalidated is not None]
+            lcl = lm.get_last_closed_ledger_num()
+            pending_at[lcl] = sum(t.misses_pending for t in tables)
+            if tables and lcl < 8:
+                time.sleep(0.050)
+            if app.clock.crank(False) == 0:
+                app.clock.crank(True)
+        work.drain(60.0)
+        assert work.get_state() == State.WORK_SUCCESS
+        assert lm.get_last_closed_ledger_hash() == archive["hashes"][63]
+        hits, pending, unknown = _table_counts(work)
+        # ledgers 5..7: (4 + 6*2 + 4*4 + 6*20) signatures each
+        assert unknown == 0 and hits + pending >= 3 * 152
+        assert device.chunks >= 8
+        adopted = [ev["args"] for ev in
+                   app.flight_recorder.to_chrome_trace()["traceEvents"]
+                   if ev["name"] == "catchup.batch.adopted"]
+        assert len(adopted) == device.chunks
+        assert [a["lo"] for a in adopted] == \
+            [lo for lo, _ in chunking.chunk_bounds(
+                sum(a["n"] for a in adopted), 64)]
+        # the first chunk before the first payment ledger applies ...
+        assert adopted[0]["lo"] == 0 and adopted[0]["seq"] <= 5
+        # ... and nothing pending once that ledger has closed
+        assert pending_at[5] == pending_at[63] == pending
+        assert app.metrics.to_json()[
+            "crypto.prevalidated.miss.pending"]["count"] == pending
+        assert hits >= 2 * 152
     finally:
         app.shutdown()
 
