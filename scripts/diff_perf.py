@@ -11,7 +11,10 @@ Inputs are JSON files saved from the admin API's `perf` route, e.g.
 
 Prints a per-zone table of count/total/mean deltas, sorted by the chosen
 column's delta (default: total_ms), so regressions stand out the same
-way DiffTracyCSV's execution-time diffs do.
+way DiffTracyCSV's execution-time diffs do. A zone's on-CPU milliseconds
+(`cpu_ms`) are a column of its row; the derived `<zone>.onCpu` entries
+that carry the same number for the benchmark's readers are not zones
+and get no row.
 """
 
 import argparse
@@ -37,7 +40,8 @@ def main() -> int:
 
     before = load(args.before)
     after = load(args.after)
-    names = sorted(set(before) | set(after))
+    names = sorted(n for n in set(before) | set(after)
+                   if not n.endswith(".onCpu"))
     key = {"total": "total_ms", "mean": "mean_ms", "count": "count"}[
         args.sort]
 
@@ -48,19 +52,20 @@ def main() -> int:
         d_count = a.get("count", 0) - b.get("count", 0)
         d_total = a.get("total_ms", 0.0) - b.get("total_ms", 0.0)
         d_mean = a.get("mean_ms", 0.0) - b.get("mean_ms", 0.0)
+        d_cpu = a.get("cpu_ms", 0.0) - b.get("cpu_ms", 0.0)
         if abs(d_total) < args.min_delta_ms:
             continue
         rows.append((name, d_count, d_total, d_mean,
-                     a.get("total_ms", 0.0)))
+                     a.get("total_ms", 0.0), d_cpu))
 
     sort_idx = {"count": 1, "total": 2, "mean": 3}[args.sort]
     rows.sort(key=lambda r: -abs(r[sort_idx]))
 
     print(f"{'zone':40} {'Δcount':>10} {'Δtotal_ms':>12} "
-          f"{'Δmean_ms':>10} {'after_total':>12}")
-    for name, dc, dt, dm, at in rows:
+          f"{'Δmean_ms':>10} {'after_total':>12} {'Δcpu_ms':>12}")
+    for name, dc, dt, dm, at, dcpu in rows:
         print(f"{name:40} {dc:>+10d} {dt:>+12.3f} {dm:>+10.3f} "
-              f"{at:>12.3f}")
+              f"{at:>12.3f} {dcpu:>+12.3f}")
     return 0
 
 

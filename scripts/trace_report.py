@@ -11,7 +11,9 @@ Inputs are trace files from the admin API:
     python scripts/trace_report.py /tmp/run.json
     python scripts/trace_report.py /tmp/run.json /tmp/other.json
 
-With one trace: top zones by total time, the ledger-close critical
+With one trace: top zones by total time and on-CPU time (`cpu_us` on
+a span's end: wall less on-CPU is what its thread stood still), the
+ledger-close critical
 path (per-phase breakdown of every ledger.close.* span), and
 barrier-wait gaps (time closes spent blocked on the completion
 worker). With two: a per-zone count/total/mean delta table, sorted so
@@ -54,22 +56,25 @@ def load_spans(path):
         elif ph == "E":
             if stacks[key]:
                 b = stacks[key].pop()
-                spans.append((b["name"], b["ts"], ev["ts"] - b["ts"],
-                              b.get("args") or {}))
+                args = b.get("args") or {}
+                if "cpu_us" in (ev.get("args") or {}):
+                    args = dict(args, cpu_us=ev["args"]["cpu_us"])
+                spans.append((b["name"], b["ts"], ev["ts"] - b["ts"], args))
         elif ph in ("i", "b", "e"):
             other[f"{ph}:{ev.get('name')}"] += 1
     return spans, other
 
 
 def aggregate(spans):
-    """name -> {count, total_us, max_us}."""
+    """name -> {count, total_us, max_us, cpu_us}."""
     agg = {}
-    for name, _ts, dur, _args in spans:
+    for name, _ts, dur, args in spans:
         st = agg.setdefault(name, {"count": 0, "total_us": 0.0,
-                                   "max_us": 0.0})
+                                   "max_us": 0.0, "cpu_us": 0.0})
         st["count"] += 1
         st["total_us"] += dur
         st["max_us"] = max(st["max_us"], dur)
+        st["cpu_us"] += args.get("cpu_us", 0.0)
     return agg
 
 
@@ -82,13 +87,14 @@ def summarize(path, top):
     agg = aggregate(spans)
     print(f"== {path}: {len(spans)} spans, {len(agg)} zones ==")
     print(f"{'zone':42} {'count':>8} {'total_ms':>12} {'mean_ms':>10} "
-          f"{'max_ms':>10}")
+          f"{'max_ms':>10} {'cpu_ms':>12}")
     for name, st in sorted(agg.items(),
                            key=lambda kv: -kv[1]["total_us"])[:top]:
         print(f"{name:42} {st['count']:>8} "
               f"{_fmt_ms(st['total_us']):>12} "
               f"{_fmt_ms(st['total_us'] / st['count']):>10} "
-              f"{_fmt_ms(st['max_us']):>10}")
+              f"{_fmt_ms(st['max_us']):>10} "
+              f"{_fmt_ms(st['cpu_us']):>12}")
 
     # ---- ledger-close critical path: per-phase share of closeLedger
     closes = [s for s in spans if s[0] == "ledger.closeLedger"]

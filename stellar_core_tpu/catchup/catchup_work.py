@@ -33,6 +33,7 @@ from ..ledger.ledger_manager import LedgerCloseData, ledger_header_hash
 from ..tx.signature_checker import collect_signature_tuples, signer_adds
 from ..util import chaos, tracing
 from ..util.logging import get_logger
+from ..util.perf import sched_lap, thread_sched
 from ..util.xdr_stream import read_record
 from ..work import BasicWork, State, Work, WorkSequence
 from ..xdr.ledger import (LedgerHeaderHistoryEntry, TransactionHistoryEntry,
@@ -366,7 +367,10 @@ class _ChunkFeed:
     without ever blocking on the device. Daemon for `_AsyncResult`'s
     reason: a stalled batch dies with the process."""
 
-    def __init__(self, handle, n: int):
+    def __init__(self, handle, n: int, metrics=None):
+        # where the collecting thread's account with the scheduler
+        # goes, once a chunk (`runtime.collect.*`); None: not kept
+        self._metrics = metrics
         self._lock = threading.Lock()   # guards _landed and error
         self._landed = deque()   # (lo, hi, verdicts or None, landed at)
         self._first = threading.Event()
@@ -392,6 +396,7 @@ class _ChunkFeed:
         from ..util import threads
         if threads.CHECK:
             threads.bind("catchup-worker")
+        sched0 = None if self._metrics is None else thread_sched()
         try:
             for lo, hi, verdicts in chunks_of(handle, n):
                 with self._lock:
@@ -399,6 +404,11 @@ class _ChunkFeed:
                     self._landed.append(
                         (lo, hi, verdicts, self.last_landed))
                 self._first.set()
+                # one cycle of this thread: a chunk collected and
+                # converted, and the next one packed and enqueued
+                sched0 = sched_lap(sched0, self._metrics,
+                                   "runtime.collect.onCpu",
+                                   "runtime.collect.runDelay")
         except BaseException as e:      # surfaced by take()
             with self._lock:
                 self.error = e
@@ -742,7 +752,7 @@ class ApplyCheckpointWork(BasicWork):
                 # ones hit the table — and an abandoned/stalled batch
                 # can never block process shutdown
                 handle = self.batch_verifier.verify_tuples_async(tuples)
-                feed = _ChunkFeed(handle, len(tuples))
+                feed = _ChunkFeed(handle, len(tuples), self.app.metrics)
             else:
                 # synchronous verifier: the cost was just paid inline;
                 # the result is simply ready

@@ -105,6 +105,13 @@ class Herder:
         # (_ledger_closed)
         self._recv_count = 0
         self._recv_seconds = 0.0
+        # ... and their on-CPU seconds, measured over the run of them
+        # and not round each (`_end_recv_run`): (perf_counter, thread
+        # clock) at the entry of the first call since the last close,
+        # taken only while a recorder records; then the run's on-CPU
+        # seconds, or None where they were not measured
+        self._recv_run = None
+        self._recv_cpu = None
         # hash-keyed propagation tracker (overlay/propagation.py), set
         # by Application; admission/externalize stamps land here so the
         # mesh observatory sees the full flood→admit→externalize path
@@ -188,13 +195,38 @@ class Herder:
         batch). Timed here with two clock reads into plain attributes
         (see `_recv_count`) and no span, not even while a trace is on:
         60,000 spans a checkpoint on the closing thread are more than
-        a reader of the recording should have to wade through."""
+        a reader of the recording should have to wade through. While
+        one is on, and only then, the first call after a close reads
+        the thread clock, for the on-CPU seconds of the run of calls
+        (`_end_recv_run`)."""
         t0 = time.perf_counter()
+        if tracing.ENABLED and self._recv_count == 0:
+            self._recv_run = (t0, time.thread_time())
         try:
             return self._recv_transaction(tx, verify)
         finally:
             self._recv_seconds += time.perf_counter() - t0
             self._recv_count += 1
+
+    def _end_recv_run(self) -> None:
+        """The on-CPU seconds of the `recv_transaction` calls since the
+        last close, from two reads of the thread clock a close and not
+        two a call: on the chip's host a read is a system call of 6-20
+        us against a call of ~230, and the clock ticks at 10 ms, so
+        reads round every call cost a sixth of a traced window and
+        could not resolve one call (PERF.md §6, PR 37). The run is from
+        the first call's entry to here, the start of the next close. It
+        stands for the calls only where they came back to back, which
+        is when a thread's wait for the interpreter matters: where what
+        lies between the calls is over a fiftieth of the run, as on a
+        node that idles between submissions, nothing is reported."""
+        run, self._recv_run = self._recv_run, None
+        if run is None:
+            return
+        cpu = time.thread_time() - run[1]
+        wall = time.perf_counter() - run[0]
+        if self._recv_seconds >= 0.98 * wall:
+            self._recv_cpu = cpu
 
     def _recv_transaction(self, tx, verify) -> AddResult:
         if verify is None and self.controller is not None and \
@@ -382,6 +414,7 @@ class Herder:
         """Build a proposal from the queue (reference:
         Herder::triggerNextLedger :1266). Standalone mode externalizes it
         directly; under SCP this is where nomination starts."""
+        self._end_recv_run()
         lcl_header = self.ledger_manager.get_last_closed_ledger_header()
         next_seq = lcl_header.ledgerSeq + 1
         targs = {"seq": next_seq} if tracing.ENABLED else None
@@ -451,8 +484,10 @@ class Herder:
         HerderImpl::updateTransactionQueue)."""
         if self._recv_count:
             self.perf.add("herder.recvTransaction", self._recv_seconds,
-                          self._recv_count)
+                          self._recv_count, self._recv_cpu)
             self._recv_count, self._recv_seconds = 0, 0.0
+            # a run still open has the close inside it: not the calls'
+            self._recv_run = self._recv_cpu = None
         self._record_tx_e2e(tx_set)
         self.tx_queue.remove_applied(tx_set.txs)
         self.tx_queue.shift()
@@ -797,6 +832,7 @@ class Herder:
         """Propose the next slot's value through SCP (reference:
         HerderImpl::triggerNextLedger :1266)."""
         assert self.scp is not None
+        self._end_recv_run()
         lcl_header = self.ledger_manager.get_last_closed_ledger_header()
         slot = lcl_header.ledgerSeq + 1
         candidates, invalid = trim_invalid(

@@ -36,6 +36,7 @@ from typing import Callable, Optional
 
 from ..util import chaos, threads
 from ..util.logging import get_logger
+from ..util.perf import sched_lap, thread_sched
 
 log = get_logger("Ledger")
 
@@ -46,8 +47,11 @@ IDLE_EXIT_SECONDS = 30.0
 class CloseCompletionQueue:
     """Single-worker FIFO queue with a per-ledger barrier."""
 
-    def __init__(self, name: str = "close-completion"):
+    def __init__(self, name: str = "close-completion", metrics=None):
         self._name = name
+        # where the worker's account with the scheduler goes, once a
+        # job (`runtime.completion.*`); None: not kept
+        self._metrics = metrics
         self._cond = threading.Condition()
         self._jobs: deque = deque()          # (seq, callable)
         self._pending = 0
@@ -85,6 +89,7 @@ class CloseCompletionQueue:
                     self._cond.wait(remaining)
                 seq, fn = self._jobs[0]
                 self._running = True
+            sched0 = None if self._metrics is None else thread_sched()
             try:
                 if chaos.ENABLED:
                     # injected completion failure: surfaces as the same
@@ -98,6 +103,9 @@ class CloseCompletionQueue:
                     if self._error is None:
                         self._error = (seq, exc)
             finally:
+                sched_lap(sched0, self._metrics,
+                          "runtime.completion.onCpu",
+                          "runtime.completion.runDelay")
                 with self._cond:
                     self._running = False
                     self._jobs.popleft()

@@ -23,6 +23,7 @@ from ..invariant.manager import InvariantManager
 from ..tx.signature_checker import VerifyFn, default_verify
 from ..util import chaos, threads, tracing
 from ..util.logging import get_logger
+from ..util.perf import sched_lap, thread_sched
 from ..xdr.ledger import (LedgerCloseMeta, LedgerCloseMetaV0,
                           LedgerEntryChanges, LedgerHeader,
                           LedgerHeaderHistoryEntry, LedgerUpgrade,
@@ -171,7 +172,7 @@ class LedgerManager:
         # synchronous reference schedule, used by determinism tests).
         from .completion import CloseCompletionQueue
         self.defer_completion = True
-        self._completion = CloseCompletionQueue()
+        self._completion = CloseCompletionQueue(metrics=metrics)
         if db is not None:
             db.add_close_barrier(self._completion.reader_barrier)
         if db is not None and not in_memory_ledger:
@@ -442,11 +443,17 @@ class LedgerManager:
             n_txs = ts.size_tx() if hasattr(ts, "size_tx") else \
                 ts.size_tx_total() if hasattr(ts, "size_tx_total") else 0
             targs = {"seq": lcd.ledger_seq, "txs": n_txs}
-        with self.perf.zone("ledger.closeLedger", targs=targs), \
-                self.perf.log_slow_execution(
+        with self.perf.zone("ledger.closeLedger", targs=targs):
+            # the closing thread's account with the scheduler, once a
+            # close: what it ran, and what it was runnable and not run
+            sched0 = thread_sched()
+            with self.perf.log_slow_execution(
                     f"closeLedger {lcd.ledger_seq}", 2.0,
-                    detail=lambda: _phase_summary(phases)):
-            self._close_ledger(lcd, verify, phases)
+                    detail=lambda: _phase_summary(phases),
+                    seq=lcd.ledger_seq, sched0=sched0):
+                self._close_ledger(lcd, verify, phases)
+            sched_lap(sched0, self._metrics, "runtime.closing.onCpu",
+                      "runtime.closing.runDelay")
 
     def join_completion(self, reraise: bool = True) -> None:
         """Barrier on the deferred completion segment: blocks until
@@ -701,7 +708,7 @@ class LedgerManager:
         targs = {"seq": seq} if tracing.ENABLED else None
         with self.perf.zone("ledger.close.complete", targs=targs), \
                 self.perf.log_slow_execution(
-                    f"closeLedger {seq} completion", 2.0):
+                    f"closeLedger {seq} completion", 2.0, seq=seq):
             # each transaction's history artifacts are built once and
             # serialised once (native codec), for both sinks (history
             # rows, close meta), and only if one is on; bytes only

@@ -96,6 +96,11 @@ class Application:
         # route their begin/end events through it while recording
         self.flight_recorder = FlightRecorder()
         self.perf.tracer = self.flight_recorder
+        # ... and the other way round: what the recorder times while it
+        # records (the collector) and what the registry counts (a scope
+        # that overran) land in this node's zones and metrics
+        self.flight_recorder.registry = self.perf
+        self.perf.metrics = self.metrics
         # input recorder (replay/recorder.py): attached by the
         # `recordstart` admin route or a Simulation driver; None means
         # every recording hook is a single attribute check

@@ -465,6 +465,14 @@ class BackendSupervisor:
         blame = (hung_device,) if hung_device is not None else participants
 
         def collect():
+            # a collect that takes a second has stalled (a chunk is
+            # collected in tens of milliseconds): the warning says
+            # whether the collecting thread ran, waited or was kept off
+            # the CPU meanwhile
+            with self.perf.log_slow_execution("crypto.verify.collect", 1.0):
+                return watched()
+
+        def watched():
             if self._deadline_s <= 0:
                 box = {}
                 try:

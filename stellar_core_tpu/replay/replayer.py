@@ -154,9 +154,16 @@ def normalize_trace(recorder) -> List[tuple]:
     ``(phase, name, canonical-args-json, correlation-id)``. Timestamps
     are wall-clock (perf_counter) and thread ids are process facts —
     both legally differ between byte-identical runs, so they are
-    normalized away; everything else must match event-for-event."""
+    normalized away, and with them what else is a timing or a fact of
+    the process: the on-CPU time a zone's end carries (`cpu_us`) and
+    the `runtime.*` instants (when the collector ran, a scope that
+    overran); everything else must match event-for-event."""
     out = []
     for ph, name, _ts, _tid, args, cid in list(recorder._buf):
+        if ph == "E":
+            args = None
+        elif ph == "i" and name.startswith("runtime."):
+            continue
         out.append((ph, name,
                     json.dumps(args, sort_keys=True, default=str)
                     if args is not None else "", cid or ""))

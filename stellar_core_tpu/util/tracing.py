@@ -30,6 +30,7 @@ nodes into distinct Perfetto process tracks.
 
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from collections import deque
@@ -47,9 +48,46 @@ _state_lock = threading.Lock()
 DEFAULT_CAPACITY = 262_144
 
 
+# seconds the collector has run while a recorder recorded, process-wide
+# and only ever growing: a scope reads it twice and takes the difference
+# (util/perf.py `log_slow_execution`)
+gc_seconds = 0.0
+_gc_t0 = 0.0
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """The one `gc.callbacks` entry, installed while a recorder records.
+    A collection can begin inside any allocation, also one made under a
+    registry's or a recorder's lock, so this takes no lock: it adds to
+    plain attributes, which `FlightRecorder.publish_gc` reports later."""
+    global gc_seconds, _gc_t0
+    if phase == "start":
+        _gc_t0 = time.perf_counter()
+        return
+    dt = time.perf_counter() - _gc_t0
+    gc_seconds += dt
+    generation = info["generation"]
+    for rec in tuple(_active):
+        if generation < 2:
+            # the zone is the collector that comes unasked: a full
+            # collection is somebody's call (`util/gcpolicy.py` keeps
+            # the automatic ones off), counted and shown by itself
+            rec._gc_count += 1
+            rec._gc_seconds += dt
+        elif rec._gc_gen2 is not None:
+            rec._gc_gen2.inc()
+        if generation >= 1:
+            rec.instant("runtime.gc", {
+                "generation": generation,
+                "collected": info["collected"],
+                "ms": round(dt * 1e3, 3)})
+
+
 def _retain(rec: "FlightRecorder") -> None:
     global ENABLED
     with _state_lock:
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
         _active.append(rec)
         ENABLED = True
 
@@ -60,6 +98,8 @@ def _release(rec: "FlightRecorder") -> None:
         if rec in _active:
             _active.remove(rec)
         ENABLED = bool(_active)
+        if not _active and _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
 
 
 def active_recorders() -> list:
@@ -76,7 +116,8 @@ class FlightRecorder:
     Events are compact tuples ``(ph, name, ts, tid, args, id)`` with
     ``ph`` one of the Chrome trace-event phases we emit:
 
-    - ``"B"``/``"E"`` — nested span begin/end on a thread track;
+    - ``"B"``/``"E"`` — nested span begin/end on a thread track; the
+      end of a zone's span carries the span's on-CPU time, `cpu_us`;
     - ``"i"`` — instant event (a point in time, e.g. one overlay send);
     - ``"b"``/``"e"`` — async track begin/end correlated by ``id``
       across threads (the tx end-to-end latency track).
@@ -97,6 +138,17 @@ class FlightRecorder:
         self._t0_wall = 0.0
         self._appended = 0
         self._lock = threading.Lock()   # start/stop/dump, not append
+        # the app's ZoneRegistry (util/perf.py), set by Application as
+        # the registry is handed this recorder: where the collector's
+        # count and seconds go while this records (zone `runtime.gc`:
+        # generations 0 and 1; counter `runtime.gc.gen2`: full ones)
+        self.registry = None
+        # written by `_on_gc` alone, and read by `publish_gc`, which
+        # alone writes what it has reported of them
+        self._gc_count = 0
+        self._gc_seconds = 0.0
+        self._gc_gen2 = None
+        self._gc_reported = (0, 0.0)
 
     # ----------------------------------------------------------- control --
     def start(self, capacity: Optional[int] = None) -> None:
@@ -116,6 +168,12 @@ class FlightRecorder:
             # (util/tracemerge.merge_trace_docs)
             self._t0_wall = time.time()
             if not self.active:
+                self._gc_count, self._gc_seconds = 0, 0.0
+                self._gc_reported = (0, 0.0)
+                metrics = getattr(self.registry, "metrics", None)
+                # made here: `_on_gc` may not take the registry's lock
+                self._gc_gen2 = None if metrics is None \
+                    else metrics.new_counter("runtime.gc.gen2")
                 self.active = True
                 _retain(self)
 
@@ -126,8 +184,24 @@ class FlightRecorder:
             if self.active:
                 self.active = False
                 _release(self)
+                self._publish_gc_locked()
             return {"events": len(self._buf), "dropped": self.dropped,
                     "capacity": self._capacity}
+
+    def publish_gc(self) -> None:
+        """Report what the collector has run since the last report into
+        the registry, as zone `runtime.gc` by `add`. Called by the
+        registry before it reports or resets, and by `stop()`."""
+        with self._lock:
+            self._publish_gc_locked()
+
+    def _publish_gc_locked(self) -> None:
+        if self.registry is None:
+            return
+        count, seconds = self._gc_count, self._gc_seconds
+        count0, seconds0 = self._gc_reported
+        self._gc_reported = (count, seconds)
+        self.registry.add("runtime.gc", seconds - seconds0, count - count0)
 
     @property
     def dropped(self) -> int:
@@ -153,10 +227,11 @@ class FlightRecorder:
         self._buf.append(("B", name, time.perf_counter() - self._t0,
                           threading.get_ident(), args, None))
 
-    def end(self, name: Optional[str] = None) -> None:
+    def end(self, name: Optional[str] = None,
+            args: Optional[dict] = None) -> None:
         self._appended += 1
         self._buf.append(("E", name, time.perf_counter() - self._t0,
-                          threading.get_ident(), None, None))
+                          threading.get_ident(), args, None))
 
     def instant(self, name: str, args: Optional[dict] = None) -> None:
         self._appended += 1
@@ -217,6 +292,8 @@ class FlightRecorder:
                 opened = stack.pop()
                 if name is None:
                     ev["name"] = opened["name"]
+                if args:
+                    ev["args"] = args   # a zone's `cpu_us`
             elif ph == "i":
                 ev["s"] = "t"       # thread-scoped instant
                 ev["args"] = args or {}

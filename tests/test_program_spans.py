@@ -10,6 +10,7 @@ compresses a segment) and publishes it, and fresh nodes that catch up
 from its archive with a `TpuBatchVerifier` whose kernel is a stand-in
 (all verdicts true, nothing traced or compiled)."""
 
+import gc
 import threading
 import time
 
@@ -41,6 +42,8 @@ def _no_leftover_tracing():
     with tracing._state_lock:
         del tracing._active[:]
         tracing.ENABLED = False
+        if tracing._on_gc in gc.callbacks:
+            gc.callbacks.remove(tracing._on_gc)
 
 
 def _events(app) -> list:
@@ -482,19 +485,22 @@ NEW_METRICS = ("crypto.prevalidated.hit", "crypto.prevalidated.miss",
 def tripwired_app(monkeypatch):
     """A started node on which every registry entry point of the new
     instrumentation raises: `ZoneRegistry.zone` / `add` for the new
-    names, and `inc` / `update` of the new metrics."""
+    names, `inc` / `update` of the new metrics, and the thread clock
+    (ISSUE 37: `recv_transaction` reads it only while a recorder
+    records, and then once a close; `verify_sig_uncached` never; a
+    zone reads it always, so no zone is on these paths either)."""
     app = Application.create(VirtualClock(ClockMode.VIRTUAL_TIME),
                              get_test_config())
     app.start()
     real_zone, real_add = ZoneRegistry.zone, ZoneRegistry.add
 
-    def zone(self, name, targs=None):
+    def zone(self, name, targs=None, sink=None):
         assert name not in NEW_ZONES, name
-        return real_zone(self, name, targs)
+        return real_zone(self, name, targs, sink)
 
-    def add(self, name, seconds, count=1):
+    def add(self, name, seconds, count=1, cpu_seconds=None):
         assert name not in NEW_ZONES, name
-        return real_add(self, name, seconds, count)
+        return real_add(self, name, seconds, count, cpu_seconds)
     monkeypatch.setattr(ZoneRegistry, "zone", zone)
     monkeypatch.setattr(ZoneRegistry, "add", add)
 
@@ -519,17 +525,24 @@ def _payment(app):
 @pytest.mark.parametrize("path", ["recv_transaction", "verify_sig",
                                   "PrevalidatedVerifier.__call__"])
 def test_per_item_paths_reach_no_new_registry_entry_point(tripwired_app,
-                                                          path):
+                                                          path,
+                                                          monkeypatch):
     app = tripwired_app
     assert tracing.ENABLED is False
     frame = _payment(app)
     pub, sig, msg = signature_checker.collect_signature_tuples([frame])[0]
     clear_verify_cache()
+
+    def thread_clock():
+        raise AssertionError("the thread clock was read on a per-item "
+                             "path with no recorder active")
+    monkeypatch.setattr(time, "thread_time", thread_clock)
     if path == "recv_transaction":
         from stellar_core_tpu.herder.tx_queue import AddResult
         assert app.herder.recv_transaction(frame) == \
             AddResult.ADD_STATUS_PENDING
         assert app.herder._recv_count == 1 and app.herder._recv_seconds > 0
+        assert app.herder._recv_run is None
     elif path == "verify_sig":
         assert keys.PubKeyUtils.verify_sig(pub, sig, msg)        # miss
         assert keys.PubKeyUtils.verify_sig(pub, sig, msg)        # hit

@@ -29,7 +29,10 @@ LOOKUPS = {"cell.zones.get", "cell.counters.get",
 METRIC_NEW = {"new_counter", "new_meter", "new_timer", "new_histogram"}
 METRIC_PARTS = {"counter", "meter", "timer", "histogram"}
 ZONE_OPEN = {"zone", "zone_into"}
-ZONE_ADD_RECEIVERS = {"perf", "default_registry"}
+ZONE_ADD_RECEIVERS = {"perf", "default_registry", "registry"}
+# `ZoneRegistry.report()` lists a zone's on-CPU seconds under the zone's
+# name with this suffix (util/perf.py ON_CPU): nothing opens that name
+ON_CPU = ".onCpu"
 
 
 def _python_files(top):
@@ -82,11 +85,15 @@ def _opened_by_call(node):
     return None
 
 
+def _is_zone_call(node):
+    return node.func.attr in ZONE_OPEN or node.func.attr == "add"
+
+
 @pytest.fixture(scope="module")
-def program_names():
-    """Every name `stellar_core_tpu/` opens as a zone, timer,
-    histogram, meter or counter."""
-    names = set()
+def program_opens():
+    """(every name `stellar_core_tpu/` opens as a zone, timer,
+    histogram, meter or counter; those of them that are zones)."""
+    names, zones = set(), set()
     for path in _python_files(PACKAGE):
         tree = _parse(path)
         for node in ast.walk(tree):
@@ -94,13 +101,27 @@ def program_names():
                 name = _opened_by_call(node)
                 if name and NAME.match(name):
                     names.add(name)
+                    if _is_zone_call(node):
+                        zones.add(name)
+                # util/perf.py `sched_lap(s0, metrics, on_cpu, run_delay)`
+                # makes the two timers it is given the names of
+                elif (_dotted(node.func) or "").split(".")[-1] \
+                        == "sched_lap":
+                    names.update(filter(None, map(_string, node.args[2:])))
             # util/jax_cache.py maps jax.monitoring events to zones
             elif isinstance(node, ast.Assign) and any(
                     isinstance(t, ast.Name) and t.id.endswith("_ZONES")
                     for t in node.targets) \
                     and isinstance(node.value, ast.Dict):
-                names.update(filter(None, map(_string, node.value.values)))
-    return names
+                found = set(filter(None, map(_string, node.value.values)))
+                names |= found
+                zones |= found
+    return names, zones
+
+
+@pytest.fixture(scope="module")
+def program_names(program_opens):
+    return program_opens[0]
 
 
 @pytest.fixture(scope="module")
@@ -155,7 +176,8 @@ def _reader_files():
 
 @pytest.mark.parametrize("reader", _reader_files())
 def test_layer_metric_reads_names_the_program_prints(
-        reader, program_names, bench_spans, cell_members, trace_members):
+        reader, program_opens, bench_spans, cell_members, trace_members):
+    program_names, program_zones = program_opens
     tree = _parse(os.path.join(READERS, reader))
     docstrings = {id(n.value) for n in ast.walk(tree)
                   if isinstance(n, ast.Expr) and _string(n.value) is not None}
@@ -180,6 +202,15 @@ def test_layer_metric_reads_names_the_program_prints(
             assert name in bench_spans, (
                 f"{reader} reads span {name!r}; benchmark/generators and "
                 f"benchmark/harness add {sorted(bench_spans)}")
+        elif name.endswith(ON_CPU):
+            # a derived name: tied to the zone the program opens under
+            # the base name, which the reader takes the wall of
+            base = name[:-len(ON_CPU)]
+            assert base in program_zones, (
+                f"{reader} reads {name!r}, and stellar_core_tpu/ opens "
+                f"no zone {base!r} whose on-CPU seconds it could be")
+            assert base in names, (
+                f"{reader} reads {name!r} and not the zone {base!r}")
         else:
             assert name in program_names, (
                 f"{reader} reads {name!r}, which stellar_core_tpu/ opens as "
@@ -279,7 +310,7 @@ def _borrowed_readers(suffix):
 
 
 @pytest.mark.parametrize("suffix,count", [(".dense.py", 13),
-                                          (".live.py", 1),
+                                          (".live.py", 3),
                                           (".range.py", 16)])
 def test_readers_that_borrow_a_reading_name_a_reader_that_exists(suffix,
                                                                  count):
@@ -297,8 +328,8 @@ def test_complete_wait_live_reads_the_barrier_zone_through_catchups_reader(
     `complete_wait_ms.catchup`'s reading, and that reader looks up the
     zone the close opens round its join of the previous tail; the zone
     the readers' join opens is the one `herder_self_ms.live` subtracts."""
-    assert dict(_borrowed_readers(".live.py")) == {
-        "complete_wait_ms.live.py": "complete_wait_ms.catchup"}
+    assert dict(_borrowed_readers(".live.py"))[
+        "complete_wait_ms.live.py"] == "complete_wait_ms.catchup"
     lender = _parse(os.path.join(READERS, "complete_wait_ms.catchup.py"))
     looked_up = {_string(n.args[0]) for n in ast.walk(lender)
                  if isinstance(n, ast.Call)
@@ -324,6 +355,84 @@ def test_complete_wait_live_reads_the_barrier_zone_through_catchups_reader(
         "source": "program_span", "layer": "ledger (close, apply)",
         "moves": "close_ms_p90",
         "workloads": ["standalone-pay1000.closed"]}]
+
+
+# what ISSUE 37 adds: six readers of what a thread ran and what it
+# stood still (the issue's two of `runtime.closing.runDelay` are not
+# there: the chip's host keeps no schedstat, PERF.md §7). {reader: (what
+# it looks up, the reader it borrows from, layer, the end-to-end metric
+# it moves)}
+# the dense and range cells are not listed: `benchmark/tests` pin the
+# number of their metrics (PERF.md §7), and that directory is the
+# benchmark's
+REPLAY_CELLS = ["catchup-pay1000.replay"]
+LIVE_CELLS = ["standalone-pay1000.closed"]
+LEDGER, HERDER = "ledger (close, apply)", "herder (admission, tx queue)"
+WAIT_READERS = {
+    "apply_wait_us_per_tx.replay": (
+        {"ledger.close.applyTx", "ledger.close.applyTx.onCpu"}, None,
+        LEDGER, "catchup_ledgers_per_s"),
+    "apply_wait_us_per_tx.live": (
+        set(), "apply_wait_us_per_tx.replay", LEDGER, "close_ms_p90"),
+    "admit_wait_us_per_tx.live": (
+        {"herder.recvTransaction", "herder.recvTransaction.onCpu"}, None,
+        HERDER, "applied_tx_per_s"),
+    "tail_wait_us_per_tx.replay": (
+        {"ledger.close.complete", "ledger.close.complete.onCpu"}, None,
+        LEDGER, "catchup_ledgers_per_s"),
+    "gc_us_per_tx.replay": (
+        {"runtime.gc"}, None, LEDGER, "catchup_ledgers_per_s"),
+    "gc_us_per_tx.live": (
+        set(), "gc_us_per_tx.replay", LEDGER, "close_ms_p90"),
+}
+
+
+@pytest.mark.parametrize("metric", sorted(WAIT_READERS))
+def test_wait_readers_look_up_what_the_program_reports(metric,
+                                                       program_opens):
+    """Each of ISSUE 37's readers looks up the names it is documented
+    to, a derived on-CPU name only beside the zone it is derived from
+    and only where the program opens that zone by `zone`, `zone_into`
+    or `add` (a name `report()` derives is opened by nothing), and is
+    entered in BENCHMARK.json for its cells."""
+    import json
+    names, zones = program_opens
+    looked_up, lender, layer, moves = WAIT_READERS[metric]
+    tree = _parse(os.path.join(READERS, metric + ".py"))
+    found = {_string(n.args[0]) for n in ast.walk(tree)
+             if isinstance(n, ast.Call) and _dotted(n.func) in LOOKUPS}
+    assert found == looked_up
+    for name in found:
+        if name.endswith(ON_CPU):
+            assert name not in names
+            assert name[:-len(ON_CPU)] in zones & found
+        else:
+            assert name in names
+    borrowed = dict(_borrowed_readers(metric.rsplit(".", 1)[1] + ".py"))
+    assert borrowed.get(metric + ".py") == lender
+    with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as fh:
+        entry = [m for m in json.load(fh)["per_layer"]
+                 if m["name"] == metric]
+    assert entry == [{
+        "name": metric, "unit": "us", "better": "lower",
+        "source": "program_span", "layer": layer, "moves": moves,
+        "workloads": LIVE_CELLS if metric.endswith(".live")
+        else REPLAY_CELLS}]
+
+
+# every zone, timer, counter and instant ISSUE 37 adds is opened by the
+# program and named in the operator's document and in PERF.md's table
+@pytest.mark.parametrize("name", [
+    "runtime.closing.onCpu", "runtime.closing.runDelay",
+    "runtime.completion.onCpu", "runtime.completion.runDelay",
+    "runtime.collect.onCpu", "runtime.collect.runDelay",
+    "runtime.gc", "runtime.gc.gen2", "runtime.stall"])
+def test_runtime_names_are_published_and_documented(name, program_names):
+    assert name in program_names, (
+        f"stellar_core_tpu/ opens no zone, timer or counter {name!r}")
+    for doc in ("docs/OBSERVABILITY.md", "PERF.md"):
+        with open(os.path.join(ROOT, doc), encoding="utf-8") as fh:
+            assert f"`{name}`" in fh.read(), f"{doc} does not name {name}"
 
 
 # ------------------------------------------------------------ documents --

@@ -3,9 +3,11 @@ Prometheus exposition, tx end-to-end latency, and the observability
 satellites (clearmetrics+zones, per-peer counters, Meter EWMA windows,
 the tracing-disabled cost contract)."""
 
+import gc
 import json
 import re
 import threading
+import time
 import tracemalloc
 
 import pytest
@@ -27,8 +29,10 @@ def _no_leftover_tracing():
     active recorder would make every other test pay for spans)."""
     yield
     with tracing._state_lock:
-        tracing._active_count = 0
+        del tracing._active[:]
         tracing.ENABLED = False
+        if tracing._on_gc in gc.callbacks:
+            gc.callbacks.remove(tracing._on_gc)
 
 
 # ------------------------------------------------------------ recorder --
@@ -84,6 +88,25 @@ def test_disabled_path_is_one_constant_check_no_alloc():
         pass
     assert len(rec) == 0
     assert reg.report()["z"]["count"] == 1
+    # ... and what only a recording pays for is not there: no entry of
+    # the collector's callbacks, no read of the thread clock round a
+    # host verify (a zone reads it always; the per-signature site
+    # never, the per-transaction one under the constant and once a
+    # close: tests/test_zone_cpu.py)
+    assert tracing._on_gc not in gc.callbacks
+    from stellar_core_tpu.crypto import keys
+    sk = keys.SecretKey.from_seed(b"\x09" * 32)
+    msg = b"d" * 32
+    sig = sk.sign(msg)
+    real = time.thread_time
+
+    def thread_clock():
+        raise AssertionError("thread clock read with no recorder active")
+    time.thread_time = thread_clock
+    try:
+        assert keys.verify_sig_uncached(sk.public_key().raw, sig, msg)
+    finally:
+        time.thread_time = real
 
 
 def test_zone_routes_spans_into_recorder():

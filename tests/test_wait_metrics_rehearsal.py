@@ -1,0 +1,149 @@
+"""ISSUE 37's six per-layer readers on the CPU, at tiny size: each is
+called on the `Cell` that a traced rehearsal of `tiny-standalone.tiny-closed`
+and of `tiny-catchup.tiny-replay` leaves (`benchmark/tests/rehearse.py`
+builds a root from copies of the benchmark plus its tiny cells; its list
+of the tiny cells' metrics is a file of the benchmark, so the readers are
+called from here and not through the result line). Each rehearsal runs in
+a process of its own with one CPU device, as `benchmark/tests` run them:
+under this directory's `conftest.py` the device check spreads over eight
+virtual devices and can overrun the tiny node's collect deadline. What
+comes back is seconds a CPU spent, held only to what must hold of any
+run: a number, not negative, on-CPU within wall."""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+from stellar_core_tpu.util.perf import ON_CPU
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+REPLAY = ["apply_wait_us_per_tx.replay", "tail_wait_us_per_tx.replay",
+          "gc_us_per_tx.replay"]
+LIVE = ["apply_wait_us_per_tx.live", "admit_wait_us_per_tx.live",
+        "gc_us_per_tx.live"]
+WALL = ["apply_us_per_tx.live", "apply_us_per_tx.catchup",
+        "herder_admit_us_per_tx.live"]
+CELLS = {"live": ("tiny-standalone.tiny-closed", LIVE),
+         "replay": ("tiny-catchup.tiny-replay", REPLAY)}
+
+CHILD = """
+import io, json, sys, tempfile
+sys.path.insert(0, sys.argv[1])
+from benchmark.tests import rehearse as R
+from stellar_core_tpu.util import perf
+seen, out = {}, io.StringIO()
+with tempfile.TemporaryDirectory(prefix="wait-metrics-") as tmp:
+    rc = R.rehearse(["--workload", sys.argv[2], "--seed", "4294967387",
+                     "--seconds", "2", "--trace", "1"], tmp, out=out,
+                    driver_hook=lambda d: seen.update(cell=d.cell))
+    cell = seen["cell"]
+    read = lambda m: cell.spec.layer_reader(m)(cell)
+    print(json.dumps({
+        "rc": rc, "doc": json.loads(out.getvalue().splitlines()[-1]),
+        "readings": {m: read(m) for m in sys.argv[3].split(",")},
+        "zones": cell.zones, "counters": sorted(cell.counters),
+        "schedstat": perf.thread_sched() is not None}))
+"""
+
+
+@pytest.fixture(scope="module")
+def children():
+    """Both rehearsals at once, each `python -c CHILD` with the
+    environment of `benchmark/tests/conftest.py`."""
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
+    procs = {
+        key: subprocess.Popen(
+            [sys.executable, "-c", CHILD, ROOT, workload,
+             ",".join(metrics + WALL)],
+            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
+            env=env, cwd=ROOT)
+        for key, (workload, metrics) in CELLS.items()}
+    results = {}
+    try:
+        for key, proc in procs.items():
+            stdout, stderr = proc.communicate(timeout=900)
+            assert proc.returncode == 0, stderr[-2000:]
+            results[key] = json.loads(stdout.splitlines()[-1])
+    finally:
+        for proc in procs.values():
+            if proc.poll() is None:
+                proc.kill()
+    return results
+
+
+@pytest.fixture(params=sorted(CELLS))
+def rehearsed(request, children):
+    got = children[request.param]
+    assert got["rc"] == 0 and got["doc"]["correct"] is True \
+        and got["doc"]["failed"] == 0
+    return request.param, got
+
+
+def test_every_new_reader_reports_a_number(rehearsed):
+    key, got = rehearsed
+    for metric in CELLS[key][1]:
+        value = got["readings"][metric]
+        if metric == "admit_wait_us_per_tx.live" and value is None:
+            # ledgers of 20 submissions, on a machine that runs the
+            # other tests beside: a hiccup between two calls takes a
+            # ledger's run out, and the reader wants half of them
+            count, _ = got["zones"]["herder.recvTransaction"]
+            measured, _ = got["zones"].get(
+                "herder.recvTransaction" + ON_CPU, (0, 0.0))
+            assert 2 * measured < count
+            continue
+        assert isinstance(value, float), (metric, value)
+        # (a difference of two means may read a hair under 0: below)
+        assert value >= 0.0 or metric == "admit_wait_us_per_tx.live"
+    assert got["zones"]["runtime.gc"][0] > 0
+    # the thread's account with the scheduler is kept where the host
+    # has one (the chip's has none, and no reader of the benchmark
+    # takes it: PERF.md §7)
+    for name in ("runtime.closing.onCpu", "runtime.closing.runDelay",
+                 "runtime.completion.onCpu", "runtime.completion.runDelay"):
+        assert (name in got["counters"]) == got["schedstat"]
+
+
+def test_on_cpu_lies_inside_wall(rehearsed):
+    """Of `ledger.close.applyTx`, and of every zone (each is opened and
+    closed on one thread): 0 <= on-CPU <= 1.02 x wall, and a millisecond
+    for the clocks' resolution over many short hits."""
+    _, got = rehearsed
+    zones = got["zones"]
+    derived = [z for z in zones if z.endswith(ON_CPU)]
+    assert "ledger.close.applyTx" + ON_CPU in derived
+    for name in derived:
+        count, wall = zones[name[:-len(ON_CPU)]]
+        measured, on_cpu = zones[name]
+        assert 0 <= measured <= count   # 0: no hit inside the window
+        if measured == count:
+            assert 0.0 <= on_cpu <= 1.02 * wall + 1e-3, name
+    count, wall = zones["ledger.close.applyTx"]
+    measured, on_cpu = zones["ledger.close.applyTx" + ON_CPU]
+    assert measured == count > 0 and 0.0 <= on_cpu <= 1.02 * wall
+
+
+def test_a_wait_is_no_longer_than_the_wall_of_its_zone(rehearsed):
+    key, got = rehearsed
+    r = got["readings"]
+    if key == "live":
+        if r["admit_wait_us_per_tx.live"] is not None:
+            # the run's on-CPU seconds may hold up to a fiftieth of the
+            # run that lay between the calls
+            assert -0.02 * r["herder_admit_us_per_tx.live"] \
+                <= r["admit_wait_us_per_tx.live"] \
+                <= r["herder_admit_us_per_tx.live"]
+        assert 0.0 <= r["apply_wait_us_per_tx.live"] \
+            <= r["apply_us_per_tx.live"]
+    else:
+        assert 0.0 <= r["apply_wait_us_per_tx.replay"] \
+            <= r["apply_us_per_tx.catchup"]
+    # the result line's own metrics are untouched by the derived names,
+    # which reach its zones as zones do
+    assert got["doc"]["metrics"]
+    assert any(z.endswith(ON_CPU) for z, _, _ in got["doc"]["host_zones_s"])
