@@ -76,7 +76,12 @@ def _on_gc(phase: str, info: dict) -> None:
             rec._gc_seconds += dt
         elif rec._gc_gen2 is not None:
             rec._gc_gen2.inc()
-        if generation >= 1:
+        if rec._gc_collected is not None:
+            rec._gc_collected.inc(info["collected"])
+        # under `util/gcpolicy.py` a young pass comes only when asked
+        # for and walks all a process keeps: a long one is written
+        # whatever its generation
+        if generation >= 1 or dt >= 1e-3:
             rec.instant("runtime.gc", {
                 "generation": generation,
                 "collected": info["collected"],
@@ -141,13 +146,15 @@ class FlightRecorder:
         # the app's ZoneRegistry (util/perf.py), set by Application as
         # the registry is handed this recorder: where the collector's
         # count and seconds go while this records (zone `runtime.gc`:
-        # generations 0 and 1; counter `runtime.gc.gen2`: full ones)
+        # generations 0 and 1; counter `runtime.gc.gen2`: full ones;
+        # counter `runtime.gc.collected`: the objects any of them freed)
         self.registry = None
         # written by `_on_gc` alone, and read by `publish_gc`, which
         # alone writes what it has reported of them
         self._gc_count = 0
         self._gc_seconds = 0.0
         self._gc_gen2 = None
+        self._gc_collected = None
         self._gc_reported = (0, 0.0)
 
     # ----------------------------------------------------------- control --
@@ -172,8 +179,11 @@ class FlightRecorder:
                 self._gc_reported = (0, 0.0)
                 metrics = getattr(self.registry, "metrics", None)
                 # made here: `_on_gc` may not take the registry's lock
-                self._gc_gen2 = None if metrics is None \
-                    else metrics.new_counter("runtime.gc.gen2")
+                self._gc_gen2 = self._gc_collected = None
+                if metrics is not None:
+                    self._gc_gen2 = metrics.new_counter("runtime.gc.gen2")
+                    self._gc_collected = metrics.new_counter(
+                        "runtime.gc.collected")
                 self.active = True
                 _retain(self)
 

@@ -426,7 +426,8 @@ def test_wait_readers_look_up_what_the_program_reports(metric,
     "runtime.closing.onCpu", "runtime.closing.runDelay",
     "runtime.completion.onCpu", "runtime.completion.runDelay",
     "runtime.collect.onCpu", "runtime.collect.runDelay",
-    "runtime.gc", "runtime.gc.gen2", "runtime.stall"])
+    "runtime.gc", "runtime.gc.gen2", "runtime.gc.collected",
+    "runtime.stall"])
 def test_runtime_names_are_published_and_documented(name, program_names):
     assert name in program_names, (
         f"stellar_core_tpu/ opens no zone, timer or counter {name!r}")

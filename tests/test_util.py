@@ -133,6 +133,9 @@ def test_gc_policy_install_and_collect():
         # an Application was built earlier in the suite: the policy
         # must already be live
         assert gc.get_threshold()[2] >= 1_000_000
+    # ISSUE 38: and generation 0's, so that no young pass falls inside
+    # a close on a count of allocations
+    assert gc.get_threshold()[0] == gcpolicy.YOUNG_THRESHOLD
 
     class Cyc:
         pass

@@ -472,7 +472,11 @@ def test_a_collection_is_a_zone_a_counter_and_an_instant(app):
     instants = [e for e in
                 app.flight_recorder.to_chrome_trace()["traceEvents"]
                 if e["ph"] == "i" and e["name"] == "runtime.gc"]
-    assert sorted({e["args"]["generation"] for e in instants}) == [1, 2]
+    # generations 1 and 2 always; one of generation 0 only if it took a
+    # millisecond or more (ISSUE 38: the long passes are its own now)
+    assert {1, 2} <= {e["args"]["generation"] for e in instants}
+    assert all(e["args"]["ms"] >= 1.0 for e in instants
+               if e["args"]["generation"] == 0)
     assert all(set(e["args"]) == {"generation", "collected", "ms"}
                for e in instants)
 
