@@ -139,6 +139,10 @@ class Herder:
         self._applicable_cache = {}   # txset hash -> (lcl seq, applicable)
         self._batch_pv_cache = {}     # txset hash -> (lcl seq, lazy pv)
         self._tx_set_valid_cache = {}  # (lcl hash, txset hash) -> bool
+        # txset hash -> perf_counter of its first `recv_tx_set`, taken
+        # back by the first verdict on it (`herder.txset.
+        # receivedToValidated`); a set nobody validates ages out
+        self._tx_set_received = {}
         self.trigger_timer = None
         self.catchup_manager = None   # set by Application
         self.out_of_sync_cb = None    # set by overlay manager
@@ -466,13 +470,24 @@ class Herder:
                 lcl_header, close_time, ltx=ltx_read)
 
     def externalize_value(self, ledger_seq: int, value: StellarValue,
-                          tx_set) -> None:
+                          tx_set, scp_history=None) -> None:
         """Apply an agreed value (reference: Herder::valueExternalized
         :380 → LedgerManager::valueExternalized)."""
-        lcd = LedgerCloseData(ledger_seq, tx_set, value)
+        lcd = LedgerCloseData(ledger_seq, tx_set, value, scp_history)
         kwargs = {}
-        if self._verify is not None:
-            kwargs["verify"] = self._verify
+        verify = self._verify
+        # a set this node validated against this LCL goes to apply with
+        # the verdicts that validation gathered (the device batch's and
+        # the verify cache's): apply asks the set's own table and not
+        # the process-wide verify cache, which evicts at random once it
+        # is full (0xffff entries: thirteen ledgers of 5,000) and would
+        # send what it dropped back to the native verifier
+        validated = self._batch_pv_cache.get(tx_set.get_contents_hash())
+        if validated is not None and validated[0] == ledger_seq - 1 \
+                and validated[1].ready:
+            verify = validated[1]
+        if verify is not None:
+            kwargs["verify"] = verify
         self.ledger_manager.close_ledger(lcd, **kwargs)
         targs = {"seq": ledger_seq} if tracing.ENABLED else None
         with self.perf.zone("herder.ledgerClosed", targs=targs):
@@ -726,6 +741,11 @@ class Herder:
         return self.pending_envelopes.get_qset(qh) if qh else None
 
     def recv_tx_set(self, tx_set_hash: bytes, tx_set) -> None:
+        received = self._tx_set_received
+        if tx_set_hash not in received:
+            if len(received) >= 4 * self.config.MAX_SLOTS_TO_REMEMBER:
+                received.clear()
+            received[tx_set_hash] = time.perf_counter()
         self.pending_envelopes.add_tx_set(tx_set_hash, tx_set)
         self.process_scp_queue()
 
@@ -793,39 +813,62 @@ class Herder:
         if cached is not None:
             return cached
         valid = self._check_tx_set_valid(tx_set_frame)
+        t_recv = self._tx_set_received.pop(h, None)
+        if t_recv is not None and self._metrics is not None:
+            self._metrics.new_timer(
+                "herder.txset.receivedToValidated").update(
+                    time.perf_counter() - t_recv)
         if len(self._tx_set_valid_cache) >= 1000:
             self._tx_set_valid_cache.clear()
         self._tx_set_valid_cache[key] = valid
         return valid
 
     def _check_tx_set_valid(self, tx_set_frame) -> bool:
-        applicable = self.applicable_for(tx_set_frame)
-        if applicable is None:
-            return False
-        verify = self._verify
-        if self.batch_verifier is not None:
-            # one device batch for the whole proposed set; per-signature
-            # results seed the lookup the per-tx checkValid consumes
-            # (reference collection point: txset validation,
-            # herder/TxSetUtils.cpp:200 — SURVEY.md §3.2). Lazy: the
-            # batch dispatches only when check_valid reaches its first
-            # signature (structurally invalid sets never pay for crypto)
-            # and is memoized per (txset hash, lcl) so a quorum's worth
-            # of envelopes re-validating the same set verify once.
-            h = tx_set_frame.get_contents_hash()
-            lcl_seq = self.ledger_manager.get_last_closed_ledger_num()
-            cached = self._batch_pv_cache.get(h)
-            if cached is None or cached[0] != lcl_seq:
-                lazy = _LazyBatchPrevalidator(self.batch_verifier,
-                                              applicable, verify)
-                for k in [k for k, (seq, _) in
-                          self._batch_pv_cache.items() if seq < lcl_seq]:
-                    del self._batch_pv_cache[k]
-                cached = (lcl_seq, lazy)
-                self._batch_pv_cache[h] = cached
-            verify = cached[1]
-        kwargs = {"verify": verify} if verify else {}
-        return applicable.check_valid(self.ledger_manager.root, **kwargs)
+        """One validation of a set against the LCL (a cached verdict
+        never comes here), under the zone `herder.txset.validate`. With
+        a device verifier the set's signatures go out as one batch
+        (`_LazyBatchPrevalidator`), whose counts are published here,
+        once a set: `herder.txset.prevalidate.cached` / `.dispatched` /
+        `.fallback`."""
+        lcl_seq = self.ledger_manager.get_last_closed_ledger_num()
+        targs = {"slot": lcl_seq + 1} if tracing.ENABLED else None
+        with self.perf.zone("herder.txset.validate", targs=targs):
+            applicable = self.applicable_for(tx_set_frame)
+            if applicable is None:
+                return False
+            if targs is not None:
+                targs["txs"] = applicable.size_tx()
+            verify = self._verify
+            lazy = None
+            if self.batch_verifier is not None:
+                # one device batch for the whole proposed set;
+                # per-signature results seed the lookup the per-tx
+                # checkValid consumes (reference collection point:
+                # txset validation, herder/TxSetUtils.cpp:200 —
+                # SURVEY.md §3.2). Lazy: the batch dispatches only when
+                # check_valid reaches its first signature (structurally
+                # invalid sets never pay for crypto) and is memoized
+                # per (txset hash, lcl) so a quorum's worth of
+                # envelopes re-validating the same set verify once.
+                h = tx_set_frame.get_contents_hash()
+                cached = self._batch_pv_cache.get(h)
+                if cached is None or cached[0] != lcl_seq:
+                    lazy = _LazyBatchPrevalidator(self.batch_verifier,
+                                                  applicable, verify)
+                    for k in [k for k, (seq, _) in
+                              self._batch_pv_cache.items()
+                              if seq < lcl_seq]:
+                        del self._batch_pv_cache[k]
+                    cached = (lcl_seq, lazy)
+                    self._batch_pv_cache[h] = cached
+                verify = cached[1]
+            kwargs = {"verify": verify} if verify else {}
+            try:
+                return applicable.check_valid(self.ledger_manager.root,
+                                              **kwargs)
+            finally:
+                if lazy is not None:
+                    lazy.publish(self._metrics)
 
     # ---------------------------------------------------------- triggering --
     def trigger_next_ledger_scp(self) -> None:
@@ -922,9 +965,9 @@ class Herder:
                 break
             sv, tx_set = buffered
             applicable = self.applicable_for(tx_set)
-            self.externalize_value(next_seq, sv, applicable)
+            self.externalize_value(next_seq, sv, applicable,
+                                   self._scp_history_rows(next_seq))
             applied += 1
-            self._persist_scp_history(next_seq)
             self._tx_sets_for_slot.pop(next_seq, None)
             self.pending_envelopes.slot_closed(
                 next_seq, self.config.MAX_SLOTS_TO_REMEMBER)
@@ -972,24 +1015,20 @@ class Herder:
             self.catchup_manager.maybe_trigger_catchup()
         self._arm_tracking_timer(OUT_OF_SYNC_RECOVERY_TIMER_SECONDS)
 
-    def _persist_scp_history(self, slot: int) -> None:
-        """Store the slot's externalizing envelopes + quorum sets
-        (reference: herder/HerderPersistence — scphistory/scpquorums
-        tables, republished in checkpoint scp files)."""
-        db = self.ledger_manager.db
-        if db is None or self.scp is None:
-            return
+    def _scp_history_rows(self, slot: int):
+        """The slot's externalizing envelopes and the quorum set, as the
+        rows of the scphistory and scpquorums tables (reference:
+        herder/HerderPersistence, republished in checkpoint scp files).
+        The close writes them in its own transaction: a statement of
+        this thread's after the close would meet the ledger's
+        completion tail, which holds the file for its history rows."""
+        if self.ledger_manager.db is None or self.scp is None:
+            return None
         from ..scp import local_node as ln
-        for env in self.scp.get_externalizing_state(slot):
-            db.execute(
-                "INSERT INTO scphistory (nodeid, ledgerseq, envelope) "
-                "VALUES (?,?,?)",
-                (ln.node_key(env.statement.nodeID), slot, env.to_bytes()))
         qset = self.scp.local_node.qset
-        db.execute(
-            "INSERT OR REPLACE INTO scpquorums "
-            "(qsethash, lastledgerseq, qset) VALUES (?,?,?)",
-            (ln.qset_hash(qset), slot, qset.to_bytes()))
+        return ([(ln.node_key(env.statement.nodeID), slot, env.to_bytes())
+                 for env in self.scp.get_externalizing_state(slot)],
+                [(ln.qset_hash(qset), slot, qset.to_bytes())])
 
     def reset_observability(self) -> None:
         """`clearmetrics` hook: drop the hash-keyed stamp dicts (tx
@@ -1080,7 +1119,13 @@ class Herder:
 class _LazyBatchPrevalidator:
     """Per-txset lazy device batch: dispatches the batch verify the first
     time a signature is actually checked, then serves per-signature
-    lookups; misses fall back to the sync path (exact semantics)."""
+    lookups; misses fall back to the sync path (exact semantics).
+
+    Of the set's signatures it counts `cached` (answered by the verify
+    cache), `dispatched` (sent to the device batch) and `fallback` (left
+    to the native per-signature path because that batch failed): all 0
+    for a set whose structure failed before any signature was reached.
+    `publish` adds them to a node's counters, once."""
 
     def __init__(self, batch_verifier, applicable, fallback):
         from ..tx.signature_checker import default_verify
@@ -1088,6 +1133,14 @@ class _LazyBatchPrevalidator:
         self._applicable = applicable
         self._fallback = fallback or default_verify
         self._pv = None
+        self.cached = self.dispatched = self.fallback = 0
+        self._published = False
+
+    @property
+    def ready(self) -> bool:
+        """Whether the set's signatures have been gathered: a table to
+        ask, not a batch still to dispatch."""
+        return self._pv is not None
 
     def __call__(self, pub: bytes, sig: bytes, msg: bytes) -> bool:
         if self._pv is None:
@@ -1108,6 +1161,7 @@ class _LazyBatchPrevalidator:
                 hit = probe_verify_cache(*t)
                 (missing if hit is None else cached).append(
                     (t, hit))
+            self.cached = len(cached)
             if cached:
                 pv.add_results([t for t, _ in cached],
                                [ok for _, ok in cached])
@@ -1122,16 +1176,34 @@ class _LazyBatchPrevalidator:
                     # cache instead of re-verifying natively
                     for (p, s, m), ok in zip(miss_tuples, results):
                         seed_verify_cache(p, s, m, ok)
+                    self.dispatched = len(miss_tuples)
                 except Exception:
                     # device verifier down: accept/reject semantics are
                     # identical on the native path, so validation
-                    # continues per-signature through the fallback
+                    # continues per-signature through the fallback,
+                    # counted (`herder.txset.prevalidate.fallback`)
+                    self.fallback = len(miss_tuples)
                     log.warning("batch verifier failed; falling back to "
-                                "native per-signature verify",
+                                "native per-signature verify of %d "
+                                "signatures", len(miss_tuples),
                                 exc_info=True)
             self._pv = pv
             self._applicable = None   # drop the reference once consumed
         return self._pv(pub, sig, msg)
+
+    def publish(self, metrics) -> None:
+        """Add the three counts to `herder.txset.prevalidate.cached` /
+        `.dispatched` / `.fallback` of `metrics`, once in this object's
+        life: the owner calls it after the one validation that made it."""
+        if self._published or metrics is None:
+            return
+        self._published = True
+        metrics.new_counter("herder.txset.prevalidate.cached").inc(
+            self.cached)
+        metrics.new_counter("herder.txset.prevalidate.dispatched").inc(
+            self.dispatched)
+        metrics.new_counter("herder.txset.prevalidate.fallback").inc(
+            self.fallback)
 
 
 def _qset_json(qset) -> dict:

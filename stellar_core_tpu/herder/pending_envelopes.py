@@ -77,6 +77,12 @@ class PendingEnvelopes:
                  request_qset: Optional[Callable[[bytes], None]] = None):
         self.network_id = network_id
         self._txsets: Dict[bytes, object] = {}     # hash -> TxSetFrame
+        # hash -> the highest slot an envelope named it for (reference:
+        # lastSeenSlotIndex); a set no envelope has named yet counts
+        # for the slot after the last one closed. A set leaves with
+        # that slot (`slot_closed`)
+        self._txset_slot: Dict[bytes, int] = {}
+        self._last_closed = 0
         self._qsets: Dict[bytes, SCPQuorumSet] = {}
         self._fetching: Dict[int, List[SCPEnvelope]] = {}
         self._ready: Dict[int, List[SCPEnvelope]] = {}
@@ -88,7 +94,12 @@ class PendingEnvelopes:
     # ------------------------------------------------------------- caches --
     def add_tx_set(self, tx_set_hash: bytes, tx_set) -> None:
         self._txsets[tx_set_hash] = tx_set
+        self._name_tx_set(tx_set_hash, self._last_closed + 1)
         self._recheck_fetching()
+
+    def _name_tx_set(self, tx_set_hash: bytes, slot: int) -> None:
+        if slot > self._txset_slot.get(tx_set_hash, 0):
+            self._txset_slot[tx_set_hash] = slot
 
     def add_scp_quorum_set(self, qset_hash: bytes,
                            qset: SCPQuorumSet) -> None:
@@ -106,9 +117,13 @@ class PendingEnvelopes:
 
     # -------------------------------------------------------------- state --
     def _missing_for(self, env: SCPEnvelope) -> Set[bytes]:
+        """What the envelope names and the node does not hold; every tx
+        set it names is noted for the envelope's slot."""
         st = env.statement
-        missing = {h for h in _statement_txset_hashes(st)
-                   if h not in self._txsets}
+        named = _statement_txset_hashes(st)
+        for h in named:
+            self._name_tx_set(h, st.slotIndex)
+        missing = {h for h in named if h not in self._txsets}
         qh = _statement_qset_hash(st)
         if qh is not None and qh not in self._qsets:
             missing.add(qh)
@@ -174,6 +189,16 @@ class PendingEnvelopes:
                   self._discarded):
             for s in [s for s in d if s < low]:
                 del d[s]
+        # tx sets too (reference: PendingEnvelopes::eraseBelow): a set
+        # leaves once the highest slot that named it is dropped above,
+        # so one a slot still in SCP needs stays however long ago it
+        # arrived (a node that lags holds sets for slots far ahead). A
+        # validator that follows holds every set it was sent otherwise,
+        # 5,000 parsed transactions a ledger
+        self._last_closed = max(self._last_closed, closed_slot)
+        for h in [h for h, s in self._txset_slot.items() if s < low]:
+            del self._txset_slot[h]
+            self._txsets.pop(h, None)
 
     def discard_slot(self, slot: int) -> None:
         self._fetching.pop(slot, None)

@@ -54,10 +54,16 @@ class LedgerCloseData:
     herder/LedgerCloseData.h): the sequence, the tx set, and the
     StellarValue (close time + upgrades + txset hash)."""
 
-    def __init__(self, ledger_seq: int, tx_set, value: StellarValue):
+    def __init__(self, ledger_seq: int, tx_set, value: StellarValue,
+                 scp_history=None):
         self.ledger_seq = ledger_seq
         self.tx_set = tx_set
         self.value = value
+        # the slot's externalizing SCP messages and the quorum set they
+        # name, as rows (herder `_scp_history_rows`): written in the
+        # close's own transaction, so they are on disk with the header
+        # they decided and before the ledger's tail may publish them
+        self.scp_history = scp_history
 
 
 def ledger_header_hash(header: LedgerHeader) -> bytes:
@@ -618,6 +624,7 @@ class LedgerManager:
                         self._store_header(closed)
                         self._persist_local_has(closed)
                         self._persist_lcl_hash()
+                        self._store_scp_history(lcd.scp_history)
             # the checkpoint's durable publishqueue row rides the close
             # transaction (HAS snapshotted at queue time, see
             # HistoryManager.snapshot_checkpoint): a crash on either
@@ -1199,6 +1206,20 @@ class LedgerManager:
             (ledger_header_hash(header), header.previousLedgerHash,
              header.ledgerSeq, header.scpValue.closeTime,
              header.to_bytes()))
+
+    def _store_scp_history(self, rows) -> None:
+        """reference: herder/HerderPersistence saveSCPHistory (the
+        scphistory / scpquorums tables, republished in a checkpoint's
+        scp files), here inside the close transaction."""
+        if self.db is None or not rows:
+            return
+        envelopes, quorums = rows
+        self.db.executemany(
+            "INSERT INTO scphistory (nodeid, ledgerseq, envelope) "
+            "VALUES (?,?,?)", envelopes)
+        self.db.executemany(
+            "INSERT OR REPLACE INTO scpquorums "
+            "(qsethash, lastledgerseq, qset) VALUES (?,?,?)", quorums)
 
     @staticmethod
     def _tx_history_rows(seq: int, applicable, txs, txset_bytes: bytes,

@@ -239,10 +239,6 @@ def test_txset_validation_uses_batch_verifier():
         app.manual_close()
 
         m1.submit(app, master.tx([op_payment(a.muxed, 1234)]))
-        calls = []
-        orig = app.batch_verifier.verify_tuples
-        app.batch_verifier.verify_tuples = \
-            lambda items: (calls.append(len(items)), orig(items))[1]
         lcl = app.ledger_manager.get_last_closed_ledger_header()
         from stellar_core_tpu.herder.tx_set import (
             SurgePricingLaneConfig, make_tx_set_from_transactions)
@@ -256,4 +252,7 @@ def test_txset_validation_uses_batch_verifier():
         from stellar_core_tpu.crypto.keys import clear_verify_cache
         clear_verify_cache()
         assert app.herder.is_tx_set_valid(frame)
-        assert calls and calls[0] >= 1
+        counts = {k: app.metrics.new_counter(
+            "herder.txset.prevalidate." + k).count
+            for k in ("cached", "dispatched", "fallback")}
+        assert counts == {"cached": 0, "dispatched": 1, "fallback": 0}

@@ -297,6 +297,30 @@ def test_range_replay_names_are_published_documented_and_read(
         assert f'"{name}"' in fh.read()
 
 
+# what ISSUE 39 publishes about a received tx set: each is read by a
+# `*.txset.py` reader (the three counters and the zone also decide
+# `correct` in the cell's driver, generators/txset_follow.py)
+TXSET_NAMES = {
+    "herder.txset.validate": "txset_validate_ms.txset.py",
+    "herder.txset.prevalidate.cached": "txset_cached_share.txset.py",
+    "herder.txset.prevalidate.dispatched": "txset_cached_share.txset.py",
+    "herder.txset.prevalidate.fallback": "txset_cached_share.txset.py",
+    "herder.txset.receivedToValidated": "received_to_validated_ms.txset.py",
+}
+
+
+@pytest.mark.parametrize("name", sorted(TXSET_NAMES))
+def test_txset_names_are_published_documented_and_read(name, program_names):
+    assert name in program_names, (
+        f"stellar_core_tpu/ opens no zone, timer or counter {name!r}")
+    for doc in ("docs/OBSERVABILITY.md", "PERF.md"):
+        with open(os.path.join(ROOT, doc), encoding="utf-8") as fh:
+            assert f"`{name}`" in fh.read(), f"{doc} does not name {name}"
+    with open(os.path.join(READERS, TXSET_NAMES[name]),
+              encoding="utf-8") as fh:
+        assert f'"{name}"' in fh.read()
+
+
 def _borrowed_readers(suffix):
     """(reader file, the reader whose code makes its reading) of every
     `*<suffix>` reader that calls `cell.spec.layer_reader`."""
@@ -320,6 +344,16 @@ def test_readers_that_borrow_a_reading_name_a_reader_that_exists(suffix,
     for reader, lender in borrowed:
         assert os.path.exists(os.path.join(READERS, lender + ".py")), reader
     assert len(borrowed) == count
+
+
+def test_txset_readers_that_borrow_a_reading_name_a_reader_that_exists():
+    """As above for `*.txset.py`, with no count held: a later PR gives
+    the cell a reader by adding a file."""
+    borrowed = list(_borrowed_readers(".txset.py"))
+    assert borrowed
+    for reader, lender in borrowed:
+        assert os.path.exists(os.path.join(READERS, lender + ".py")), reader
+        assert not lender.endswith(".txset")
 
 
 def test_complete_wait_live_reads_the_barrier_zone_through_catchups_reader(

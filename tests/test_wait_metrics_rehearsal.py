@@ -32,18 +32,34 @@ CELLS = {"live": ("tiny-standalone.tiny-closed", LIVE),
 
 # the hook lays CPython's own threshold on for the window: under
 # `util/gcpolicy.py`'s (ISSUE 38) a window of two seconds at tiny size
-# has no pass at all, and `gc_us_per_tx.*` would find nothing to read
+# has no pass at all, and `gc_us_per_tx.*` would find nothing to read.
+# It also gives the nodes a collect deadline of a minute for the
+# default's two seconds (the replay's through the cell's `node` table,
+# from which each replay builds a fresh node; the live cell's, which
+# set-up has built, on its supervisor): under `-n 6` the child shares
+# the machine with five workers and runs the profiler, one collect of
+# the replay's 101-signature batch (or of the live cell's device check)
+# overran 2,000 ms, the supervisor counted a timeout, and the run came
+# out `correct: false` by "supervisor complaints" (the driver's run of
+# PR 38's tree; ISSUE 39 found it by running the child beside other
+# tests). What these tests hold is the
+# readers' arithmetic, not the CPU's speed.
 CHILD = """
 import gc, io, json, sys, tempfile
 sys.path.insert(0, sys.argv[1])
 from benchmark.tests import rehearse as R
 from stellar_core_tpu.util import perf
 seen, out = {}, io.StringIO()
+def hook(d):
+    seen.update(cell=d.cell)
+    d.cell.config["node"].update(VERIFY_DISPATCH_DEADLINE_MS=60000.0)
+    if getattr(d, "app", None) is not None:
+        d.app.batch_verifier._deadline_s = 60.0
+    gc.set_threshold(700, 10, 10**6)
 with tempfile.TemporaryDirectory(prefix="wait-metrics-") as tmp:
     rc = R.rehearse(["--workload", sys.argv[2], "--seed", "4294967387",
                      "--seconds", "2", "--trace", "1"], tmp, out=out,
-                    driver_hook=lambda d: (seen.update(cell=d.cell),
-                                           gc.set_threshold(700, 10, 10**6)))
+                    driver_hook=hook)
     cell = seen["cell"]
     read = lambda m: cell.spec.layer_reader(m)(cell)
     print(json.dumps({
