@@ -10,7 +10,9 @@ requirement on the DB file).
 Tables created at `initialize()`:
   storestate      — PersistentState key/value (main/PersistentState.h)
   ledgerheaders   — one row per closed ledger (header XDR + hash)
-  txhistory/txfeehistory — applied transactions + fee changes per ledger
+  txhistory/txfeehistory — applied transactions + fee changes per ledger,
+                    keyed (ledgerseq, txindex) and indexed by nothing
+                    else: no reader goes by txid (schema v4)
   scphistory/scpquorums  — externalized SCP messages / quorum sets
   accounts/trustlines/offers/accountdata/claimablebalance/liquiditypool
                   — one table per classic ledger-entry type, keyed by the
@@ -41,14 +43,13 @@ log = get_logger("Database")
 # [MIN_SCHEMA_VERSION, SCHEMA_VERSION] has a stepwise
 # _apply_schema_upgrade so on-disk state survives software upgrades.
 MIN_SCHEMA_VERSION = 1
-SCHEMA_VERSION = 3
+SCHEMA_VERSION = 4
 
-# v2: transaction-hash lookup indexes. txhistory/txfeehistory key on
-# (ledgerseq, txindex); every by-txid read (HTTP tx-result lookups,
-# catchup acceptance checks) was a full scan on v1 databases.
+# v2: scphistory's by-ledger index (history/manager.py reads a
+# checkpoint's envelopes by ledgerseq). Until v4 this step also made
+# two transaction-hash indexes; it no longer does, so a v1 database
+# never builds them over its whole history for v4 to drop.
 SCHEMA_V2_STATEMENTS = (
-    "CREATE INDEX IF NOT EXISTS histbytxid ON txhistory (txid)",
-    "CREATE INDEX IF NOT EXISTS feehistbytxid ON txfeehistory (txid)",
     "CREATE INDEX IF NOT EXISTS scpenvsbyseq ON scphistory (ledgerseq)",
 )
 
@@ -59,6 +60,25 @@ SCHEMA_V3_STATEMENTS = (
     "CREATE TABLE IF NOT EXISTS publishqueue ("
     "ledgerseq INTEGER PRIMARY KEY, has TEXT)",
 )
+
+# v4: histbytxid and feehistbytxid go. Nothing in the tree reads
+# txhistory or txfeehistory by txid: history/manager.py and the
+# maintainer go by ledgerseq, the tables' own (ledgerseq, txindex) key
+# (upstream keys them the same way and has no txid index either). Each
+# was a b-tree keyed by a uniformly random 32-byte hash: past sqlite's
+# page cache every row of a close's completion tail was a random page
+# read and a random page written to the WAL, so the tail's transaction
+# grew with the history behind it (PERF.md §6, PR 40). A by-txid reader
+# that appears brings its index with it, and says what it costs the
+# tail. The txid columns stay and are written.
+SCHEMA_V4_STATEMENTS = (
+    "DROP INDEX IF EXISTS histbytxid",
+    "DROP INDEX IF EXISTS feehistbytxid",
+)
+
+# version -> the statements of the step that reaches it; each idempotent
+_SCHEMA_STEPS = {2: SCHEMA_V2_STATEMENTS, 3: SCHEMA_V3_STATEMENTS,
+                 4: SCHEMA_V4_STATEMENTS}
 
 _ENTRY_TABLES = ("accounts", "trustlines", "offers", "accountdata",
                  "claimablebalance", "liquiditypool", "contractdata",
@@ -119,6 +139,9 @@ def schema_statements() -> list:
     ]
     stmts.extend(SCHEMA_V2_STATEMENTS)   # fresh DBs start at the
     stmts.extend(SCHEMA_V3_STATEMENTS)   # current schema version
+    # no-ops on a fresh file; `new-db` over a v3 file keeps its tables
+    # (IF NOT EXISTS) and must not keep their txid indexes under v4
+    stmts.extend(SCHEMA_V4_STATEMENTS)
     return stmts
 
 
@@ -265,16 +288,12 @@ class SchemaMixin:
         """One pure-delta version step (reference:
         Database::applySchemaUpgrade, Database.cpp:208-265)."""
         log.info("applying schema upgrade to v%d", v)
-        if v == 2:
-            with self.transaction():
-                for stmt in SCHEMA_V2_STATEMENTS:
-                    self.execute(stmt)
-        elif v == 3:
-            with self.transaction():
-                for stmt in SCHEMA_V3_STATEMENTS:
-                    self.execute(stmt)
-        else:
+        stmts = _SCHEMA_STEPS.get(v)
+        if stmts is None:
             raise RuntimeError(f"unknown schema version {v}")
+        with self.transaction():
+            for stmt in stmts:
+                self.execute(stmt)
 
     def entry_tables(self) -> tuple:
         return _ENTRY_TABLES

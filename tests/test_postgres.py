@@ -89,6 +89,22 @@ def test_every_schema_statement_translates():
         assert t is not None and "BLOB" not in t and "?" not in t
 
 
+def test_schema_v4_step_translates_as_it_stands():
+    """The step that drops the two txid indexes is the same statements
+    on postgres, which has `DROP INDEX IF EXISTS` itself; the backend
+    takes it through the shared `_apply_schema_upgrade`."""
+    from stellar_core_tpu.db.database import (SCHEMA_V4_STATEMENTS,
+                                              SchemaMixin)
+    from stellar_core_tpu.db.postgres import PostgresDatabase
+    for stmt, want in zip(SCHEMA_V4_STATEMENTS, (
+            "DROP INDEX IF EXISTS histbytxid",
+            "DROP INDEX IF EXISTS feehistbytxid"), strict=True):
+        t = translate(stmt)
+        assert (t.sql, t.pre_deletes, t.n_params) == (want, [], 0)
+    assert PostgresDatabase._apply_schema_upgrade is \
+        SchemaMixin._apply_schema_upgrade
+
+
 def test_every_insert_or_replace_in_tree_has_conflict_keys():
     """Every INSERT OR REPLACE the node ever issues must be
     translatable — scan the source tree for table names."""
@@ -153,6 +169,39 @@ def pg_uri():
         yield srv.url()
     finally:
         srv.stop()
+
+
+def test_v3_database_upgrades_to_v4_on_postgres(pg_uri):
+    """The v3 -> v4 step through the postgres facade and libpq: the
+    version moves, the statements are accepted, the rows stay."""
+    from stellar_core_tpu.db.database import SCHEMA_VERSION
+    from stellar_core_tpu.db.postgres import PostgresDatabase
+    db = PostgresDatabase(pg_uri)
+    try:
+        # the three tables the step and its version touch (the whole
+        # DDL is 40 round trips; test_node_boots_... runs it)
+        from stellar_core_tpu.db.database import schema_statements
+        for stmt in schema_statements():
+            if stmt.startswith(tuple(
+                    f"CREATE TABLE IF NOT EXISTS {t} " for t in (
+                        "storestate", "txhistory", "txfeehistory"))):
+                db.execute(stmt)
+        db.execute("CREATE INDEX IF NOT EXISTS histbytxid "
+                   "ON txhistory (txid)")
+        db.execute("CREATE INDEX IF NOT EXISTS feehistbytxid "
+                   "ON txfeehistory (txid)")
+        db.put_schema_version(3)
+        db.execute(
+            "INSERT OR REPLACE INTO txhistory "
+            "(txid, ledgerseq, txindex, txbody, txresult, txmeta) "
+            "VALUES (?,?,?,?,?,?)", (b"h" * 32, 7, 0, b"b", b"r", b"m"))
+        db.upgrade_to_current_schema()
+        assert db.get_schema_version() == SCHEMA_VERSION == 4
+        rows = db.query_all(
+            "SELECT txresult FROM txhistory WHERE txid=?", (b"h" * 32,))
+        assert [bytes(r[0]) for r in rows] == [b"r"]
+    finally:
+        db.close()
 
 
 def test_stub_binding_roundtrip(pg_uri):
