@@ -191,6 +191,10 @@ class LedgerManager:
             # RestoreFootprint reaches the hot archive through the
             # LedgerTxn chain (protocol 23+ state archival)
             self.root.hot_archive = bucket_manager.hot_archive
+        # what a ledger's Soroban invocations count (soroban/host.py);
+        # published once a close, below
+        from ..soroban.host import SorobanApplyStats
+        self.root.soroban_stats = SorobanApplyStats()
         self._lcl_hash = b"\x00" * 32
         self._metrics = metrics
         if metrics is not None:
@@ -691,6 +695,7 @@ class LedgerManager:
             # by itself since the last close (crypto.verify.native,
             # crypto.verify.cache.hit/.miss)
             publish_verify_counts(self._metrics, self.perf)
+        self.root.soroban_stats.publish(self._metrics, self.perf)
         log.info("closed ledger %d (%d txs) hash %s", lcd.ledger_seq,
                  len(txs), self._lcl_hash.hex()[:16])
 

@@ -617,14 +617,22 @@ class CommandHandler:
 
     def _generate_load(self, params) -> dict:
         """reference: CommandHandler::generateLoad — synthesize load
-        (generateload?mode=create|pay|zipf|multisig_setup|multisig
-        &accounts=N&txs=N[&exponent=F]). `zipf` is the hot-account skew
+        (generateload?mode=create|pay|zipf|multisig_setup|multisig|
+        sac_setup|sac_auth&accounts=N&txs=N[&exponent=F][&relayed=F]).
+        `zipf` is the hot-account skew
         mode (ISSUE 16's Zipfian loadgen, ISSUE 20's matrix cell):
         rank-weighted source/destination draws, reproducible per node.
         `multisig_setup` installs the signers of the benchmark's four
         signer classes (close a ledger after it), `multisig` sends
         payments signed m-of-n, fee-bumped and with twenty signatures
-        as each source's class says."""
+        as each source's class says. `sac_setup` deploys the native
+        asset's Stellar Asset Contract (close a ledger after it) and
+        `sac_auth` sends `transfer` invocations of it, the share
+        `relayed` of them (0.8 by default) submitted by a third account
+        and authorized by `from`'s address-credential entry, which
+        carries the second signature; more than 100 of them a ledger
+        need `TESTING_SOROBAN_HIGH_LIMIT_OVERRIDE = true` at genesis
+        (`ledgerMaxTxCount`)."""
         from ..simulation.load_generator import LoadGenerator
         mode = params.get("mode", "create")
         if getattr(self, "_load_generator", None) is None:
@@ -659,6 +667,22 @@ class CommandHandler:
             else:
                 submitted = lg.generate_multisig(
                     int(params.get("txs", "100")))
+            return {"status": "ok", "mode": mode, "submitted": submitted}
+        if mode in ("sac_setup", "sac_auth"):
+            if len(lg.accounts) < 3:
+                return {"exception": "run generateload?mode=create with "
+                        "at least 3 accounts and close a ledger first"}
+            lg.sync_account_seqs()
+            if mode == "sac_setup":
+                self._sac_contract = lg.setup_sac()
+                return {"status": "ok", "mode": mode,
+                        "contract": self._sac_contract.hex()}
+            if getattr(self, "_sac_contract", None) is None:
+                return {"exception": "run generateload?mode=sac_setup "
+                        "and close a ledger first"}
+            submitted = lg.generate_sac_transfers(
+                self._sac_contract, int(params.get("txs", "100")),
+                relayed_share=float(params.get("relayed", "0.8")))
             return {"status": "ok", "mode": mode, "submitted": submitted}
         return {"exception": f"unknown load mode: {mode}"}
 

@@ -438,52 +438,74 @@ class LoadGenerator:
         return cid
 
     def generate_sac_transfers(self, cid: bytes, n: int,
-                               amount: int = 1000) -> int:
+                               amount: int = 1000,
+                               relayed_share: float = 0.0) -> int:
         """n native-SAC `transfer` invocations between generated
-        accounts — the wasm-VM/SAC analogue of PAY mode."""
+        accounts — the wasm-VM/SAC analogue of PAY mode.
+
+        `relayed_share` of them (evenly interleaved) are relayed: the
+        transaction's source and fee payer is `accounts[i]`, the funds
+        move from `accounts[i+1]` to `accounts[i+2]`, and `from`
+        authorizes with an address-credential entry of its own (CAP-
+        0046-11: its Ed25519 signature over the nonce'd invocation, a
+        nonce from this generator's seed, expiration 100 ledgers
+        ahead), whose nonce key sits in the read-write footprint. The
+        rest are self-signed: `from` is the source and a source-account
+        entry covers it."""
         from ..soroban import sac as sac_mod
-        from ..soroban.host import instance_key
+        from ..soroban.host import instance_key, nonce_key
         from ..xdr import contract as cx
         assert self.accounts, "run generate_accounts first"
         addr = cx.SCAddress(cx.SCAddressType.SC_ADDRESS_TYPE_CONTRACT,
                             cid)
+        expiration = \
+            self.app.ledger_manager.get_last_closed_ledger_num() + 100
+        count = len(self.accounts)
+        base = self.submitted
         ok = 0
         for i in range(n):
-            src = self.accounts[(self.submitted + i) % len(self.accounts)]
-            dst = self.accounts[(self.submitted + i + 1)
-                                % len(self.accounts)]
-            src_addr = cx.SCAddress(
-                cx.SCAddressType.SC_ADDRESS_TYPE_ACCOUNT, src.account_id)
+            relayed = int((i + 1) * relayed_share) > int(i * relayed_share)
+            src = self.accounts[(base + i) % count]
+            frm = self.accounts[(base + i + 1) % count] if relayed else src
+            dst = self.accounts[(base + i + (2 if relayed else 1)) % count]
+            from_addr = cx.SCAddress(
+                cx.SCAddressType.SC_ADDRESS_TYPE_ACCOUNT, frm.account_id)
             dst_addr = cx.SCAddress(
                 cx.SCAddressType.SC_ADDRESS_TYPE_ACCOUNT, dst.account_id)
-            args = [sac_mod._addr_scval(src_addr),
-                    sac_mod._addr_scval(dst_addr),
-                    sac_mod.sc_i128(amount)]
             invoke = cx.InvokeContractArgs(
                 contractAddress=addr, functionName=b"transfer",
-                args=list(args))
-            auth = cx.SorobanAuthorizationEntry(
-                credentials=cx.SorobanCredentials(
+                args=[sac_mod._addr_scval(from_addr),
+                      sac_mod._addr_scval(dst_addr),
+                      sac_mod.sc_i128(amount)])
+            invocation = cx.SorobanAuthorizedInvocation(
+                function=cx.SorobanAuthorizedFunction(
+                    cx.SorobanAuthorizedFunctionType
+                    .SOROBAN_AUTHORIZED_FUNCTION_TYPE_CONTRACT_FN,
+                    invoke),
+                subInvocations=[])
+            rw = [LedgerKey.account(frm.account_id),
+                  LedgerKey.account(dst.account_id)]
+            if relayed:
+                nonce = self._rng.getrandbits(63)
+                credentials = address_credentials(
+                    self.network_id, frm.key, from_addr, nonce,
+                    expiration, invocation)
+                rw.append(nonce_key(from_addr, nonce))
+            else:
+                credentials = cx.SorobanCredentials(
                     cx.SorobanCredentialsType
-                    .SOROBAN_CREDENTIALS_SOURCE_ACCOUNT),
-                rootInvocation=cx.SorobanAuthorizedInvocation(
-                    function=cx.SorobanAuthorizedFunction(
-                        cx.SorobanAuthorizedFunctionType
-                        .SOROBAN_AUTHORIZED_FUNCTION_TYPE_CONTRACT_FN,
-                        invoke),
-                    subInvocations=[]))
+                    .SOROBAN_CREDENTIALS_SOURCE_ACCOUNT)
+            auth = cx.SorobanAuthorizationEntry(
+                credentials=credentials, rootInvocation=invocation)
             body = _OperationBody(
                 OperationType.INVOKE_HOST_FUNCTION,
                 cx.InvokeHostFunctionOp(hostFunction=cx.HostFunction(
                     cx.HostFunctionType.HOST_FUNCTION_TYPE_INVOKE_CONTRACT,
                     invoke), auth=[auth]))
-            ro = [instance_key(addr)]
-            rw = [LedgerKey.account(src.account_id),
-                  LedgerKey.account(dst.account_id)]
             if self._sign_and_submit(
                     src, [Operation(sourceAccount=None, body=body)],
                     fee=100 + 10_000_000,
-                    ext=self._soroban_ext(ro, rw)) == \
+                    ext=self._soroban_ext([instance_key(addr)], rw)) == \
                     AddResult.ADD_STATUS_PENDING:
                 ok += 1
         return ok
@@ -658,6 +680,32 @@ class LoadGenerator:
 # levels deep enough not to spill during a bench window.
 
 BIGSTATE_LEVELS = (7, 8, 9, 10)
+
+
+def address_credentials(network_id: bytes, key: SecretKey, address,
+                        nonce: int, expiration: int, invocation):
+    """Address credentials (CAP-0046-11) of `address`, signed by `key`
+    over the SHA-256 of the `ENVELOPE_TYPE_SOROBAN_AUTHORIZATION`
+    preimage; the signature is the account contract's vector of
+    `{public_key, signature}` maps, one here."""
+    from ..soroban.host import soroban_auth_payload
+    from ..xdr import contract as cx
+    payload = soroban_auth_payload(network_id, nonce, expiration,
+                                   invocation)
+    sym = cx.SCValType.SCV_SYMBOL
+    signature = cx.SCVal(cx.SCValType.SCV_VEC, [cx.SCVal(
+        cx.SCValType.SCV_MAP, [
+            cx.SCMapEntry(key=cx.SCVal(sym, b"public_key"),
+                          val=cx.SCVal(cx.SCValType.SCV_BYTES,
+                                       key.public_key().raw)),
+            cx.SCMapEntry(key=cx.SCVal(sym, b"signature"),
+                          val=cx.SCVal(cx.SCValType.SCV_BYTES,
+                                       key.sign(payload)))])])
+    return cx.SorobanCredentials(
+        cx.SorobanCredentialsType.SOROBAN_CREDENTIALS_ADDRESS,
+        cx.SorobanAddressCredentials(
+            address=address, nonce=nonce,
+            signatureExpirationLedger=expiration, signature=signature))
 
 
 def bulk_account_id(i: int, tag: bytes = b"bigstate") -> bytes:

@@ -21,6 +21,7 @@ seam.
 from __future__ import annotations
 
 import struct
+import time
 from typing import Callable, Dict, List, Optional, Tuple
 
 from ..crypto.sha import sha256
@@ -108,6 +109,15 @@ def ttl_key_for(key: LedgerKey) -> LedgerKey:
     return LedgerKey.ttl(sha256(key.to_bytes()))
 
 
+def nonce_key(address: SCAddress, nonce: int) -> LedgerKey:
+    """The nonce entry an address-credential authorization of `address`
+    consumes: temporary contract data under the address itself."""
+    return LedgerKey.contract_data(
+        address, SCVal(SCValType.SCV_LEDGER_KEY_NONCE,
+                       SCNonceKey(nonce=nonce)),
+        ContractDataDurability.TEMPORARY)
+
+
 # --- pluggable execution -----------------------------------------------------
 
 # code-prefix -> callable(host, contract_addr, code, fn_name, args) -> SCVal
@@ -119,6 +129,56 @@ def register_vm(prefix: bytes):
         VM_REGISTRY[prefix] = fn
         return fn
     return deco
+
+
+class SorobanApplyStats:
+    """What a ledger's invocations did, in plain attributes (the sites
+    are per transaction and per signature): a `LedgerManager` hangs one
+    on its ledger root, `InvokeHostFunctionOpFrame` hands it to each
+    host, and `publish` turns it into zones and counters once a close.
+
+    `soroban.invoke` (zone): the host function calls, wall seconds;
+    `soroban.auth` (zone): the checks of address credentials inside
+    them (expiration, signer, signature, nonce consumption);
+    `soroban.auth.verify.prevalidated` / `.fallback`: auth signatures
+    answered by a verdict table that knew the tuple, and verified by
+    the fallback (a miss of the table, or no table);
+    `soroban.auth.entries.address` / `.source`: the authorization
+    entries of the invocations by credential type;
+    `soroban.auth.failed`: `require_auth` calls that raised."""
+
+    __slots__ = ("invoke_s", "invokes", "auth_s", "auth_checks",
+                 "prevalidated", "fallback", "address_entries",
+                 "source_entries", "failed")
+
+    def __init__(self):
+        self.reset()
+
+    def reset(self) -> None:
+        self.invoke_s = self.auth_s = 0.0
+        self.invokes = self.auth_checks = 0
+        self.prevalidated = self.fallback = 0
+        self.address_entries = self.source_entries = self.failed = 0
+
+    def publish(self, metrics, perf) -> None:
+        """Add what was counted since the last call to `perf`'s zones
+        and `metrics`' counters, and start again from zero."""
+        if not self.invokes:
+            return
+        if perf is not None:
+            perf.add("soroban.invoke", self.invoke_s, self.invokes)
+            perf.add("soroban.auth", self.auth_s, self.auth_checks)
+        if metrics is not None:
+            metrics.new_counter("soroban.auth.verify.prevalidated").inc(
+                self.prevalidated)
+            metrics.new_counter("soroban.auth.verify.fallback").inc(
+                self.fallback)
+            metrics.new_counter("soroban.auth.entries.address").inc(
+                self.address_entries)
+            metrics.new_counter("soroban.auth.entries.source").inc(
+                self.source_entries)
+            metrics.new_counter("soroban.auth.failed").inc(self.failed)
+        self.reset()
 
 
 class SorobanHost:
@@ -133,8 +193,9 @@ class SorobanHost:
 
     def __init__(self, ltx, header, config, footprint: LedgerFootprint,
                  budget: Budget, network_id: bytes,
-                 source_account: PublicKey, verify=None):
+                 source_account: PublicKey, verify=None, stats=None):
         self.ltx = ltx
+        self.stats = stats if stats is not None else SorobanApplyStats()
         self.header = header
         self.config = config
         self.budget = budget
@@ -350,7 +411,13 @@ class SorobanHost:
 
     # ---------------------------------------------------------------- auth --
     def set_auth_entries(self, entries) -> None:
+        from ..xdr.contract import SorobanCredentialsType
         self._auth_entries = list(entries)
+        address = sum(
+            1 for e in self._auth_entries if e.credentials.disc
+            == SorobanCredentialsType.SOROBAN_CREDENTIALS_ADDRESS)
+        self.stats.address_entries += address
+        self.stats.source_entries += len(self._auth_entries) - address
 
     def require_auth(self, address: SCAddress) -> None:
         """reference: host's require_auth — source-account credentials
@@ -378,9 +445,19 @@ class SorobanHost:
                 ac = cred.value
                 if ac.address.to_bytes() != ab:
                     continue
-                self._verify_address_credentials(entry, ac)
+                stats = self.stats
+                t0 = time.perf_counter()
+                try:
+                    self._verify_address_credentials(entry, ac)
+                except HostError:
+                    stats.failed += 1
+                    raise
+                finally:
+                    stats.auth_s += time.perf_counter() - t0
+                    stats.auth_checks += 1
                 self._authorized_addrs.append(ab)
                 return
+        self.stats.failed += 1
         raise HostError(SCErrorType.SCE_AUTH, "no authorization",
                         SCErrorCode.SCEC_INVALID_ACTION)
 
@@ -399,11 +476,20 @@ class SorobanHost:
             raise HostError(SCErrorType.SCE_AUTH, "missing signature")
         self.budget.charge(self.COST_VERIFY_SIG * len(sigs))
         verify = self.get_verify()
+        stats = self.stats
         for pub, sig in sigs:
             if pub != account_raw:
                 raise HostError(SCErrorType.SCE_AUTH,
                                 "signer is not the address")
-            if not verify(pub, sig, payload):
+            # a verdict table counts what it answered itself (`hits` of
+            # a PrevalidatedVerifier); anything else is the fallback's
+            hits = getattr(verify, "hits", None)
+            ok = verify(pub, sig, payload)
+            if hits is not None and verify.hits != hits:
+                stats.prevalidated += 1
+            else:
+                stats.fallback += 1
+            if not ok:
                 raise HostError(SCErrorType.SCE_AUTH, "bad signature")
         self._consume_nonce(ac)
 
@@ -439,11 +525,7 @@ class SorobanHost:
     def _consume_nonce(self, ac) -> None:
         """Replay protection: the nonce entry must not exist yet
         (reference: nonce consumption in soroban auth)."""
-        key = LedgerKey.contract_data(
-            ac.address,
-            SCVal(SCValType.SCV_LEDGER_KEY_NONCE,
-                  SCNonceKey(nonce=ac.nonce)),
-            ContractDataDurability.TEMPORARY)
+        key = nonce_key(ac.address, ac.nonce)
         if self.ltx.load_without_record(key) is not None:
             raise HostError(SCErrorType.SCE_AUTH, "nonce already used")
         self.ltx.create(LedgerEntry(
@@ -484,6 +566,15 @@ class SorobanHost:
 
     # ------------------------------------------------------------- dispatch --
     def invoke_host_function(self, host_fn: HostFunction, auth) -> SCVal:
+        stats = self.stats
+        t0 = time.perf_counter()
+        try:
+            return self._invoke_host_function(host_fn, auth)
+        finally:
+            stats.invoke_s += time.perf_counter() - t0
+            stats.invokes += 1
+
+    def _invoke_host_function(self, host_fn: HostFunction, auth) -> SCVal:
         self.set_auth_entries(auth)
         t = host_fn.disc
         if t == HostFunctionType.HOST_FUNCTION_TYPE_UPLOAD_CONTRACT_WASM:

@@ -80,7 +80,9 @@ class InvokeHostFunctionOpFrame(SorobanOpFrame):
         host_cls = host_for_protocol(header.ledgerVersion)
         host = host_cls(ltx, header, config, sd.resources.footprint,
                         budget, network_id, self.source_id,
-                        verify=getattr(ctx, "verify", None))
+                        verify=getattr(ctx, "verify", None),
+                        stats=getattr(ltx.get_root(), "soroban_stats",
+                                      None))
         try:
             result_val = host.invoke_host_function(
                 self.body.hostFunction, list(self.body.auth))

@@ -575,6 +575,10 @@ class TransactionFrame:
             ctx.soroban_data = self.soroban_data()
             ctx.fee_source_id = self.fee_source_id
             ctx.tx_size_bytes = len(self.envelope_bytes())
+            # the Soroban host asks the verifier this apply was given
+            # (a checkpoint's or a set's verdict table), as the
+            # envelope's signatures just did
+            ctx.verify = checker.verify
             op_metas = []
             for op in self.op_frames:
                 with LedgerTxn(ltx_tx) as ltx_op:

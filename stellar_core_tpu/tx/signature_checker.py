@@ -134,6 +134,11 @@ class SignatureChecker:
         self.used = [False] * len(self.signatures)
         self._verify = verify
 
+    @property
+    def verify(self) -> VerifyFn:
+        """The verifier every check of this checker asks."""
+        return self._verify
+
     def check_signature(self, signers: List[Tuple[SignerKey, int]],
                         needed_weight: int) -> bool:
         """signers: (signer key, weight). Matches the reference
@@ -258,9 +263,11 @@ def collect_signature_tuples(frames, network_id=None, ledger_state=None,
     `perf` opens the zone `crypto.collectTuples` round the collection
     (args `checkpoint`, `n`, `frames`) and `metrics` counts
     `crypto.collect.signatures` (decorated signatures seen),
-    `crypto.collect.candidates` (tuples made) and
+    `crypto.collect.candidates` (tuples made),
     `crypto.collect.carried` (those of them whose key `carried` alone
-    gave): once a call, so the per-transaction callers pass neither."""
+    gave) and `crypto.collect.auth` (those of them that are Soroban
+    auth-entry signatures): once a call, so the per-transaction callers
+    pass neither."""
     if perf is None:
         return _collect(frames, network_id, ledger_state, metrics, carried,
                         added)
@@ -367,7 +374,7 @@ def _collect(frames, network_id, ledger_state, metrics, carried=None,
         return table
 
     tuples = []
-    seen_signatures = from_carry = 0
+    seen_signatures = from_carry = auth = 0
     for signatures, h, named, frame in parts:
         seen_signatures += len(signatures)
         tables = [hints_of(a) for a in named]
@@ -384,12 +391,15 @@ def _collect(frames, network_id, ledger_state, metrics, carried=None,
             if carried_alone:
                 from_carry += sum(1 for k in keys if k in carried_alone)
         if network_id is not None and frame is not None:
+            n = len(tuples)
             tuples.extend(_soroban_auth_tuples(frame, network_id))
+            auth += len(tuples) - n
     if metrics is not None:
         metrics.new_counter("crypto.collect.signatures").inc(
             seen_signatures)
         metrics.new_counter("crypto.collect.candidates").inc(len(tuples))
         metrics.new_counter("crypto.collect.carried").inc(from_carry)
+        metrics.new_counter("crypto.collect.auth").inc(auth)
     return tuples
 
 

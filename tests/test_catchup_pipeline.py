@@ -242,7 +242,16 @@ def test_pipeline_crash_mid_apply_resumes_from_committed(tmp_path):
             chaos.uninstall()
         assert crashed
         # abandon the crashed process image (no shutdown — a crash
-        # doesn't get to run destructors); restart from the same files
+        # doesn't get to run destructors); restart from the same files.
+        # A real crash takes the image's threads with it; here they
+        # live on, and ledger 41's history tail, committing from the
+        # dead image's completion worker while the restarted node's
+        # `Database.initialize()` holds a read snapshot, is "database
+        # is locked" at once (a stale WAL snapshot, which no busy
+        # timeout waits out): seen under `-n 6`. So do to the image what
+        # a kill does: drop its queued tails, let the one in flight end.
+        app_b.ledger_manager.discard_pending_completion()
+        app_b.ledger_manager.join_completion(reraise=False)
         app_b2 = Application.create(VirtualClock(ClockMode.VIRTUAL_TIME),
                                     cfg)
         app_b2.start()

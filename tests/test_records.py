@@ -321,6 +321,38 @@ def test_txset_names_are_published_documented_and_read(name, program_names):
         assert f'"{name}"' in fh.read()
 
 
+# what ISSUE 41 publishes about Soroban authorization at apply: each is
+# read by a `*.auth.py` reader of its own or decides `correct` in the
+# cell's driver (generators/soroban_replay.py), and the two zones are
+# reported by `add`, once a close
+SOROBAN_NAMES = {
+    "soroban.invoke": "soroban_invoke_us_per_tx.auth.py",
+    "soroban.auth": "soroban_auth_us_per_tx.auth.py",
+    "soroban.auth.entries.address": "soroban_auth_us_per_tx.auth.py",
+    "soroban.auth.verify.prevalidated": "auth_prevalidated_share.auth.py",
+    "soroban.auth.verify.fallback": "auth_prevalidated_share.auth.py",
+    "crypto.collect.auth": "auth_tuple_share.auth.py",
+    "soroban.auth.entries.source": None,
+    "soroban.auth.failed": None,
+}
+
+
+@pytest.mark.parametrize("name", sorted(SOROBAN_NAMES))
+def test_soroban_names_are_published_documented_and_read(name,
+                                                         program_opens):
+    names, zones = program_opens
+    assert name in names, (
+        f"stellar_core_tpu/ opens no zone or counter {name!r}")
+    assert (name in zones) == (name in ("soroban.invoke", "soroban.auth"))
+    for doc in ("docs/OBSERVABILITY.md", "PERF.md"):
+        with open(os.path.join(ROOT, doc), encoding="utf-8") as fh:
+            assert f"`{name}`" in fh.read(), f"{doc} does not name {name}"
+    reader = SOROBAN_NAMES[name]
+    if reader is not None:
+        with open(os.path.join(READERS, reader), encoding="utf-8") as fh:
+            assert f'"{name}"' in fh.read()
+
+
 def _borrowed_readers(suffix):
     """(reader file, the reader whose code makes its reading) of every
     `*<suffix>` reader that calls `cell.spec.layer_reader`."""
@@ -354,6 +386,15 @@ def test_txset_readers_that_borrow_a_reading_name_a_reader_that_exists():
     for reader, lender in borrowed:
         assert os.path.exists(os.path.join(READERS, lender + ".py")), reader
         assert not lender.endswith(".txset")
+
+
+def test_auth_readers_that_borrow_a_reading_name_a_reader_that_exists():
+    """As above for `*.auth.py`, with no count held."""
+    borrowed = list(_borrowed_readers(".auth.py"))
+    assert borrowed
+    for reader, lender in borrowed:
+        assert os.path.exists(os.path.join(READERS, lender + ".py")), reader
+        assert not lender.endswith(".auth")
 
 
 def test_complete_wait_live_reads_the_barrier_zone_through_catchups_reader(

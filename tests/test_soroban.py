@@ -597,7 +597,7 @@ def test_auth_tuples_collected_for_batch(app):
                 signatureExpirationLedger=expiration,
                 signature=sig_val)),
         rootInvocation=root_inv)]
-    frame = soroban_tx(app, master, body, [], [])
+    frame = soroban_tx(app, master, body, *invoke_footprints(cid))
 
     tuples = collect_signature_tuples([frame], app.config.network_id())
     # envelope signature + the auth-entry signature
@@ -611,6 +611,20 @@ def test_auth_tuples_collected_for_batch(app):
     pv.add_results(tuples, [ref.verify(p, sg, ms) for p, sg, ms in tuples])
     assert pv(pub, s, m) is True
     assert pv.misses == 0
+    # ... and through apply, not only by hand: a close that is given the
+    # table hands it to the Soroban host (tests/test_soroban_auth.py
+    # holds the seam at a checkpoint's size)
+    lm = app.ledger_manager
+    close = lm.close_ledger
+    lm.close_ledger = lambda lcd, verify=None: close(lcd, verify=pv)
+    asked = pv.hits
+    pair = submit_and_close(app, frame)
+    from stellar_core_tpu.xdr.results import TransactionResultCode
+    assert pair.result.result.disc == TransactionResultCode.txSUCCESS
+    assert pv.misses == 0 and pv.hits > asked + 1
+    seen = app.metrics.to_json()
+    assert seen["soroban.auth.verify.prevalidated"]["count"] == 1
+    assert seen["soroban.auth.verify.fallback"]["count"] == 0
 
 
 def test_malformed_auth_signature_never_crashes(app):
