@@ -92,6 +92,11 @@ class Herder:
             # externalized in a closed ledger, on THIS node's clock
             self.tx_e2e_timer = metrics.timer("ledger", "transaction",
                                               "e2e")
+            # what the proposer's trim kept on a carried verdict and
+            # what it had to validate (herder/tx_set.py): there from
+            # the start, so that a reader tells 0 from no counter
+            metrics.new_counter("herder.trim.verdict.hit")
+            metrics.new_counter("herder.trim.verdict.miss")
         else:
             self._tx_recv_meter = self._tx_accept_meter = None
             self.tx_e2e_timer = None
@@ -247,9 +252,10 @@ class Herder:
             self._tx_recv_meter.mark()
         max_ops = (self.config.TRANSACTION_QUEUE_SIZE_MULTIPLIER
                    * self._max_tx_set_ops())
-        res = self.tx_queue.try_add(tx, self.ledger_manager.root, max_ops,
-                                    verify=verify if verify is not None
-                                    else self._verify)
+        res = self.tx_queue.try_add(
+            tx, self.ledger_manager.root, max_ops,
+            verify=verify if verify is not None else self._verify,
+            lcl_hash=self.ledger_manager.get_last_closed_ledger_hash())
         if res == AddResult.ADD_STATUS_PENDING:
             if self._tx_accept_meter is not None:
                 self._tx_accept_meter.mark()
@@ -426,7 +432,8 @@ class Herder:
             with self.perf.zone("herder.trimInvalid", targs=targs):
                 candidates, invalid = trim_invalid(
                     self.tx_queue.get_transactions(),
-                    self.ledger_manager.root, verify=self._verify)
+                    self.ledger_manager.root, verify=self._verify,
+                    metrics=self._metrics)
             if invalid:
                 # reference: Herder::triggerNextLedger bans trimInvalid's
                 # output so stale txs stop being re-validated every
@@ -880,7 +887,7 @@ class Herder:
         slot = lcl_header.ledgerSeq + 1
         candidates, invalid = trim_invalid(
             self.tx_queue.get_transactions(), self.ledger_manager.root,
-            verify=self._verify)
+            verify=self._verify, metrics=self._metrics)
         if invalid:
             self.tx_queue.ban(invalid)
         frame, applicable, _ = make_tx_set_from_transactions(

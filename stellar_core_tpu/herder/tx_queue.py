@@ -17,6 +17,7 @@ from __future__ import annotations
 from enum import Enum
 from typing import Dict, List, Optional
 
+from ..ledger.ledger_manager import ledger_header_hash
 from ..util.logging import get_logger
 from .surge_pricing import fee_rate_cmp
 
@@ -100,9 +101,11 @@ class TransactionQueue:
 
     # ----------------------------------------------------------- admission --
     def try_add(self, tx, ltx_root, max_queue_ops: int,
-                verify=None) -> AddResult:
+                verify=None, lcl_hash: Optional[bytes] = None) -> AddResult:
         """Admit a tx after validation (reference: TransactionQueue::tryAdd
-        → canAdd → TransactionFrame::checkValid)."""
+        → canAdd → TransactionFrame::checkValid). `lcl_hash` is the hash
+        of `ltx_root`'s header where the caller holds it (the herder
+        does; hashed here otherwise)."""
         h = tx.full_hash()
         if self.is_banned(h):
             return AddResult.ADD_STATUS_TRY_AGAIN_LATER
@@ -137,6 +140,14 @@ class TransactionQueue:
             ltx.rollback()
         if not ok:
             return AddResult.ADD_STATUS_ERROR
+        # the verdict rides with the frame (TransactionFrame.
+        # verdict_key): the trim of this LCL's set asks the same
+        # question and does not validate again. A frame that can carry
+        # one has no `minSeqNum`, so the sequence number just seen is
+        # the one before its own (`_is_bad_seq`)
+        if lcl_hash is None:
+            lcl_hash = ledger_header_hash(ltx_root.get_header())
+        tx.valid_at = tx.verdict_key(lcl_hash, tx.seq_num - 1)
         # capacity: the replaced tx's ops are already freed (it can't be
         # picked for eviction and doesn't count against the limit), but it
         # is only dropped once admission is certain

@@ -212,7 +212,7 @@ def _header_hash(header) -> bytes:
 
 
 def walk_tx_chains(txs: Sequence[TransactionFrame], ltx_parent, verify,
-                   stop_on_first: bool = False
+                   stop_on_first: bool = False, metrics=None
                    ) -> Tuple[List[TransactionFrame],
                               List[TransactionFrame]]:
     """Per-account seqnum-chain validation walk shared by txset
@@ -221,40 +221,76 @@ def walk_tx_chains(txs: Sequence[TransactionFrame], ltx_parent, verify,
     Only the first tx of a chain is checked against the live account
     seqnum; accepted txs consume their seqnum so followers must be
     contiguous. Returns (kept, dropped); with stop_on_first the walk
-    aborts at the first invalid tx (validation mode)."""
+    aborts at the first invalid tx (validation mode).
+
+    The trim keeps, without validating it again, a frame that carries
+    the verdict of this very question (`TransactionFrame.verdict_key`:
+    this LCL, this sequence number in the scratch txn; queue admission
+    left it, or an earlier trim), and leaves the verdict on what it
+    validates itself. Validation mode does neither: a received set's
+    frames are made from the wire and carry none. `metrics`, if given,
+    takes the trim's `herder.trim.verdict.hit` / `.miss`."""
     from ..ledger.ledger_txn import LedgerTxn
     from ..tx.signature_checker import default_verify
+    from ..xdr.ledger_entries import LedgerKey
     verify = verify or default_verify
     by_acct: Dict[bytes, List[TransactionFrame]] = {}
     for t in txs:
         by_acct.setdefault(t.source_id.to_bytes(), []).append(t)
     kept: List[TransactionFrame] = []
     dropped: List[TransactionFrame] = []
+    lcl_hash = None if stop_on_first \
+        else _header_hash(ltx_parent.get_header())
+    hits = 0
     with LedgerTxn(ltx_parent) as ltx:
         for chain in by_acct.values():
             chain.sort(key=lambda t: t.seq_num)
+            # the source's sequence number in the scratch txn, followed
+            # here and not read back a frame: only this chain moves it
+            seq_now = None
+            if lcl_hash is not None:
+                source = ltx.load_without_record(
+                    LedgerKey.account(chain[0].source_id))
+                if source is not None:
+                    seq_now = source.data.value.seqNum
             for t in chain:
-                if t.check_valid(ltx, current=0, verify=verify):
+                key = None
+                if lcl_hash is not None and seq_now is not None:
+                    key = t.verdict_key(lcl_hash, seq_now)
+                if key is not None and t.valid_at == key:
+                    hits += 1
+                    # nobody reads the scratch number after the last
+                    if t is not chain[-1]:
+                        t._process_seq_num(ltx)
+                elif t.check_valid(ltx, current=0, verify=verify):
+                    if key is not None:
+                        t.valid_at = key
                     t._process_seq_num(ltx)
-                    kept.append(t)
                 else:
                     dropped.append(t)
                     if stop_on_first:
                         ltx.rollback()
                         return kept, dropped
+                    continue
+                kept.append(t)
+                seq_now = t.seq_num
         ltx.rollback()
+    if metrics is not None and lcl_hash is not None:
+        metrics.new_counter("herder.trim.verdict.hit").inc(hits)
+        metrics.new_counter("herder.trim.verdict.miss").inc(
+            len(txs) - hits)
     return kept, dropped
 
 
-def trim_invalid(txs: Sequence[TransactionFrame], ltx_root, verify=None
-                 ) -> Tuple[List[TransactionFrame],
-                            List[TransactionFrame]]:
+def trim_invalid(txs: Sequence[TransactionFrame], ltx_root, verify=None,
+                 metrics=None) -> Tuple[List[TransactionFrame],
+                                        List[TransactionFrame]]:
     """Split candidates into (valid, invalid) against the LCL state in
     `ltx_root` (reference: TxSetUtils::trimInvalid,
     herder/TxSetUtils.cpp:200 — run on the proposer's queue snapshot
     before surge pricing so a stale-invalid tx can never reach a
     nominated set; the herder bans the invalid remainder)."""
-    return walk_tx_chains(txs, ltx_root, verify)
+    return walk_tx_chains(txs, ltx_root, verify, metrics=metrics)
 
 
 def make_tx_set_from_transactions(

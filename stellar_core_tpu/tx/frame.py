@@ -76,6 +76,11 @@ def _v0_to_v1_tx(v0tx) -> Transaction:
 
 
 class TransactionFrame:
+    # the `verdict_key` under which `check_valid` last answered True,
+    # left and read by the herder (queue admission, the proposer's
+    # trim); None: never, or a kind that carries none
+    valid_at: Optional[tuple] = None
+
     def __init__(self, envelope: TransactionEnvelope, network_id: bytes):
         releaseAssert(
             envelope.disc != EnvelopeType.ENVELOPE_TYPE_TX_FEE_BUMP,
@@ -443,6 +448,30 @@ class TransactionFrame:
                 self.set_error(TransactionResultCode.txBAD_AUTH_EXTRA)
                 return False
         return True
+
+    def verdict_key(self, lcl_hash: bytes, seq_seen: Optional[int],
+                    lb_offset: int = 0, ub_offset: int = 0,
+                    charge_fee: bool = True) -> Optional[tuple]:
+        """Everything `check_valid`'s answer is a function of, for the
+        frames of which that can be said from the envelope alone: the
+        hash of the LCL header the ledger txn stood on (it commits to
+        the bucket list, so to every account, signer, threshold and
+        balance), the source account's sequence number as the call saw
+        it (the account's own, or a queued predecessor's consumed in
+        the caller's scratch txn), and the arguments passed. None for
+        every other frame, which is validated each time: one with a
+        precondition (time and ledger bounds are held against the
+        offsets, `minSeqAge` and `minSeqLedgerGap` against what a
+        predecessor's consumption writes beside the number, extra
+        signers are a second signer set; `minSeqNum` alone would do,
+        but then the number seen is not implied by the frame's own), a
+        Soroban transaction (the network's settings, its footprint)
+        and a fee bump (a second account). docs/CLOSE_PIPELINE.md,
+        "The verdict rides with the frame"."""
+        if self.tx.cond.disc != PreconditionType.PRECOND_NONE \
+                or self.is_soroban() or self.is_fee_bump():
+            return None
+        return (lcl_hash, seq_seen, lb_offset, ub_offset, charge_fee)
 
     # ------------------------------------------------------------ fee stage --
     def process_fee_seq_num(self, ltx_outer,

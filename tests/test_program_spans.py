@@ -197,6 +197,7 @@ def runs(tmp_path_factory):
     ("standalone", "herder.recvTransaction"),
     ("standalone", "herder.triggerNextLedger"),
     ("standalone", "herder.trimInvalid"),
+    ("standalone", "herder.trim.verdict.hit"),
     ("standalone", "herder.makeTxSet"),
     ("standalone", "herder.ledgerClosed"),
     ("standalone", "herder.joinCompletion"),
@@ -223,6 +224,20 @@ def test_recv_transaction_zone_counts_every_call(runs):
     # 5 account creations + 3 ledgers x 5 payments
     assert runs["standalone"]["herder.recvTransaction"] == 20
     assert runs["standalone"]["herder.triggerNextLedger"] == CHECKPOINT - 1
+
+
+def test_trim_verdict_counters_sit_beside_the_trim_zone(runs):
+    # ISSUE 42: every transaction of the rehearsal is admitted and
+    # trimmed at one LCL (the five creations are one account's chain),
+    # so each is kept on its verdict; the miss counter is there and 0
+    seen = runs["standalone"]
+    assert seen["herder.trim.verdict.hit"] == 20
+    assert seen["herder.trim.verdict.miss"] == 0
+    assert seen["herder.trimInvalid"] == CHECKPOINT - 1
+    spans = [ev for ev in runs["standalone_events"]
+             if ev["ph"] == "B" and ev["name"] == "herder.trimInvalid"]
+    assert [ev["args"]["seq"] for ev in spans] == \
+        list(range(2, CHECKPOINT + 1))
 
 
 def test_prevalidated_counts_are_the_checks_apply_made(runs):
