@@ -216,6 +216,10 @@ class LedgerManager:
                 "ledger", "close", "tail", "hidden")
             self._tail_waited = metrics.counter(
                 "ledger", "close", "tail", "waited")
+            # once a close: the root's lookups since the last one that
+            # neither its cache nor a prefetch answered
+            self._root_point_sql = metrics.counter(
+                "ledger", "root", "point", "sql")
         else:
             self.tx_apply_timer = None
             self.ledger_close_timer = None
@@ -224,6 +228,7 @@ class LedgerManager:
             self.apply_stage_width_hist = None
             self.apply_conflict_hist = None
             self._tail_hidden = self._tail_waited = None
+            self._root_point_sql = None
 
     # ------------------------------------------------------------ LCL state --
     def get_last_closed_ledger_header(self) -> LedgerHeader:
@@ -541,9 +546,18 @@ class LedgerManager:
                     self._chaos_crash_point("ledger.close.crash.fees",
                                             lcd.ledger_seq)
                 # Phase 2: the apply loop (reference: applyTransactions)
+                # Soroban operations of this close share one network
+                # configuration, read at the first of them; it is gone
+                # before the upgrades below can change a setting, and
+                # a close that raises leaves none behind
+                shared = self.root.soroban_stats
                 with self.perf.zone_into("ledger.close.applyTx", phases):
-                    result_pairs, tx_metas = self._apply_transactions(
-                        ltx, applicable, txs, verify, footprints)
+                    shared.config = shared.UNREAD
+                    try:
+                        result_pairs, tx_metas = self._apply_transactions(
+                            ltx, applicable, txs, verify, footprints)
+                    finally:
+                        shared.config = None
                 if chaos.ENABLED:
                     self._chaos_crash_point("ledger.close.crash.applyTx",
                                             lcd.ledger_seq)
@@ -696,6 +710,9 @@ class LedgerManager:
             # crypto.verify.cache.hit/.miss)
             publish_verify_counts(self._metrics, self.perf)
         self.root.soroban_stats.publish(self._metrics, self.perf)
+        if self._root_point_sql is not None:
+            self._root_point_sql.inc(self.root.point_reads)
+            self.root.point_reads = 0
         log.info("closed ledger %d (%d txs) hash %s", lcd.ledger_seq,
                  len(txs), self._lcl_hash.hex()[:16])
 

@@ -404,6 +404,8 @@ class InMemoryLedgerTxnRoot(AbstractLedgerTxnParent):
     --in-memory mode and tests).  Entries are stored as objects and
     handed out shared; commits adopt the child's objects."""
 
+    point_reads = 0     # see LedgerTxnRoot: there is no store to read
+
     def __init__(self, header: Optional[LedgerHeader] = None):
         self._entries: Dict[bytes, LedgerEntry] = {}
         self._header = header or LedgerHeader()
@@ -521,10 +523,19 @@ class LedgerTxnRoot(AbstractLedgerTxnParent):
 
     The entry cache holds DECODED LedgerEntry objects (or _ABSENT
     negatives) handed out as shared snapshots — the load path clones
-    exactly once at the LedgerTxn that records the entry.  Values
-    prefetched in bulk are kept as raw bytes and decoded lazily on
-    first access (reference analogue: the entry cache fed by
-    prefetch, LedgerTxnRoot.h)."""
+    exactly once at the LedgerTxn that records the entry.
+
+    What `prefetch` reads in bulk is held beside the cache
+    (`_prefetched`: raw bytes, decoded on first access and then cached)
+    until the next commit, and not in it: the cache is bounded and
+    evicts at random, so once it is full it has room for no key set,
+    and a close whose ledger outgrows it (a contract ledger's nonce
+    and TTL keys are new every time) would pay a point SELECT for
+    every key it had asked for ahead (reference analogue: the entry
+    cache fed by prefetch, LedgerTxnRoot.h, which upstream empties at
+    every commit).  `point_reads` counts the lookups that fell through
+    both to a point SELECT (SQL only: one the bucket list answers is
+    not counted)."""
 
     def __init__(self, db, header: Optional[LedgerHeader] = None,
                  cache_size: int = 4096):
@@ -533,6 +544,10 @@ class LedgerTxnRoot(AbstractLedgerTxnParent):
         self._header = header or LedgerHeader()
         self._child = None
         self._cache: "RandomEvictionCache" = RandomEvictionCache(cache_size)
+        # kb -> raw entry bytes, a decoded entry or _ABSENT: the answers
+        # of `prefetch` since the last commit
+        self._prefetched: Dict[bytes, object] = {}
+        self.point_reads = 0
         self._bucket_list = None
         # state-archival lookup hook (protocol 23+): set by the
         # LedgerManager so RestoreFootprint can consult the hot archive
@@ -574,8 +589,10 @@ class LedgerTxnRoot(AbstractLedgerTxnParent):
         the bucket indexes (bloom-gated, newest level first) instead of
         SQL.  Offers stay in SQL — the order book needs its range
         queries, exactly as the reference keeps offers in the database
-        under BucketListDB."""
+        under BucketListDB.  What a prefetch took from SQL before
+        the switch is dropped: a miss there is no miss in the buckets."""
         self._bucket_list = bucket_list
+        self._prefetched = {}
 
     # ------------------------------------------------------------- entries --
     @staticmethod
@@ -587,13 +604,19 @@ class LedgerTxnRoot(AbstractLedgerTxnParent):
 
     def _lookup(self, kb: bytes) -> Optional[LedgerEntry]:
         hit = self._cache.maybe_get(kb)
-        if hit is not None:
-            if hit is _ABSENT:
-                return None
-            if hit.__class__ is bytes:        # lazily decode prefetches
-                hit = LedgerEntry.from_bytes(hit)
+        if hit is None:
+            hit = self._prefetched.get(kb)
+            if hit is None:
+                return self._read_through(kb)
+            if hit is not _ABSENT:
+                if hit.__class__ is bytes:    # lazily decode prefetches
+                    hit = self._prefetched[kb] = LedgerEntry.from_bytes(hit)
                 self._cache.put(kb, hit)
-            return hit
+        return None if hit is _ABSENT else hit
+
+    def _read_through(self, kb: bytes) -> Optional[LedgerEntry]:
+        """One key from the store, cached: what neither the cache nor
+        a prefetch answered."""
         if self._bucket_list is not None \
                 and not kb.startswith(_OFFER_KB_PREFIX):
             from ..xdr.ledger import BucketEntryType
@@ -604,6 +627,7 @@ class LedgerTxnRoot(AbstractLedgerTxnParent):
             e = be.value
             self._cache.put(kb, e)
             return e
+        self.point_reads += 1
         row = self._db.query_one(
             f"SELECT entry FROM {self._table_for(kb)} WHERE key=?", (kb,))
         if row:
@@ -614,30 +638,36 @@ class LedgerTxnRoot(AbstractLedgerTxnParent):
         return None
 
     def prefetch(self, keys) -> int:
-        """Batch-load entries into the root cache: one SELECT ... IN (...)
-        per table instead of a query per key (reference: LedgerTxnRoot
-        prefetch + prefetchTxSourceIds, LedgerManagerImpl.cpp:805).
-        Stops inserting near the cache cap so a huge key set cannot
-        thrash out its own (or hot, unrelated) entries. Returns the
-        number of keys now cached."""
-        budget = self._cache.max_size - len(self._cache)
+        """Batch-load entries ahead of their lookups: one SELECT ... IN
+        (...) per table instead of a query per key (reference:
+        LedgerTxnRoot prefetch + prefetchTxSourceIds,
+        LedgerManagerImpl.cpp:805). The answers, misses too, stay in
+        `_prefetched` until the next commit; one that is looked up
+        moves into the cache decoded. A key set is as large as its tx
+        set, so it cannot thrash the cache's hot entries out, and a
+        full cache cannot turn it away. Returns the number of keys a
+        lookup now answers without a read of its own."""
+        cache = self._cache
+        held = self._prefetched
         by_table: Dict[str, list] = {}
         n = 0
         for key in keys:
             kb = key.to_bytes() if hasattr(key, "to_bytes") else bytes(key)
-            if self._cache.maybe_get(kb) is not None:
-                n += 1
+            n += 1
+            if kb in held:
                 continue
-            if budget <= 0:
+            hit = cache.maybe_get(kb)
+            if hit is not None:
+                # held too: the lookups in between may evict it
+                held[kb] = hit
                 continue
-            budget -= 1
             if self._bucket_list is not None \
                     and not kb.startswith(_OFFER_KB_PREFIX):
                 # SQL is not authoritative for bucket-list-served keys
-                # (entries may live only in buckets); caching an SQL
+                # (entries may live only in buckets); holding an SQL
                 # miss as _ABSENT here would shadow a live entry.
-                self._lookup(kb)
-                n += 1
+                e = self._read_through(kb)
+                held[kb] = _ABSENT if e is None else e
                 continue
             by_table.setdefault(self._table_for(kb), []).append(kb)
         # chunk to stay under sqlite's bound-parameter limit AND the
@@ -652,8 +682,7 @@ class LedgerTxnRoot(AbstractLedgerTxnParent):
                              f"SELECT key, entry FROM {table} "
                              f"WHERE key IN ({marks})", chunk)}
                 for kb in chunk:
-                    self._cache.put(kb, found.get(kb, _ABSENT))
-                    n += 1
+                    held[kb] = found.get(kb, _ABSENT)
         return n
 
     def get_header(self) -> LedgerHeader:
@@ -720,7 +749,9 @@ class LedgerTxnRoot(AbstractLedgerTxnParent):
                     "buyingasset, pricen, priced, price) "
                     "VALUES (?,?,?,?,?,?,?,?,?,?)", b)
         # cache reflects only durably committed state; committed objects
-        # are adopted (the committing txn is closed, so they are frozen)
+        # are adopted (the committing txn is closed, so they are frozen);
+        # what was prefetched was read before this commit and goes
+        self._prefetched = {}
         for kb, v in cache_updates:
             self._cache.put(kb, v)
         _index_apply_delta(self._contract_key_index, delta)
@@ -777,8 +808,7 @@ class LedgerTxnRoot(AbstractLedgerTxnParent):
                 if kb in exclude:
                     continue
                 cached = self._cache.maybe_get(kb)
-                if cached is not None and cached is not _ABSENT \
-                        and cached.__class__ is not bytes:
+                if cached is not None and cached is not _ABSENT:
                     e = cached
                 else:
                     e = LedgerEntry.from_bytes(bytes(raw))
@@ -803,7 +833,7 @@ class LedgerTxnRoot(AbstractLedgerTxnParent):
             if tkb == kb or tkb in exclude:
                 continue
             te = self._cache.maybe_get(tkb)
-            if te is None or te is _ABSENT or te.__class__ is bytes:
+            if te is None or te is _ABSENT:
                 te = LedgerEntry.from_bytes(bytes(traw))
                 self._cache.put(tkb, te)
             if _offer_less(te.data.value, best.data.value):

@@ -132,10 +132,11 @@ def register_vm(prefix: bytes):
 
 
 class SorobanApplyStats:
-    """What a ledger's invocations did, in plain attributes (the sites
-    are per transaction and per signature): a `LedgerManager` hangs one
-    on its ledger root, `InvokeHostFunctionOpFrame` hands it to each
-    host, and `publish` turns it into zones and counters once a close.
+    """What a ledger's Soroban operations did, and what they share, in
+    plain attributes (the sites are per transaction and per signature):
+    a `LedgerManager` hangs one on its ledger root, the Soroban
+    operation frames reach it through `ltx.get_root()`, and `publish`
+    turns the counts into zones and counters once a close.
 
     `soroban.invoke` (zone): the host function calls, wall seconds;
     `soroban.auth` (zone): the checks of address credentials inside
@@ -145,13 +146,27 @@ class SorobanApplyStats:
     the fallback (a miss of the table, or no table);
     `soroban.auth.entries.address` / `.source`: the authorization
     entries of the invocations by credential type;
-    `soroban.auth.failed`: `require_auth` calls that raised."""
+    `soroban.auth.failed`: `require_auth` calls that raised;
+    `soroban.config.load`: network configurations built from the
+    ledger's CONFIG_SETTING entries (one a close that applies a Soroban
+    operation: see `config`).
+
+    `config` is what the Soroban operations of one close share: None
+    outside a close's apply loop, where every reader builds its own
+    from its ledger; `UNREAD` from the loop's start; the close's one
+    `SorobanNetworkConfig` from its first Soroban operation
+    (`soroban/ops.py` `_load_config`) until the loop ends or raises,
+    which is before the close's upgrades run. Nothing of one close
+    reaches the next."""
+
+    UNREAD = object()
 
     __slots__ = ("invoke_s", "invokes", "auth_s", "auth_checks",
                  "prevalidated", "fallback", "address_entries",
-                 "source_entries", "failed")
+                 "source_entries", "failed", "config_loads", "config")
 
     def __init__(self):
+        self.config = None
         self.reset()
 
     def reset(self) -> None:
@@ -159,13 +174,14 @@ class SorobanApplyStats:
         self.invokes = self.auth_checks = 0
         self.prevalidated = self.fallback = 0
         self.address_entries = self.source_entries = self.failed = 0
+        self.config_loads = 0
 
     def publish(self, metrics, perf) -> None:
         """Add what was counted since the last call to `perf`'s zones
         and `metrics`' counters, and start again from zero."""
-        if not self.invokes:
+        if not (self.invokes or self.config_loads):
             return
-        if perf is not None:
+        if perf is not None and self.invokes:
             perf.add("soroban.invoke", self.invoke_s, self.invokes)
             perf.add("soroban.auth", self.auth_s, self.auth_checks)
         if metrics is not None:
@@ -178,6 +194,8 @@ class SorobanApplyStats:
             metrics.new_counter("soroban.auth.entries.source").inc(
                 self.source_entries)
             metrics.new_counter("soroban.auth.failed").inc(self.failed)
+            metrics.new_counter("soroban.config.load").inc(
+                self.config_loads)
         self.reset()
 
 

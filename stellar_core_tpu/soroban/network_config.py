@@ -152,6 +152,12 @@ def create_initial_settings(ltx, archival_overrides=None,
             ltx.create(_entry(setting))
 
 
+# canonical key bytes of every CONFIG_SETTING entry: what a
+# SorobanNetworkConfig reads, for the close's prefetch
+CONFIG_SETTING_KEYS = frozenset(
+    LedgerKey.config_setting(sid).to_bytes() for sid in ConfigSettingID)
+
+
 class SorobanNetworkConfig:
     """Cached accessor over the CONFIG_SETTING entries (reference:
     SorobanNetworkConfig::loadFromLedger)."""

@@ -31,7 +31,24 @@ log = get_logger("Tx")
 
 
 def _load_config(ltx) -> SorobanNetworkConfig:
-    return SorobanNetworkConfig(ltx)
+    """The network configuration an operation applies under. A close
+    reads it once: the first Soroban operation it applies builds it
+    from the ledger and leaves it on the root's `soroban_stats`, every
+    later one of that close finds it there, and the close drops it
+    before its upgrades run (`LedgerManager._close_ledger`; reference:
+    SorobanNetworkConfig::loadFromLedger, once a ledger and after
+    upgrades). The settings are shared and read-only, as
+    `load_without_record` hands them out. An operation applied outside
+    a close reads its own `ltx`."""
+    shared = getattr(ltx.get_root(), "soroban_stats", None)
+    kept = None if shared is None else shared.config
+    if isinstance(kept, SorobanNetworkConfig):
+        return kept
+    config = SorobanNetworkConfig(ltx)
+    if kept is not None:        # UNREAD: a close's apply loop keeps it
+        shared.config = config
+        shared.config_loads += 1
+    return config
 
 
 class SorobanOpFrame(OperationFrame):

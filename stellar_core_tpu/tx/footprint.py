@@ -31,9 +31,14 @@ from __future__ import annotations
 
 from typing import List, Set
 
-from ..xdr.ledger_entries import AssetType, LedgerKey, TrustLineAsset
+from ..soroban.host import ttl_key_for
+from ..soroban.network_config import CONFIG_SETTING_KEYS
+from ..xdr.ledger_entries import (AssetType, LedgerEntryType, LedgerKey,
+                                  TrustLineAsset)
 from ..xdr.transaction import OperationType
 from . import tx_utils
+
+_HAS_TTL = (LedgerEntryType.CONTRACT_DATA, LedgerEntryType.CONTRACT_CODE)
 
 
 class TxFootprint:
@@ -65,14 +70,21 @@ def extract_footprint(tx) -> "TxFootprint":
 
     if tx.is_soroban():
         # declared footprint keys still feed the prefetch, but host
-        # calls mutate the header (fee refunds) and TTL entries beyond
-        # the declaration, so Soroban txs apply inline
+        # calls mutate the header (fee refunds), so Soroban txs apply
+        # inline. Every contract entry has a TTL entry the host reads
+        # or creates beside it (liveness, a consumed nonce, a first
+        # write) and no footprint names, and every Soroban operation
+        # applies under the CONFIG_SETTING entries: the prefetch gets
+        # both here (the settings' keys are the same for every frame)
         precise = False
+        keys |= CONFIG_SETTING_KEYS
         sd = tx.soroban_data()
         if sd is not None:
             for key in list(sd.resources.footprint.readOnly) + \
                     list(sd.resources.footprint.readWrite):
                 keys.add(key.to_bytes())
+                if key.disc in _HAS_TTL:
+                    keys.add(ttl_key_for(key).to_bytes())
 
     tx_source = tx.tx.sourceAccount
     for op in tx.tx.operations:

@@ -240,6 +240,17 @@ def test_trim_verdict_counters_sit_beside_the_trim_zone(runs):
         list(range(2, CHECKPOINT + 1))
 
 
+@pytest.mark.parametrize("run", ["standalone", "catchup"])
+def test_close_read_counters_of_a_payment_node(runs, run):
+    # ISSUE 43: the root's point reads are a counter from the ledger
+    # manager's start, whatever it reads; a node that applies no
+    # Soroban operation builds no configuration and publishes none
+    seen = runs[run]
+    assert "ledger.root.point.sql" in seen
+    assert "soroban.config.load" not in seen
+    assert "soroban.invoke" not in seen
+
+
 def test_prevalidated_counts_are_the_checks_apply_made(runs):
     seen = runs["catchup"]
     assert runs["catchup_asked"] > 0

@@ -353,6 +353,32 @@ def test_soroban_names_are_published_documented_and_read(name,
             assert f'"{name}"' in fh.read()
 
 
+# what ISSUE 43 publishes about a contract ledger's reads: each is read
+# by a `*.auth.py` reader of its own, over the `soroban.invoke` zone's
+# count; neither is a zone
+CLOSE_READ_NAMES = {
+    "soroban.config.load": "soroban_config_loads_per_tx.auth.py",
+    "ledger.root.point.sql": "root_point_reads_per_tx.auth.py",
+}
+
+
+@pytest.mark.parametrize("name", sorted(CLOSE_READ_NAMES))
+def test_close_read_names_are_published_documented_and_read(name,
+                                                            program_opens):
+    names, zones = program_opens
+    assert name in names, f"stellar_core_tpu/ opens no counter {name!r}"
+    assert name not in zones
+    for doc in ("docs/OBSERVABILITY.md", "docs/CLOSE_PIPELINE.md",
+                "PERF.md"):
+        with open(os.path.join(ROOT, doc), encoding="utf-8") as fh:
+            assert f"`{name}`" in fh.read(), f"{doc} does not name {name}"
+    tree = _parse(os.path.join(READERS, CLOSE_READ_NAMES[name]))
+    looked_up = {_string(n) for n in ast.walk(tree)
+                 if isinstance(n, ast.Constant) and _string(n)
+                 and NAME.match(_string(n))}
+    assert looked_up == {name, "soroban.invoke"}
+
+
 def _borrowed_readers(suffix):
     """(reader file, the reader whose code makes its reading) of every
     `*<suffix>` reader that calls `cell.spec.layer_reader`."""
