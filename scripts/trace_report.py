@@ -321,125 +321,6 @@ def report_flood(path):
     return summary
 
 
-def _union(intervals):
-    """Merge [(start, end)] into disjoint sorted intervals."""
-    merged = []
-    for s, e in sorted(intervals):
-        if merged and s <= merged[-1][1]:
-            merged[-1] = (merged[-1][0], max(merged[-1][1], e))
-        else:
-            merged.append((s, e))
-    return merged
-
-
-def _isect_us(a, b):
-    """Total overlap between two DISJOINT-SORTED interval lists."""
-    total = i = j = 0
-    while i < len(a) and j < len(b):
-        lo = max(a[i][0], b[j][0])
-        hi = min(a[i][1], b[j][1])
-        if lo < hi:
-            total += hi - lo
-        if a[i][1] <= b[j][1]:
-            i += 1
-        else:
-            j += 1
-    return total
-
-
-def report_catchup(path):
-    """Per-stage occupancy/bubble report over the streaming-catchup
-    pipeline's `catchup.pipeline.*` zones (docs/CATCHUP.md): stage busy
-    % of the pipeline wall, download/device overlap (the saturation
-    evidence), queue depth high-water from the queue instants, and
-    device idle gaps. Returns the summary dict for programmatic use."""
-    spans, _ = load_spans(path)
-    events, _labels = _load_events(path)
-    intervals = {"download": [], "verify": [], "device": [], "apply": []}
-    for name, ts, dur, _args in spans:
-        if name == "catchup.pipeline.verify":
-            intervals["verify"].append((ts, ts + dur))
-        elif name == "catchup.pipeline.apply":
-            intervals["apply"].append((ts, ts + dur))
-    # pair start/done (downloads, per checkpoint) and dispatch/land
-    # (device batches, per batch id) instants into intervals — instants
-    # because both run across cranks/threads, where B/E nesting can't
-    open_dl, open_dev = {}, {}
-    queue_bytes_hwm = queue_ready_hwm = 0
-    for ev in events:
-        if ev.get("ph") != "i":
-            continue
-        name, args = ev.get("name"), ev.get("args") or {}
-        if name == "catchup.pipeline.download":
-            if args.get("event") == "start":
-                open_dl[args.get("checkpoint")] = ev["ts"]
-            elif args.get("event") == "done":
-                t0 = open_dl.pop(args.get("checkpoint"), None)
-                if t0 is not None:
-                    intervals["download"].append((t0, ev["ts"]))
-        elif name == "catchup.pipeline.device":
-            if args.get("event") == "dispatch":
-                open_dev[args.get("batch")] = ev["ts"]
-            elif args.get("event") == "land":
-                t0 = open_dev.pop(args.get("batch"), None)
-                if t0 is not None:
-                    intervals["device"].append((t0, ev["ts"]))
-        elif name == "catchup.pipeline.queue":
-            queue_bytes_hwm = max(queue_bytes_hwm, args.get("bytes", 0))
-            queue_ready_hwm = max(queue_ready_hwm, args.get("ready", 0))
-    unions = {k: _union(v) for k, v in intervals.items()}
-    all_pts = [p for u in unions.values() for s, e in u for p in (s, e)]
-    if not all_pts:
-        print(f"== {path}: no catchup.pipeline.* events — record a "
-              "streaming catchup with tracing on ==")
-        return {}
-    wall_us = max(all_pts) - min(all_pts)
-    summary = {"wall_ms": round(wall_us / 1000.0, 3),
-               "stages": {},
-               "queues": {"bytes_hwm": queue_bytes_hwm,
-                          "ready_hwm": queue_ready_hwm},
-               "overlap": {}}
-    print(f"== {path}: catchup pipeline, wall "
-          f"{_fmt_ms(wall_us)} ms ==")
-    print(f"{'stage':12} {'items':>7} {'busy_ms':>12} {'busy %':>8}")
-    for stage in ("download", "verify", "device", "apply"):
-        busy = sum(e - s for s, e in unions[stage])
-        summary["stages"][stage] = {
-            "items": len(intervals[stage]),
-            "busy_ms": round(busy / 1000.0, 3),
-            "occupancy": round(busy / wall_us, 3) if wall_us else 0.0}
-        print(f"{stage:12} {len(intervals[stage]):>7} "
-              f"{_fmt_ms(busy):>12} "
-              f"{100.0 * busy / max(1, wall_us):>7.1f}%")
-    # overlap evidence: device/apply busy while >=1 download in flight
-    for a, b in (("device", "download"), ("apply", "download")):
-        ov = _isect_us(unions[a], unions[b])
-        summary["overlap"][f"{a}_busy_while_{b}_ms"] = \
-            round(ov / 1000.0, 3)
-    print(f"device busy while downloads in flight: "
-          f"{_fmt_ms(summary['overlap']['device_busy_while_download_ms'] * 1000)} ms; "
-          f"apply busy while downloads in flight: "
-          f"{_fmt_ms(summary['overlap']['apply_busy_while_download_ms'] * 1000)} ms")
-    # device idle gaps (pipeline bubbles): dead air between coalesced
-    # batches while the pipeline was still running
-    dev = unions["device"]
-    gaps = [dev[i + 1][0] - dev[i][1] for i in range(len(dev) - 1)]
-    summary["device_idle"] = {
-        "gaps": len(gaps),
-        "total_ms": round(sum(gaps) / 1000.0, 3),
-        "max_ms": round(max(gaps) / 1000.0, 3) if gaps else 0.0}
-    if dev:
-        print(f"device idle gaps between batches: {len(gaps)}, total "
-              f"{_fmt_ms(sum(gaps))} ms, max "
-              f"{_fmt_ms(max(gaps) if gaps else 0)} ms")
-    else:
-        print("(no device batch instants — native verify or no "
-              "prevalidation dispatched)")
-    print(f"queue high-water: {queue_bytes_hwm} bytes buffered, "
-          f"{queue_ready_hwm} checkpoints verified-unapplied")
-    return summary
-
-
 def diff(path_a, path_b, top, min_delta_ms):
     agg_a = aggregate(load_spans(path_a)[0])
     agg_b = aggregate(load_spans(path_b)[0])
@@ -484,21 +365,15 @@ def main() -> int:
                     help="flood hop-count distribution, duplicate "
                          "ratio, per-link propagation p50/p99 "
                          "(merged trace)")
-    ap.add_argument("--catchup", action="store_true",
-                    help="streaming-catchup pipeline stage occupancy, "
-                         "download/device overlap, queue high-water, "
-                         "device idle gaps")
     args = ap.parse_args()
-    if args.slots or args.flood or args.catchup:
+    if args.slots or args.flood:
         if args.other:
-            ap.error("--slots/--flood/--catchup analyze ONE trace; "
+            ap.error("--slots/--flood analyze ONE trace; "
                      "a second positional is diff mode only")
         if args.slots:
             report_slots(args.trace)
         if args.flood:
             report_flood(args.trace)
-        if args.catchup:
-            report_catchup(args.trace)
         return 0
     if args.other:
         diff(args.trace, args.other, args.top, args.min_delta_ms)

@@ -117,9 +117,8 @@ def replay_one_ledger(app, seq: int, hhe, frame: TxSetFrame, verify=None,
                       expected_results=None) -> bool:
     """Close one replayed ledger and pin it to the verified chain:
     prepare → closeLedger → archived-results anchor → header-hash
-    compare. The ONE apply core shared by the sequential
-    ApplyCheckpointWork and the streaming pipeline (catchup/pipeline.py)
-    so the two replay paths cannot drift semantically."""
+    compare. The one apply core: ApplyCheckpointWork is its only
+    caller."""
     lm = app.ledger_manager
     if chaos.ENABLED:
         # mid-apply fault seam (docs/CHAOS.md): `crash` here models a
@@ -295,77 +294,15 @@ class DownloadVerifyLedgerChainWork(Work):
         return State.WORK_SUCCESS
 
 
-_PENDING = object()
-
-
-class _ReadyResult:
-    """Already-materialized result with the _AsyncResult interface."""
-
-    __slots__ = ("_res",)
-
-    def __init__(self, res):
-        self._res = res
-
-    def done(self) -> bool:
-        return True
-
-    def wait(self, timeout=None) -> bool:
-        return True
-
-    def result(self, timeout=None):
-        return self._res
-
-
-class _AsyncResult:
-    """Daemon-thread future: collects a blocking device result off the
-    apply path without ever pinning process shutdown (a stalled batch
-    dies with the process; ThreadPoolExecutor's non-daemon workers
-    would be joined at exit)."""
-
-    __slots__ = ("_done", "_res", "_exc")
-
-    def __init__(self, fn):
-        self._done = threading.Event()
-        self._res = None
-        self._exc: Optional[BaseException] = None
-        t = threading.Thread(target=self._run, args=(fn,), daemon=True,
-                             name="batch-resolve")
-        t.start()
-
-    def _run(self, fn) -> None:  # thread-domain: catchup-worker
-        from ..util import threads
-        if threads.CHECK:
-            threads.bind("catchup-worker")
-        try:
-            self._res = fn()
-        except BaseException as e:      # surfaced on result()
-            self._exc = e
-        finally:
-            self._done.set()
-
-    def done(self) -> bool:
-        return self._done.is_set()
-
-    def wait(self, timeout: Optional[float] = None) -> bool:
-        """Block up to `timeout` for completion; no result adoption."""
-        return self._done.wait(timeout)
-
-    def result(self, timeout: Optional[float] = None):
-        """Result, the stored exception, or _PENDING on timeout."""
-        if not self._done.wait(timeout):
-            return _PENDING
-        if self._exc is not None:
-            raise self._exc
-        return self._res
-
-
 class _ChunkFeed:
     """The verdicts of one dispatched batch, chunk by chunk: a daemon
     thread takes each chunk from the verifier's collect callable as it
     lands (`chunks()` of a split batch, ops/chunking.py; a callable
     without it is one chunk) and the crank thread takes what has landed
-    without ever blocking on the device. Daemon for `_AsyncResult`'s
-    reason: a stalled batch dies with the process."""
+    without ever blocking on the device. A daemon thread, so that a
+    stalled batch dies with the process and never pins its shutdown
+    (ThreadPoolExecutor's non-daemon workers would be joined at
+    exit)."""
 
     def __init__(self, handle, n: int, metrics=None):
         # where the collecting thread's account with the scheduler

@@ -17,7 +17,6 @@ from typing import Optional
 from ..util.logging import get_logger
 from ..work import State, WorkSequence, WorkWithCallback
 from .catchup_work import CatchupConfiguration, CatchupWork
-from .pipeline import StreamingCatchupWork
 
 log = get_logger("History")
 
@@ -87,12 +86,7 @@ class CatchupManager:
         # wedge recovery (reference: random archive selection in
         # HistoryArchiveManager::selectRandomReadableHistoryArchive)
         archive = archives[self.catchups_started % len(archives)]
-        # streaming pipeline by default (docs/CATCHUP.md); the
-        # sequential CatchupWork stays as the reference path behind the
-        # CATCHUP_PIPELINE knob (and as the differential-test baseline)
-        work_cls = StreamingCatchupWork \
-            if self.app.config.CATCHUP_PIPELINE else CatchupWork
-        work = work_cls(
+        work = CatchupWork(
             self.app, archive,
             CatchupConfiguration(to_ledger=target),
             verify=herder._verify)

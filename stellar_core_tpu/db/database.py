@@ -230,6 +230,12 @@ class SchemaMixin:
         backend with one connection runs it as any other."""
         return self.transaction()
 
+    def writing_transaction(self):
+        """The scope of a transaction that writes from its first
+        statement. A backend whose writers never invalidate a reader's
+        snapshot runs it as any other."""
+        return self.transaction()
+
     def insert_rows(self, sql: str, rows: Iterable[Iterable[Any]]) -> None:
         """`executemany` of a one-row `INSERT ... VALUES (?,...)`, in
         whatever shape the backend runs a bulk insert best."""
@@ -244,7 +250,7 @@ class SchemaMixin:
     def initialize(self) -> None:
         """Create all tables from scratch (reference: `new-db`,
         Database::initialize + each manager's dropAll)."""
-        with self.transaction():
+        with self.writing_transaction():
             for stmt in schema_statements():
                 self.execute(stmt)
             self.put_schema_version(SCHEMA_VERSION)
@@ -416,8 +422,9 @@ class Database(SchemaMixin):
         BEGIN/SAVEPOINT would corrupt the shared depth machinery.  The
         lock is an RLock, so same-thread nesting still works."""
 
-        def __init__(self, db: "Database"):
+        def __init__(self, db: "Database", begin: str = "BEGIN"):
             self._db = db
+            self._begin = begin
             self._done = False
 
         def __enter__(self):
@@ -425,7 +432,7 @@ class Database(SchemaMixin):
             db._lock.acquire()
             try:
                 if db._tx_depth == 0:
-                    db._conn.execute("BEGIN")
+                    db._conn.execute(self._begin)
                     db._tx_owner = threading.current_thread()
                 else:
                     db._conn.execute(f"SAVEPOINT sp{db._tx_depth}")
@@ -463,6 +470,14 @@ class Database(SchemaMixin):
 
     def transaction(self) -> "_TxScope":
         return Database._TxScope(self)
+
+    def writing_transaction(self) -> "_TxScope":
+        # the write lock is taken at BEGIN, so another connection's
+        # writer is waited out (the busy timeout). Under a deferred
+        # BEGIN its commit, landing between the BEGIN and the first
+        # write, leaves this one a stale snapshot: SQLITE_BUSY_SNAPSHOT,
+        # which no timeout waits out
+        return Database._TxScope(self, "BEGIN IMMEDIATE")
 
     def _commit(self, conn: sqlite3.Connection) -> None:
         if chaos.ENABLED:

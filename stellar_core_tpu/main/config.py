@@ -124,20 +124,6 @@ class Config:
         self.HISTORY: Dict[str, Dict[str, str]] = {}
         self.CATCHUP_COMPLETE = False
         self.CATCHUP_RECENT = 0
-        # streaming catchup pipeline (catchup/pipeline.py,
-        # docs/CATCHUP.md): overlap download → verify → device
-        # prevalidate → apply across checkpoints instead of replaying
-        # them strictly one at a time; False keeps the sequential
-        # CatchupWork reference path
-        self.CATCHUP_PIPELINE = True
-        # checkpoints the download stage may run ahead of apply
-        self.CATCHUP_PIPELINE_AHEAD_CHECKPOINTS = 8
-        # byte budget for downloaded-but-unapplied checkpoint files: a
-        # fast archive over a slow apply parks the download stage here
-        self.CATCHUP_PIPELINE_BYTE_BUDGET = 64 * 1024 * 1024
-        # verified checkpoints ahead of apply the device prevalidation
-        # stage may fuse into one coalesced signature batch
-        self.CATCHUP_PIPELINE_PREVALIDATE_AHEAD = 4
 
         # upgrades this validator votes for (reference: Upgrades params
         # come via the `upgrades` admin endpoint; the TESTING_UPGRADE_*

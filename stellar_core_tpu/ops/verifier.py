@@ -74,38 +74,6 @@ def _bucket_size(n: int, minimum: int = MIN_BUCKET) -> int:
     return b
 
 
-def prevalidate_coalesce(counts: Sequence[int], max_fuse: int,
-                         minimum: int = MIN_BUCKET) -> int:
-    """How many pending checkpoints' signature batches the catchup
-    pipeline should fuse into ONE device dispatch (catchup/pipeline.py's
-    prevalidation stage sizing its batch from the ahead-window).
-
-    `counts[i]` is checkpoint i's signature-tuple count, in replay
-    order. Device batches pad to a power-of-two bucket (static shapes,
-    one XLA program per size — `_bucket_size`), so fusing is accepted
-    greedily while it wastes no padding slots versus separate
-    dispatches: e.g. 300+300 fused costs bucket(600)=1024 = 512+512
-    separate (equal slots, one launch saved — fuse), while 512+10
-    fused costs bucket(522)=1024 > 512+16 (reject). Zero-count
-    checkpoints fuse for free. Deterministic, pure, unit-tested in
-    tests/test_catchup_pipeline.py."""
-    if not counts:
-        return 0
-    k = 1
-    total = counts[0]
-    while k < min(len(counts), max_fuse):
-        nxt = counts[k]
-        if nxt:
-            fused = _bucket_size(total + nxt, minimum)
-            separate = (_bucket_size(total, minimum) if total else 0) \
-                + _bucket_size(nxt, minimum)
-            if fused > separate:
-                break
-            total += nxt
-        k += 1
-    return k
-
-
 def _native():
     """The native library; one that cannot be built is an error (the
     Python-oracle prep it used to fall back to is ~1000x slower)."""

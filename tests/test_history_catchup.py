@@ -3,6 +3,7 @@
 TmpDirHistoryConfigurator archives, publish + catchup round trips).
 """
 
+import contextlib
 import os
 
 import pytest
@@ -350,6 +351,17 @@ def feed_externalized_slot(app_a, app_b, seq):
         seq, header.scpValue.to_bytes())
 
 
+def crank_until_lcl(app, target, seconds=60):
+    """Crank `app` until its LCL reaches `target` (or `seconds` of real
+    time pass: the archive's `cp` commands run in real time)."""
+    import time as _time
+    deadline = _time.monotonic() + seconds
+    while app.ledger_manager.get_last_closed_ledger_num() < target \
+            and _time.monotonic() < deadline:
+        if app.clock.crank(False) == 0:
+            _time.sleep(0.002)
+
+
 def test_out_of_sync_node_recovers_via_catchup(tmp_path):
     """A node far behind the network buffers an externalized value with
     a ledger gap, the CatchupManager fills the gap from the archive, and
@@ -379,12 +391,7 @@ def test_out_of_sync_node_recovers_via_catchup(tmp_path):
 
             # gap detected → catchup runs → buffered values drain
             assert app_b.catchup_manager.catchups_started == 1
-            import time as _time
-            deadline = _time.monotonic() + 60
-            while app_b.ledger_manager.get_last_closed_ledger_num() < 131 \
-                    and _time.monotonic() < deadline:
-                if app_b.clock.crank(False) == 0:
-                    _time.sleep(0.002)  # archive `cp` runs in real time
+            crank_until_lcl(app_b, 131)
             assert app_b.ledger_manager.get_last_closed_ledger_num() == 131
             assert app_b.ledger_manager.get_last_closed_ledger_hash() == \
                 app_a.ledger_manager.get_last_closed_ledger_hash()
@@ -414,19 +421,11 @@ def test_catchup_to_midcheckpoint_target_then_second_gap(tmp_path):
         def feed_slot(seq):
             feed_externalized_slot(app_a, app_b, seq)
 
-        def crank_until_lcl(target):
-            import time as _time
-            deadline = _time.monotonic() + 60
-            while app_b.ledger_manager.get_last_closed_ledger_num() \
-                    < target and _time.monotonic() < deadline:
-                if app_b.clock.crank(False) == 0:
-                    _time.sleep(0.002)
-
         try:
             # slot 100 is mid-checkpoint (checkpoints end at 63, 127)
             feed_slot(100)
             assert app_b.catchup_manager.catchups_started == 1
-            crank_until_lcl(100)
+            crank_until_lcl(app_b, 100)
             # catchup replayed exactly to 99, then the buffered slot
             # 100 applied — NOT the whole checkpoint through 127
             assert app_b.ledger_manager.get_last_closed_ledger_num() \
@@ -436,7 +435,7 @@ def test_catchup_to_midcheckpoint_target_then_second_gap(tmp_path):
             # a later gap must still be detected and recovered
             feed_slot(125)
             assert app_b.catchup_manager.catchups_started == 2
-            crank_until_lcl(125)
+            crank_until_lcl(app_b, 125)
             assert app_b.ledger_manager.get_last_closed_ledger_num() \
                 == 125
             row = app_a.database.query_one(
@@ -446,6 +445,67 @@ def test_catchup_to_midcheckpoint_target_then_second_gap(tmp_path):
                 == bytes(row[0])
         finally:
             app_b.shutdown()
+    finally:
+        app_a.shutdown()
+
+
+@contextlib.contextmanager
+def _online_catchup_on_the_device(app_a, slot):
+    """A node with the device backend, at genesis, handed slot `slot` of
+    app_a's chain: yields it once the CatchupManager's catchup and the
+    buffered slot have brought it there, on app_a's hash."""
+    cfg_b = get_test_config()
+    cfg_b.NETWORK_PASSPHRASE = app_a.config.NETWORK_PASSPHRASE
+    cfg_b.SIGNATURE_VERIFY_BACKEND = "tpu"
+    cfg_b.HISTORY = {n: {"get": c["get"]}
+                     for n, c in app_a.config.HISTORY.items()}
+    app_b = Application.create(VirtualClock(ClockMode.VIRTUAL_TIME),
+                               cfg_b)
+    app_b.start()
+    try:
+        feed_externalized_slot(app_a, app_b, slot)
+        assert app_b.catchup_manager.catchups_started == 1
+        # long batch_grace, as in the offline twin above: the first
+        # probe waits for the batch, so what the table answers does not
+        # depend on how fast this machine's device is
+        app_b.catchup_manager._running._sequence[0].batch_grace = 60.0
+        crank_until_lcl(app_b, slot, seconds=120)
+        assert app_b.ledger_manager.get_last_closed_ledger_num() == slot
+        assert app_b.ledger_manager.get_last_closed_ledger_hash() == \
+            bytes(app_a.database.query_one(
+                "SELECT ledgerhash FROM ledgerheaders WHERE ledgerseq=?",
+                (slot,))[0])
+        yield app_b
+    finally:
+        app_b.shutdown()
+
+
+def test_online_catchup_answers_from_the_device_table(tmp_path):
+    """A live node that falls behind runs the catchup the `catchup`
+    command runs: its checkpoint's signatures go to the device resolved
+    against state, and every check of the replay is one the resolver
+    foresaw (`crypto.prevalidated.*`, published when a checkpoint's
+    work ends)."""
+    app_a, archive, root = make_publishing_app(tmp_path, n_ledgers=70)
+    try:
+        with _online_catchup_on_the_device(app_a, 64) as app_b:
+            counters = app_b.metrics.to_json()
+            assert counters["crypto.prevalidated.hit"]["count"] > 0
+            assert counters["crypto.prevalidated.miss.unknown"][
+                "count"] == 0
+    finally:
+        app_a.shutdown()
+
+
+def test_online_catchup_prefetches_the_second_checkpoint(tmp_path):
+    """Over a gap of two checkpoints the second one's batch is
+    dispatched while the first applies: `catchup.batch.lead` is how long
+    its verdicts were back before its first ledger asked."""
+    app_a, archive, root = make_publishing_app(tmp_path, n_ledgers=130)
+    try:
+        with _online_catchup_on_the_device(app_a, 128) as app_b:
+            assert app_b.metrics.to_json()[
+                "catchup.batch.lead"]["count"] == 1
     finally:
         app_a.shutdown()
 
