@@ -117,6 +117,9 @@ class Herder:
         # seconds, or None where they were not measured
         self._recv_run = None
         self._recv_cpu = None
+        # frames of `recv_transactions` bursts since the last close:
+        # received, admitted, duplicate, bad signature
+        self._flood_counts = [0, 0, 0, 0]
         # hash-keyed propagation tracker (overlay/propagation.py), set
         # by Application; admission/externalize stamps land here so the
         # mesh observatory sees the full flood→admit→externalize path
@@ -299,10 +302,18 @@ class Herder:
         multi-process harness runs native-backend nodes, on the
         serviceless path (per-signature verify, results prevalidated
         into try_add so nothing verifies twice)."""
+        if not frames:
+            return []
+        targs = {"n": len(frames)} if tracing.ENABLED else None
+        with self.perf.zone("herder.recvTransactions", targs=targs):
+            return self._recv_transactions(frames, bad_sig, targs)
+
+    def _recv_transactions(self, frames, bad_sig, targs) -> List[AddResult]:
         verify = self._verify
         svc = self.verify_service
         pv = None
-        if frames and (svc is not None or bad_sig is not None):
+        bad = 0
+        if svc is not None or bad_sig is not None:
             from ..tx.signature_checker import (PrevalidatedVerifier,
                                                 collect_signature_tuples,
                                                 default_verify)
@@ -325,8 +336,11 @@ class Herder:
             results: list = []
             if tuples:
                 if svc is not None:
-                    futures = svc.submit_many(tuples)
-                    results = [f.result() for f in futures]
+                    # what the crank stands still for its batch
+                    with self.perf.zone("herder.recvTransactions.verify",
+                                        targs=targs):
+                        futures = svc.submit_many(tuples)
+                        results = [f.result() for f in futures]
                 else:
                     sync_verify = self._verify or default_verify
                     results = [sync_verify(p, s, m)
@@ -335,19 +349,27 @@ class Herder:
                     fallback=self._verify or default_verify)
                 pv.add_results(tuples, results)
                 verify = pv
-            if bad_sig is not None:
-                # the contract is one bool per frame even when nothing
-                # needed verifying (all duplicates / no signatures) —
-                # the overlay's zip-based per-peer accounting must
-                # never silently truncate
-                it = iter(results)
-                for ts in per_frame:
-                    rs = [next(it) for _ in ts]
-                    bad_sig.append(bool(ts) and not all(rs))
+            # the contract is one bool per frame even when nothing
+            # needed verifying (all duplicates / no signatures) — the
+            # overlay's zip-based per-peer accounting must never
+            # silently truncate
+            it = iter(results)
+            for ts in per_frame:
+                rs = [next(it) for _ in ts]
+                flag = bool(ts) and not all(rs)
+                bad += flag
+                if bad_sig is not None:
+                    bad_sig.append(flag)
         out = [self.recv_transaction(f, verify=verify) for f in frames]
         if pv is not None:
             # end of the burst: what the batch answered at admission
             pv.publish(self._metrics)
+        # frames of bursts by outcome, published at the next close
+        fc = self._flood_counts
+        fc[0] += len(frames)
+        fc[1] += out.count(AddResult.ADD_STATUS_PENDING)
+        fc[2] += out.count(AddResult.ADD_STATUS_DUPLICATE)
+        fc[3] += bad
         return out
 
     def _advert_or_queue(self, tx) -> None:
@@ -510,6 +532,16 @@ class Herder:
             self._recv_count, self._recv_seconds = 0, 0.0
             # a run still open has the close inside it: not the calls'
             self._recv_run = self._recv_cpu = None
+        fc = self._flood_counts
+        if fc[0] and self._metrics is not None:
+            # frames handed to `recv_transactions` since the last
+            # close, by outcome
+            m = self._metrics
+            m.new_counter("herder.flood.received").inc(fc[0])
+            m.new_counter("herder.flood.admitted").inc(fc[1])
+            m.new_counter("herder.flood.duplicate").inc(fc[2])
+            m.new_counter("herder.flood.badSig").inc(fc[3])
+            self._flood_counts = [0, 0, 0, 0]
         self._record_tx_e2e(tx_set)
         self.tx_queue.remove_applied(tx_set.txs)
         self.tx_queue.shift()

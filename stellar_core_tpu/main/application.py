@@ -40,6 +40,16 @@ class AppState:
     APP_STOPPING_STATE = 5
 
 
+# JAX backends on which a node loads its verify shapes when it starts
+# (`Application._load_verify_shapes`). The two rungs are priced on the
+# chip: 40 ms a run of the largest bucket whatever it holds. On the CPU
+# backend, which a node accepts only where the operator names it (a
+# rehearsal, the test suite), that run is seconds and would put every
+# small batch of a tiny node over its dispatch deadline; the tests that
+# are about the loaded shapes add "cpu" here and make the rungs small.
+SHAPE_LOADING_BACKENDS = ("tpu",)
+
+
 def device_backend_refusal(default_backend: str,
                            jax_platforms: Optional[str]) -> Optional[str]:
     """Why a node with SIGNATURE_VERIFY_BACKEND = "tpu" must not start
@@ -469,6 +479,7 @@ class Application:
             self.snapshots.on_ledger_closed(
                 self.ledger_manager.get_last_closed_ledger_header(),
                 self.ledger_manager.get_last_closed_ledger_hash())
+            self._load_verify_shapes()
             self.herder.start()
             if self.overlay_manager is not None:
                 self.overlay_manager.start()
@@ -500,6 +511,37 @@ class Application:
                 self.clock.add_io_poller(_sleepy_poller)
             log.info("application started at ledger %d",
                      self.ledger_manager.get_last_closed_ledger_num())
+
+    def _load_verify_shapes(self) -> None:
+        """A node with the device backend loads the shapes its live
+        batches run on before it takes its first message, so that none
+        of them is first met on the crank (a shape's first call traces,
+        lowers and compiles for seconds to minutes inside whatever
+        called it): the largest bucket, which every chunk of a larger
+        batch and every batch down to the next rung runs on (a received
+        set's cache misses, a checkpoint's tuples), and, on a node that
+        tracks a network, the bucket of a full verify-service flush
+        (`VERIFY_MAX_BATCH`), which a flood burst runs on. A
+        MANUAL_CLOSE node has no peers to flood it and takes its
+        transactions one at a time: it loads the first alone. Each
+        shape costs a process its trace once (~12 s on a v5e host),
+        so the rungs are these two and no ladder. On a backend that is
+        not in `SHAPE_LOADING_BACKENDS` (the CPU: a rehearsal, the
+        suite) nothing is loaded and a batch keeps its own bucket."""
+        load = getattr(self.batch_verifier, "load_shapes", None)
+        if load is None:        # no device backend (or a test's fake)
+            return
+        import jax
+        if jax.default_backend() not in SHAPE_LOADING_BACKENDS:
+            return
+        from ..ops import chunking
+        lanes = [chunking.MAX_BUCKET]
+        if not self.config.MANUAL_CLOSE:
+            lanes.append(min(self.config.VERIFY_MAX_BATCH,
+                             chunking.MAX_BUCKET))
+        with self.perf.zone("app.start.loadShapes"):
+            loaded = load(lanes)
+        log.info("device verifier: shapes of %s lanes loaded", loaded)
 
     def _arm_self_check_timer(self) -> None:
         """Recurring background self-check (reference: scheduleSelfCheck,

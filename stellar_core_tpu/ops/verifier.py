@@ -74,6 +74,22 @@ def _bucket_size(n: int, minimum: int = MIN_BUCKET) -> int:
     return b
 
 
+# (program, lanes) of every shape this process has run. A jit keeps one
+# executable a shape for its life, whichever verifier made the call, so
+# a second node of a process finds here what the first one loaded.
+_SHAPES_RUN: set = set()
+
+
+def _first_run(d) -> bool:
+    """Whether the packed batch `d` is this process's first of its
+    program and lanes; it is on record from here on."""
+    key = (d.fn, d.bucket)
+    if key in _SHAPES_RUN:
+        return False
+    _SHAPES_RUN.add(key)
+    return True
+
+
 def _native():
     """The native library; one that cannot be built is an error (the
     Python-oracle prep it used to fall back to is ~1000x slower)."""
@@ -145,6 +161,52 @@ class TpuBatchVerifier:
         self.perf = perf  # per-app zone registry (None = process default)
         self._init_dispatch_metrics(metrics)
 
+    # ---------------------------------------------------- loaded shapes --
+    _loaded: Tuple[int, ...] = ()   # lanes of the shapes loaded, ascending
+
+    def load_shapes(self, buckets) -> List[int]:
+        """Make the tx-hash program ready at each of `buckets` (lanes,
+        each rounded up to a bucket) before any batch needs it: one run
+        on zeros where this process has not run the shape yet (its
+        trace, lowering and compile, or the read of the compile cache),
+        nothing where it has. From then on a batch that one of them
+        holds is padded to the smallest that does (`_bucket_for`), so
+        no batch below the largest meets a shape of its own. Returns
+        the shapes loaded, ascending. A node calls it once, when it
+        starts (`Application.start`); no dispatch is counted."""
+        for lanes in sorted({_bucket_size(int(b), self._min_bucket)
+                             for b in buckets}):
+            zeros = np.zeros((lanes, 64), dtype=np.uint8)
+            d = self._pack(zeros[:, :32], zeros, [bytes(32)] * lanes)
+            if _first_run(d):
+                np.asarray(d.fn(*d.args))
+            self._loaded = tuple(sorted({lanes, *self._loaded}))
+        if self._m_shape_loaded is not None:
+            self._m_shape_loaded.set_count(len(self._loaded))
+        return self.loaded_shapes
+
+    @property
+    def loaded_shapes(self) -> List[int]:
+        return list(self._loaded)
+
+    def _bucket_for(self, n: int, minimum: int) -> int:
+        """Lanes of the program a batch of `n` runs on: the smallest
+        loaded shape that holds it (and divides by the devices that
+        share it, of which `minimum` is a multiple), else its own
+        power-of-two bucket."""
+        for b in self._loaded:
+            if b >= n and b % minimum == 0:
+                return b
+        return _bucket_size(n, minimum)
+
+    def _launch(self, d: SimpleNamespace):
+        """The jit call of a packed batch. A shape this process has not
+        run yet traces and compiles inside it, on the caller's thread:
+        counted (`crypto.verify.shape.missed`)."""
+        if _first_run(d) and self._m_shape_missed is not None:
+            self._m_shape_missed.inc()
+        return d.fn(*d.args)
+
     def device_info(self) -> dict:
         """{platform, kind, count} of the devices THIS verifier
         dispatches to — the `device` of `backendstatus`, so a process
@@ -174,6 +236,7 @@ class TpuBatchVerifier:
         if metrics is None:
             self._m_batch = self._m_padding = self._m_wall = None
             self._m_host = self._m_chunks = None
+            self._m_shape_loaded = self._m_shape_missed = None
             return
         self._m_batch = metrics.new_histogram(
             "crypto.verify.dispatch.batch")
@@ -187,6 +250,12 @@ class TpuBatchVerifier:
         # (ops/chunking.py); a batch that fits one bucket adds nothing
         self._m_chunks = metrics.new_counter(
             "crypto.verify.dispatch.chunks")
+        # shapes `load_shapes` made ready, and dispatches that met a
+        # shape no one had (expected 0 on a node that loaded its own)
+        self._m_shape_loaded = metrics.new_counter(
+            "crypto.verify.shape.loaded")
+        self._m_shape_missed = metrics.new_counter(
+            "crypto.verify.shape.missed")
 
     def verify_batch(self, pubs: np.ndarray, sigs: np.ndarray,
                      msgs: Sequence[bytes]) -> np.ndarray:
@@ -215,7 +284,7 @@ class TpuBatchVerifier:
         pubs = np.asarray(pubs, dtype=np.uint8).reshape(n, 32)
         sigs = np.asarray(sigs, dtype=np.uint8).reshape(n, 64)
         bucket = chunking.MAX_BUCKET if full \
-            else _bucket_size(n, self._min_bucket)
+            else self._bucket_for(n, self._min_bucket)
         if all(len(m) == 32 for m in msgs):
             # tx-hash hot path: ship M raw, SHA-512 + mod L on device —
             # no per-signature host work
@@ -235,7 +304,7 @@ class TpuBatchVerifier:
         """Device half: the jit call (transfer and launch; on a shape's
         first call also its trace, lowering and compile) and the
         collect callable."""
-        out = d.fn(*d.args)
+        out = self._launch(d)
         n = d.n
         if self._m_batch is None:
             return lambda: np.asarray(out)[:n]
@@ -503,7 +572,7 @@ class ShardedBatchVerifier(TpuBatchVerifier):
         pubs = np.asarray(pubs, dtype=np.uint8).reshape(n, 32)
         sigs = np.asarray(sigs, dtype=np.uint8).reshape(n, 64)
         bucket = -(-chunking.MAX_BUCKET // nact) * nact if full \
-            else _bucket_size(n, self._min_bucket_for(nact))
+            else self._bucket_for(n, self._min_bucket_for(nact))
         rows = bucket // nact
         counts = shard_shares(n, nact)
 
@@ -538,8 +607,8 @@ class ShardedBatchVerifier(TpuBatchVerifier):
         args, active, rows, counts = d.args, d.active, d.rows, d.counts
         nact = len(active)
         if d.pin is not None:
-            args = tuple(jax.device_put(a, d.pin) for a in args)
-        out = d.fn(*args)
+            args = d.args = tuple(jax.device_put(a, d.pin) for a in args)
+        out = self._launch(d)
 
         def unshard(res: np.ndarray) -> np.ndarray:
             parts = [res[s * rows:s * rows + counts[s]]

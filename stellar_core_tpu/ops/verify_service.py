@@ -51,7 +51,8 @@ Semantics contract — results are bit-identical to the sync path:
 Observability: ``crypto.verify_service.occupancy`` histogram (tuples
 per flush), ``crypto.verify_service.queue-wait`` timer (submit →
 dispatch), ``crypto.verify_service.flush.<reason>`` counters,
-``crypto.verify_service.fallback`` counter, and a
+``crypto.verify_service.flush.native`` (flushes under the device
+cutoff), ``crypto.verify_service.fallback`` counter, and a
 ``crypto.verifyService.flush`` perf zone (batch/reason span args) that
 rides the flight recorder like every other zone.
 
@@ -147,6 +148,10 @@ class VerifyService:
         self._reasons = {
             r: metrics.counter("crypto", "verify_service", "flush", r)
             for r in FLUSH_REASONS}
+        # flushes under the verifier's device cutoff, whatever the
+        # trigger: they ran per signature on the host
+        self._native_flushes = metrics.counter(
+            "crypto", "verify_service", "flush", "native")
         self._lock = threading.Lock()
         self._pending_tuples: List[Tuple[bytes, bytes, bytes]] = []
         self._pending_keys: List[bytes] = []
@@ -277,6 +282,8 @@ class VerifyService:
         n = len(tuples)
         self._occupancy.update(n)
         self._reasons.get(reason, self._reasons["drain"]).inc()
+        if n < getattr(self._verifier, "_device_min_batch", 1):
+            self._native_flushes.inc()
         now = time.perf_counter()
         for t0 in times:
             self._queue_wait.update(now - t0)
@@ -437,5 +444,6 @@ class VerifyService:
             "queue_wait_p99_ms": round(qw["99%"] * 1000, 3),
             "flush_reasons": {r: c.count
                               for r, c in self._reasons.items()},
+            "native_flushes": self._native_flushes.count,
             "fallbacks": self._fallbacks.count,
         }

@@ -20,7 +20,7 @@ PACKAGE = os.path.join(ROOT, "stellar_core_tpu")
 READERS = os.path.join(ROOT, "benchmark", "layer_metrics")
 
 # a zone, timer, histogram, meter or counter name: dotted, lower-case head
-NAME = re.compile(r"^[a-z][A-Za-z0-9]*(\.[A-Za-z0-9]+)+$")
+NAME = re.compile(r"^[a-z][A-Za-z0-9]*(\.[A-Za-z0-9_]+)+$")
 # the lookups benchmark/harness/cell.py offers a reader
 LOOKUPS = {"cell.zones.get", "cell.counters.get",
            "cell.spans.total", "cell.spans.named"}
@@ -421,6 +421,58 @@ def test_auth_readers_that_borrow_a_reading_name_a_reader_that_exists():
     for reader, lender in borrowed:
         assert os.path.exists(os.path.join(READERS, lender + ".py")), reader
         assert not lender.endswith(".auth")
+
+
+def test_flood_readers_that_borrow_a_reading_name_a_reader_that_exists():
+    """As above for `*.flood.py`, with no count held."""
+    borrowed = list(_borrowed_readers(".flood.py"))
+    assert borrowed
+    for reader, lender in borrowed:
+        assert os.path.exists(os.path.join(READERS, lender + ".py")), reader
+        assert not lender.endswith(".flood")
+
+
+# what ISSUE 45 publishes about a flood burst and the shapes a node
+# loads: each is read by a `*.flood.py` reader of its own or decides
+# `correct` in the cell's driver (generators/txset_flood.py)
+FLOOD_NAMES = {
+    "herder.recvTransactions": "flood_admit_us_per_tx.flood.py",
+    "herder.recvTransactions.verify": "flood_verify_wait_us_per_tx.flood.py",
+    "herder.flood.received": "flood_verify_wait_us_per_tx.flood.py",
+    "herder.flood.admitted": "flood_admit_us_per_tx.flood.py",
+    "herder.flood.duplicate": None,
+    "herder.flood.badSig": None,
+    "herder.ledgerClosed": "queue_upkeep_ms.flood.py",
+    "crypto.verify_service.flush.native":
+        "verify_service_native_share.flood.py",
+    "crypto.verify_service.occupancy": "flood_batch_occupancy.flood.py",
+    "crypto.verify.shape.loaded": None,
+    "crypto.verify.shape.missed": "shape_missed.flood.py",
+    "ledger.root.point.sql": "root_point_reads_per_tx.flood.py",
+}
+FLOOD_ZONES = {"herder.recvTransactions", "herder.recvTransactions.verify",
+               "herder.ledgerClosed"}
+
+
+@pytest.mark.parametrize("name", sorted(FLOOD_NAMES))
+def test_flood_names_are_published_documented_and_read(name, program_opens):
+    names, zones = program_opens
+    assert name in names, (
+        f"stellar_core_tpu/ opens no zone or counter {name!r}")
+    assert (name in zones) == (name in FLOOD_ZONES)
+    for doc in ("docs/OBSERVABILITY.md", "PERF.md"):
+        with open(os.path.join(ROOT, doc), encoding="utf-8") as fh:
+            assert f"`{name}`" in fh.read(), f"{doc} does not name {name}"
+    reader = FLOOD_NAMES[name]
+    if reader is not None:
+        with open(os.path.join(READERS, reader), encoding="utf-8") as fh:
+            assert f'"{name}"' in fh.read()
+    else:
+        # no reader takes it: the cell's driver holds it in `correct`
+        with open(os.path.join(ROOT, "benchmark", "generators",
+                               "txset_flood.py"), encoding="utf-8") as fh:
+            text = fh.read()
+        assert f'"{name}"' in text or name.rsplit(".", 1)[0] + "." in text
 
 
 def test_complete_wait_live_reads_the_barrier_zone_through_catchups_reader(
